@@ -2,7 +2,7 @@
 
 The reference compares against Dask bag (benchmarks/tf-idf-dask.py) and
 derives a finding from it (Dask OOMs at the 500x tier).  This offline
-image has no dask wheel (VERDICT round 5, item 7), so the second system
+image has no dask wheel, so the second system
 is the stdlib's honest multi-core yardstick: split the corpus into
 line-aligned byte ranges, run the reference baseline's exact per-line
 Counter loop (benchmarks/baseline.py:12-24 shape) in a worker pool, and

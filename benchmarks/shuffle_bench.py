@@ -314,6 +314,12 @@ def _spawn_mproc(args):
     port = _free_port()
     env_base = dict(os.environ)
     env_base.pop("XLA_FLAGS", None)
+    # One process per chip: N ranks on one TPU host would each reach for
+    # the chip, and all but one would fail or hang.  The ranks run on
+    # the virtual CPU backend; multi-process on chips is not a path yet.
+    env_base["JAX_PLATFORMS"] = "cpu"
+    print("mproc: {} ranks on the virtual CPU backend (JAX_PLATFORMS=cpu), "
+          "never on a TPU".format(args.mproc), file=sys.stderr, flush=True)
     procs = []
     for rank in range(args.mproc):
         env = dict(env_base)

@@ -14,6 +14,10 @@ hierarchy is HBM -> host RAM -> disk instead of RAM -> disk.
 
 import logging
 
+from . import settings as _settings
+
+_settings.configure_compile_cache()
+
 from .base import (BlockMapper, BlockReducer, Map, Mapper, Reduce, Reducer,
                    StreamMapper, StreamReducer, Streamable)
 from .blocks import Block, BlockBuilder

@@ -41,38 +41,49 @@ def log(msg):
     print(msg, file=sys.stderr, flush=True)
 
 
-def make_corpus(path, mb):
-    """Deterministic Zipf-ish text: ~24k-word vocabulary, ~8-12 tokens/line
-    (the Shakespeare corpus shape: 5.3MB, 23,903 unique words)."""
+def corpus_text(rng, nbytes):
+    """At least ``nbytes`` of deterministic Zipf-ish text drawn from
+    ``rng`` (a ``numpy.random.RandomState``): a ~24k-word vocabulary,
+    8-12 tokens per line (the Shakespeare corpus shape: 5.3MB, 23,903
+    unique words)."""
     import numpy as np
 
-    if os.path.exists(path) and os.path.getsize(path) >= mb * 1024 ** 2:
-        return
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    rng = np.random.RandomState(1234)
     vocab_n = 24000
     vocab = np.array(["w%04x" % i if i > 200 else "t%d" % i
                       for i in range(vocab_n)], dtype=object)
     # Zipf ranks: common words dominate like natural text
     probs = 1.0 / np.arange(1, vocab_n + 1) ** 1.1
     probs /= probs.sum()
-    target = mb * 1024 ** 2
+    parts = []
     written = 0
+    while written < nbytes:
+        ids = rng.choice(vocab_n, size=(20000,), p=probs)
+        lens = rng.randint(8, 13, size=2000)
+        pos = 0
+        out = []
+        for L in lens:
+            out.append(" ".join(vocab[ids[pos:pos + L]]))
+            pos += L
+            if pos + 13 > len(ids):
+                break
+        chunk = "\n".join(out) + "\n"
+        parts.append(chunk)
+        written += len(chunk)
+    return "".join(parts)
+
+
+def make_corpus(path, mb, seed=1234):
+    """Write ``mb`` MiB of :func:`corpus_text` drawn from ``seed`` to
+    ``path`` (kept when a file of that size is already there)."""
+    import numpy as np
+
+    if os.path.exists(path) and os.path.getsize(path) >= mb * 1024 ** 2:
+        return
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    text = corpus_text(np.random.RandomState(seed), mb * 1024 ** 2)
     with open(path, "w") as f:
-        while written < target:
-            ids = rng.choice(vocab_n, size=(20000,), p=probs)
-            lens = rng.randint(8, 13, size=2000)
-            pos = 0
-            out = []
-            for L in lens:
-                out.append(" ".join(vocab[ids[pos:pos + L]]))
-                pos += L
-                if pos + 13 > len(ids):
-                    break
-            chunk = "\n".join(out) + "\n"
-            f.write(chunk)
-            written += len(chunk)
-    log("corpus: {} ({:.1f} MB)".format(path, written / 1e6))
+        f.write(text)
+    log("corpus: {} ({:.1f} MB)".format(path, len(text) / 1e6))
 
 
 def run_baseline(corpus, outdir):

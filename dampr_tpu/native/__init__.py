@@ -1,12 +1,14 @@
 """Native host codec: builds and binds the C++ tokenizer via ctypes.
 
 The shared object compiles on first use (g++ -O3, ~1s) and is cached next to
-the source; set DAMPR_TPU_NATIVE=0 to force the pure-numpy fallback.  The
-binding is ctypes on purpose — no pybind11 in the image, and the interface is
+the source under a name keyed by the source's content and the flags; set
+DAMPR_TPU_NATIVE=0 to force the pure-numpy fallback.  The binding is ctypes
+on purpose — no pybind11 in the image, and the interface is
 four flat arrays, exactly what ctypes does well.
 """
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -18,20 +20,29 @@ log = logging.getLogger("dampr_tpu.native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "tokenizer.cpp")
-_SO = os.path.join(_HERE, "_native.so")
+_FLAGS = ("-O3", "-shared", "-fPIC")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build():
-    cmd = ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _SO]
-    try:
-        subprocess.run(cmd + ["-march=native"], check=True,
-                       capture_output=True)
-    except (subprocess.CalledProcessError, OSError):
-        subprocess.run(cmd, check=True, capture_output=True)
+def _so_path():
+    """The built library's path, named by a digest of the source and the
+    build flags: a copy of the tree can only load a library built from
+    the same source with the same flags.  No ``-march=native`` — a tree
+    copied to another host must never load code built for a CPU it lacks."""
+    h = hashlib.sha256(" ".join(_FLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_HERE, "_native-{}.so".format(h.hexdigest()[:16]))
+
+
+def _build(so):
+    tmp = "{}.{}.tmp".format(so, os.getpid())
+    subprocess.run(["g++", *_FLAGS, _SRC, "-o", tmp], check=True,
+                   capture_output=True)
+    os.replace(tmp, so)  # atomic: a concurrent loader never sees half a file
 
 
 def _codec_timed(fn):
@@ -61,10 +72,10 @@ def get_lib():
         if os.environ.get("DAMPR_TPU_NATIVE", "1") in ("0", "false"):
             return None
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                _build()
-            lib = ctypes.CDLL(_SO)
+            so = _so_path()
+            if not os.path.exists(so):
+                _build(so)
+            lib = ctypes.CDLL(so)
             fn = lib.dampr_tokenize_hash
             fn.restype = ctypes.c_long
             fn.argtypes = [
@@ -79,29 +90,18 @@ def get_lib():
                 ctypes.c_int, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ]
-            try:
-                fp = lib.dampr_parse_i64
-                fp.restype = ctypes.c_long
-                fp.argtypes = [
-                    ctypes.c_void_p, ctypes.c_long, ctypes.c_void_p,
-                    ctypes.c_void_p,
-                ]
-            except AttributeError:
-                log.warning("cached native library predates "
-                            "dampr_parse_i64; rebuild to enable it")
-            # Newer symbol: bind guarded so a stale cached .so (mtime-
-            # preserving deploys can skip the rebuild) degrades only this
-            # entry point, never the tokenizer fast paths it still exports.
-            try:
-                fb = lib.dampr_hash_bytes_batch
-                fb.restype = None
-                fb.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_long,
-                    ctypes.c_void_p, ctypes.c_void_p,
-                ]
-            except AttributeError:
-                log.warning("cached native library predates "
-                            "dampr_hash_bytes_batch; rebuild to enable it")
+            fp = lib.dampr_parse_i64
+            fp.restype = ctypes.c_long
+            fp.argtypes = [
+                ctypes.c_void_p, ctypes.c_long, ctypes.c_void_p,
+                ctypes.c_void_p,
+            ]
+            fb = lib.dampr_hash_bytes_batch
+            fb.restype = None
+            fb.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_long,
+                ctypes.c_void_p, ctypes.c_void_p,
+            ]
             _lib = lib
         except Exception as exc:  # noqa: BLE001 - any failure -> numpy path
             log.warning("native tokenizer unavailable (%s); using numpy", exc)
@@ -142,7 +142,7 @@ def parse_i64(buf):
     or raises ValueError on the first unparsable/out-of-range token
     (numpy-parse error semantics)."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "dampr_parse_i64"):
+    if lib is None:
         return None
     buf = np.ascontiguousarray(buf)
     n = len(buf)
@@ -162,7 +162,7 @@ def hash_bytes_batch(bs):
     (h1, h2) uint32 arrays, or None when the native library is
     unavailable."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "dampr_hash_bytes_batch"):
+    if lib is None:
         return None
     n = len(bs)
     offs = np.zeros(n + 1, dtype=np.int64)

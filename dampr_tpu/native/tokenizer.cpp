@@ -13,7 +13,8 @@
 // (_FNV_OFFSET1/2, _FNV_PRIME1/2 over utf-8 bytes) so tokens group with
 // equal Python-string keys everywhere in the engine.
 //
-// Build: g++ -O3 -march=native -shared -fPIC tokenizer.cpp -o _native.so
+// Build: native/__init__.py (_FLAGS); the library is named by a digest of
+// this file and the flags.
 
 #include <cstdint>
 #include <cstdlib>

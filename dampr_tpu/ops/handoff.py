@@ -81,9 +81,7 @@ def _pow2(n, floor=4096):
 def _donate_ok():
     """Buffer donation is a no-op (with a warning) on CPU backends;
     donate only where shapes and platform permit."""
-    import jax
-
-    return jax.default_backend() not in ("cpu",)
+    return settings.backend() != "cpu"
 
 
 @functools.lru_cache(maxsize=None)
@@ -103,9 +101,7 @@ def _host_bootstrap():
     re-hash, no row sort), skipping the window's tokenize/pad/dispatch
     entirely.  A real accelerator keeps the classic bootstrap: the
     program runs on device while the host tokenizes the next window."""
-    import jax
-
-    return jax.default_backend() == "cpu"
+    return settings.backend() == "cpu"
 
 
 #: Windowed-dedup span (tokens): batches whose longest line fits run
@@ -209,6 +205,54 @@ def _scatter_program():
     kwargs = {"donate_argnums": (0,)} if _donate_ok() else {}
     return jax.jit(lambda acc, slots, vals: acc.at[slots].add(vals),
                    **kwargs)
+
+
+@functools.lru_cache(maxsize=None)
+def _set_rows_program():
+    """Stage vocabulary rows into the device table; slots past the table
+    (the padding) drop."""
+    import jax
+
+    def program(tab_mat, tab_lens, slots, rows, lens):
+        return (tab_mat.at[slots].set(rows, mode="drop"),
+                tab_lens.at[slots].set(lens, mode="drop"))
+
+    kwargs = {"donate_argnums": (0, 1)} if _donate_ok() else {}
+    return jax.jit(program, **kwargs)
+
+
+@functools.lru_cache(maxsize=None)
+def _finalize_program(n_partitions):
+    """Gather the accumulator into partition order (``idx``) and sum each
+    partition's counts (``seg_of``; pad rows carry ``n_partitions`` and
+    drop).  Returns the gathered lanes, the per-partition sums and the
+    smallest count."""
+    import jax
+    import jax.numpy as jnp
+
+    def program(acc, idx, seg_of):
+        vals = jnp.take(acc, idx)
+        live = seg_of < n_partitions
+        psum = jax.ops.segment_sum(vals, seg_of, num_segments=n_partitions)
+        vmin = jnp.min(jnp.where(live, vals, jnp.iinfo(vals.dtype).max))
+        return vals, psum, vmin
+
+    return jax.jit(program)
+
+
+def _slice(x, start, width):
+    """``x[start:start + width]`` through one compiled slice per
+    ``(x.shape, width)``: the start is a traced argument."""
+    return _slice_program(width)(x, np.int32(start))
+
+
+@functools.lru_cache(maxsize=None)
+def _slice_program(width):
+    import jax
+    from jax import lax
+
+    return jax.jit(lambda x, start: lax.dynamic_slice(x, (start,),
+                                                      (width,)))
 
 
 def group_token_rows(buf, starts, lens, lines, dedup):
@@ -374,9 +418,17 @@ class HandoffVocab(object):
                 w = min(len(b), self.Lcap)
                 rows[i, :w] = np.frombuffer(b[:w], dtype=np.uint8)
                 lens[i] = len(b)
-            dslots = jnp.asarray(slots)
-            self.tab_mat = self.tab_mat.at[dslots].set(jnp.asarray(rows))
-            self.tab_lens = self.tab_lens.at[dslots].set(jnp.asarray(lens))
+            # Pad the staged rows to a power-of-two count (pad slots
+            # point past the table and drop): one compiled update per
+            # bucket, not one per distinct staging size.
+            m = _pow2(len(slots), 256)
+            slots = np.pad(slots, (0, m - len(slots)),
+                           constant_values=self.cap)
+            rows = np.pad(rows, ((0, m - len(rows)), (0, 0)))
+            lens = np.pad(lens, (0, m - len(lens)))
+            self.tab_mat, self.tab_lens = _set_rows_program()(
+                self.tab_mat, self.tab_lens, jnp.asarray(slots),
+                jnp.asarray(rows), jnp.asarray(lens))
             moved += rows.nbytes + lens.nbytes
             self._pending_rows = []
         if self._tab_dirty and (
@@ -468,9 +520,14 @@ class HandoffVocab(object):
             return False
         self.total_added += total
         self._sync_table()
-        self.acc = _scatter_program()(
-            self.acc, jnp.asarray(np.asarray(slots, dtype=np.int32)),
-            jnp.asarray(np.asarray(counts).astype(_acc_dtype())))
+        # Pad to a power-of-two count: pad entries add 0 to the sink slot.
+        m = _pow2(len(slots), 256)
+        slots = np.pad(np.asarray(slots, dtype=np.int32),
+                       (0, m - len(slots)), constant_values=self.cap)
+        counts = np.pad(np.asarray(counts).astype(_acc_dtype()),
+                        (0, m - len(counts)))
+        self.acc = _scatter_program()(self.acc, jnp.asarray(slots),
+                                      jnp.asarray(counts))
         if self.store is not None:
             self.store.count_h2d(len(slots) * (4 + _acc_dtype().itemsize))
         return True
@@ -677,33 +734,48 @@ class HandoffVocab(object):
         keys = np.empty(self.nslots, dtype=object)
         for i, k in enumerate(self.keys):
             keys[i] = k
-        with devtime.track("device"), _trace.span(
-                "handoff", "finalize", records=int(self.nslots)):
-            perm_dev = jnp.asarray(perm.astype(np.int32))
-            vals = jnp.take(self.acc, perm_dev)
-            mins = jnp.min(vals) if self.nslots else None
-        if self.store is not None:
-            self.store.count_h2d(perm.nbytes)
         bounds = np.flatnonzero(np.diff(sorted_pid)) + 1
         starts = np.concatenate(([0], bounds))
         ends = np.concatenate((bounds, [self.nslots]))
-        lane_min = int(mins) if mins is not None else 0
+        # Every partition's lanes are padded to a power of two (the
+        # consumer marks the valid prefix, ``len(ref)``), and all of them
+        # gather from the accumulator in ONE program: device shapes stay
+        # in a few pow2 buckets instead of one compile per partition size.
+        # Pad rows read the sink slot and belong to no partition.
+        widths = [_pow2(e - s, 8) for s, e in zip(starts, ends)]
+        offs = np.concatenate(([0], np.cumsum(widths)))
+        total = _pow2(int(offs[-1]), 256)
+        idx = np.full(total, self.cap, dtype=np.int32)
+        seg_of = np.full(total, n_partitions, dtype=np.int32)
+        for s, e, o in zip(starts, ends, offs):
+            idx[o:o + e - s] = perm[s:e]
+            seg_of[o:o + e - s] = sorted_pid[s]
+        with devtime.track("device"), _trace.span(
+                "handoff", "finalize", records=int(self.nslots)):
+            vals, psum, vmin = _finalize_program(n_partitions)(
+                self.acc, jnp.asarray(idx), jnp.asarray(seg_of))
+            psum = np.asarray(psum)
+            lane_min = int(vmin)
+        if self.store is not None:
+            self.store.count_h2d(idx.nbytes + seg_of.nbytes)
+            self.store.count_d2h(psum.nbytes + 4)
         mapping = {}
         total_dev = 0
-        for s, e in zip(starts, ends):
+        for s, e, o, w in zip(starts, ends, offs, widths):
             p = int(sorted_pid[s])
             seg = perm[s:e]
-            dev_v = vals[int(s):int(e)]
-            h1_seg = h1[seg]
-            h2_seg = h2[seg]
+            dev_v = _slice(vals, int(o), w)
+            h1_seg = np.zeros(w, dtype=np.uint32)
+            h2_seg = np.zeros(w, dtype=np.uint32)
+            h1_seg[:e - s] = h1[seg]
+            h2_seg[:e - s] = h2[seg]
             dev_h1 = jax.device_put(h1_seg)
             dev_h2 = jax.device_put(h2_seg)
             # total_added is guarded under the lane bound, so the segment
             # sum is exact in the accumulator dtype.
-            lane_abs = int(jnp.sum(dev_v))
             ref = BlockRef.from_device_lanes(
-                keys.take(seg), h1_seg, h2_seg, dev_v, dev_h1, dev_h2,
-                store=store, value_dtype=np.int64, lane_abs=lane_abs,
+                keys.take(seg), h1[seg], h2[seg], dev_v, dev_h1, dev_h2,
+                store=store, value_dtype=np.int64, lane_abs=int(psum[p]),
                 lane_min=lane_min,
                 h2d_bytes=h1_seg.nbytes + h2_seg.nbytes)
             store.register_device(ref)

@@ -14,7 +14,7 @@ Division of labor per line-aligned scan window:
   padded token byte matrix — the h2d payload, built for the NEXT batch
   while the previous batch's program runs (double buffering);
 - **device (one program)**: dual-lane FNV hash of the matrix (byte-exact
-  with :mod:`.hashing`), stable sort by ``(validity, h1, h2[, line])``,
+  with :mod:`.hashing`), stable sorts ordering rows by ``(h1, h2)``,
   per-line first-occurrence dedup (DocFreq), segment counts via an
   in-program prefix scan (or the Pallas fused segfold kernel when
   ``settings.lower_pallas_segfold`` opts in), segment-representative
@@ -46,6 +46,7 @@ from .. import settings
 from ..obs import profile as _profile
 from ..obs import trace as _trace
 from . import devtime
+from .segment import sort_perm
 from .text import (_LOWER, _SHORT_TOKEN, _token_bounds, chunk_doc_freq,
                    chunk_token_counts)
 
@@ -114,19 +115,21 @@ def _token_fold_jit(n, L, dedup, pallas, interpret):
 
         h1, h2 = lax.fori_loop(0, L, body, (h1, h2))
 
-        # -- stable sort by (validity, h1, h2[, line]) ------------------
-        inv = jnp.where(lens > 0, 0, 1).astype(jnp.int32)  # pad rows last
-        iota = jnp.arange(n, dtype=jnp.int32)
-        if dedup:
-            keys = (inv, h1, h2, lines.astype(jnp.int32), iota)
-            num_keys = 4
-        else:
-            keys = (inv, h1, h2, iota)
-            num_keys = 3
-        sorted_ = lax.sort(keys, num_keys=num_keys, is_stable=True)
-        sinv, sh1, sh2 = sorted_[0], sorted_[1], sorted_[2]
-        sline = sorted_[3] if dedup else None
-        perm = sorted_[-1]
+        # -- order by (h1, h2), pad rows last ---------------------------
+        # Pad rows carry the all-ones key; stability keeps them behind a
+        # real token with that key (they are the batch's tail), and the
+        # validity lane splits the two.  Rows enter in document order, so
+        # stability also leaves each (h1, h2) group ordered by line: the
+        # per-line dedup below needs no line key.
+        valid = lens > 0
+        pad = jnp.uint32(0xFFFFFFFF)
+        k1 = jnp.where(valid, h1, pad)
+        k2 = jnp.where(valid, h2, pad)
+        perm = sort_perm(k1, k2)
+        sh1 = jnp.take(k1, perm)
+        sh2 = jnp.take(k2, perm)
+        sinv = jnp.where(jnp.take(valid, perm), 0, 1).astype(jnp.int32)
+        sline = jnp.take(lines.astype(jnp.int32), perm) if dedup else None
 
         def adj_new(*lanes):
             """True where any lane differs from its predecessor (position
@@ -183,10 +186,9 @@ def _token_fold_jit(n, L, dedup, pallas, interpret):
 
 
 def _lower_interpret():
-    """Pallas interpret mode is required off-TPU; resolve once."""
-    import jax
-
-    return jax.default_backend() not in ("tpu",)
+    """Pallas runs interpreted off-TPU only: on a TPU the kernel always
+    compiles through Mosaic."""
+    return settings.backend() != "tpu"
 
 
 def _handoff_enter_frac():
@@ -268,10 +270,15 @@ class DeviceTokenFoldSink(object):
                                              budget=share)
 
     # -- host fallbacks ----------------------------------------------------
+    def _note_fallback(self):
+        self.fallbacks += 1
+        if self.store is not None:
+            self.store.count_lower_fallback()
+
     def _host_window(self, win):
         """Exact host path for one whole window (non-UTF-8 windows, lines
         wider than a batch)."""
-        self.fallbacks += 1
+        self._note_fallback()
         if self.dedup:
             blk = chunk_doc_freq(win, self.mode, self.lower,
                                  self.pair_values)
@@ -288,7 +295,7 @@ class DeviceTokenFoldSink(object):
         from . import hashing
         from .handoff import group_token_rows
 
-        self.fallbacks += 1
+        self._note_fallback()
         uniq, counts = group_token_rows(buf, starts, lens, lines,
                                         self.dedup)
         keys = np.empty(len(uniq), dtype=object)

@@ -24,8 +24,8 @@ and subtraction of exclusive prefixes is exact.
 
 Like ops/pallas_fnv.py this is TPU-Mosaic code; CPU tests run it with
 ``interpret=True``.  The real-chip benchmark lives in
-benchmarks/pallas_bench.py and RESULTS.md records whether it beats the XLA
-scan chain (no unverified perf claims here).
+benchmarks/pallas_bench.py; whether it beats the XLA scan chain is not
+measured yet (no unverified perf claims here).
 
 Reference anchor: this is the hot half of the reference's combine path
 (dampr/base.py:393-402 PartialReduceCombiner + dataset.py:84-117

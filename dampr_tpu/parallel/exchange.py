@@ -54,7 +54,7 @@ from .. import settings
 from ..io import codecs as _codecs
 from . import mitigate as _mitigate
 from . import replan
-from .mesh import mesh_size, shard_map as _shard_map
+from .mesh import mesh_size
 
 log = logging.getLogger("dampr_tpu.parallel.exchange")
 
@@ -114,7 +114,7 @@ def _build_exchange(mesh, axis, capacity, gather=False):
         kwargs["check_vma"] = False
 
     def program(bb, ln):
-        return _shard_map(
+        return jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(axis), P(axis)),
             out_specs=(out_spec, out_spec), **kwargs)(bb, ln)
@@ -441,7 +441,7 @@ def _build_share(mesh, axis):
     def per_device(t):
         return lax.all_gather(t, axis, tiled=True)
 
-    return jax.jit(_shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
+    return jax.jit(jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
                               out_specs=P(), check_vma=False))
 
 

@@ -130,30 +130,17 @@ def _clock_handshake():
 
     if jax.process_count() <= 1:
         return
-    method = None
+    from jax.experimental import multihost_utils
+
     try:
-        from jax.experimental import multihost_utils
-
         multihost_utils.sync_global_devices("dampr_tpu_clock_handshake")
-        method = "sync_global_devices"
     except Exception:
-        try:
-            # Older jax without multihost_utils: a tiny psum across all
-            # devices is an equivalent barrier (every process must
-            # contribute before any result materializes).
-            import numpy as np
-
-            val = jax.pmap(lambda x: jax.lax.psum(x, "i"), axis_name="i")(
-                np.ones((len(jax.local_devices()),), dtype=np.float32))
-            np.asarray(val)
-            method = "psum"
-        except Exception:
-            clock_sync = None
-            return
+        clock_sync = None
+        return
     clock_sync = {
         "barrier_perf": time.perf_counter(),
         "barrier_wall": time.time(),
-        "method": method,
+        "method": "sync_global_devices",
     }
 
 
@@ -220,22 +207,3 @@ def process_info():
         "global_devices": len(jax.devices()),
         "multiprocess": jax.process_count() > 1,
     }
-
-
-def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` across jax versions.  Newer jax exposes it
-    top-level with the vma-typed replication check (``check_vma``); 0.4.x
-    ships ``jax.experimental.shard_map`` with ``check_rep`` instead.
-    Callers always pass the new-API kwargs; the legacy spelling is mapped
-    here so every mesh program has exactly one compatibility seam."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kwargs)

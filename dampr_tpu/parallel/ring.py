@@ -14,7 +14,7 @@ import functools
 import numpy as np
 
 from .. import settings
-from .mesh import mesh_size, shard_map as _shard_map
+from .mesh import mesh_size
 
 
 @functools.lru_cache(maxsize=None)
@@ -47,7 +47,7 @@ def _ring_allreduce_program(mesh, axis, op):
         return acc
 
     def program(x):
-        return _shard_map(
+        return jax.shard_map(
             per_device, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis))(x)
 
     return jax.jit(program)
@@ -90,7 +90,7 @@ def _ring_allgather_program(mesh, axis):
         return jnp.concatenate(parts, axis=0)
 
     def program(x):
-        return _shard_map(
+        return jax.shard_map(
             per_device, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis))(x)
 
     return jax.jit(program)

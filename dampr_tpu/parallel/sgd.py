@@ -13,7 +13,7 @@ import functools
 import numpy as np
 
 from .. import settings
-from .mesh import mesh_size, shard_map as _shard_map
+from .mesh import mesh_size
 
 
 def init_params(n_features, seed=0):
@@ -42,7 +42,7 @@ def _build_train_step(mesh, lr, axis):
     # the cross-device gradient combine is inserted by the transpose rules
     # (an automatic psum over the replicated params) rather than hand-written
     # — hand-psum'ing inside would double-count under vma-typed shard_map.
-    per_shard_loss = _shard_map(
+    per_shard_loss = jax.shard_map(
         lambda p, xs, ys: jnp.expand_dims(_loss_fn(p, xs, ys), 0),
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),

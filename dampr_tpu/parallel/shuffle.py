@@ -33,7 +33,8 @@ import functools
 import numpy as np
 
 from .. import settings
-from .mesh import mesh_size, shard_map as _shard_map
+from ..ops.segment import sort_perm
+from .mesh import mesh_size
 
 _INVALID_SLOT_PAD = 1  # extra scatter slot that swallows dropped writes
 
@@ -58,11 +59,9 @@ def _local_fold(inv, h1, h2, v, kind, nonneg_sum=False):
 
     - ``nonneg_sum`` (the count/len/doc-freq hot path): pure scan fold —
       sort, then segment totals land at segment *end* positions via
-      ``cumsum`` + a ``cummax``-carried start offset.  No scatter at all;
-      on a v5e this runs 6.7x faster than the scatter lowering because XLA's
-      TPU scatter serializes random updates while sort and scan are
-      bandwidth-bound (measured: 279 vs 42 M records/s at 4M records —
-      benchmarks/RESULTS.md).  Exact because the host wrapper only sets the
+      ``cumsum`` + a ``cummax``-carried start offset.  No scatter at all:
+      XLA's TPU scatter serializes random updates while sort and scan are
+      bandwidth-bound.  Exact because the host wrapper only sets the
       flag for signed integer values whose *global* sum fits the lane dtype,
       so the running cumsum cannot wrap and is order-exact.
     - otherwise: segment_sum/min/max scatters into segment-id slots (handles
@@ -71,10 +70,10 @@ def _local_fold(inv, h1, h2, v, kind, nonneg_sum=False):
     """
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     n = h1.shape[0]
-    inv, h1, h2, v = lax.sort((inv, h1, h2, v), num_keys=3, is_stable=True)
+    perm = sort_perm(inv, h1, h2)
+    inv, h1, h2, v = (jnp.take(a, perm) for a in (inv, h1, h2, v))
     starts = _segments(inv, h1, h2)
 
     if nonneg_sum and kind == "sum":
@@ -136,9 +135,11 @@ def _pack_by_dest(inv, h1, h2, v, n_dev, capacity):
 
     n = h1.shape[0]
     dest = (h1 % jnp.uint32(n_dev)).astype(jnp.uint32)
-    # Sort by (validity, dest) so each destination's entries are contiguous.
-    inv, dest, h1, h2, v = lax.sort((inv, dest, h1, h2, v), num_keys=2,
-                                    is_stable=True)
+    # Order by (validity, dest) so each destination's entries are
+    # contiguous: invalid entries take the key n_dev, past every dest.
+    perm = sort_perm(jnp.where(inv == 0, dest, jnp.uint32(n_dev)))
+    inv, dest, h1, h2, v = (jnp.take(a, perm)
+                            for a in (inv, dest, h1, h2, v))
     iota = jnp.arange(n, dtype=jnp.int32)
     new_group = jnp.where(
         iota == 0, True,
@@ -219,7 +220,7 @@ def _build_fold_program(mesh, n_dev, n_local, capacity, kind, v_dtype_name,
             # all_gather output IS replicated; the varying-axes inference
             # can't prove it, so disable the check for this variant.
             kwargs["check_vma"] = False
-        return _shard_map(
+        return jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis)),
@@ -391,28 +392,32 @@ def mesh_keyed_refold(mesh, parts, kind, nonneg=False, capacity_factor=None):
     engine tracks the running bound host-side before uploading windows),
     because partial magnitudes are bounded by element magnitudes.  All
     parts must share one value dtype (the engine guards this)."""
-    import jax
-    import jax.numpy as jnp
-
-    h1 = jnp.concatenate([p[0] for p in parts])
-    h2 = jnp.concatenate([p[1] for p in parts])
-    v = jnp.concatenate([p[2] for p in parts])
-    valid = jnp.concatenate([p[3] for p in parts])
-
     n_dev = mesh_size(mesh)
-    total = h1.shape[0]
+    total = sum(int(p[0].shape[0]) for p in parts)
     n_local = _pad_pow2(-(-total // n_dev))
-    padded = n_local * n_dev
-    if padded != total:
-        pad = padded - total
-        h1 = jnp.pad(h1, (0, pad))
-        h2 = jnp.pad(h2, (0, pad))
-        v = jnp.pad(v, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
+    h1, h2, v, valid = _concat_pad(n_local * n_dev)(
+        [tuple(p) for p in parts])
 
     factor = capacity_factor or settings.shuffle_capacity_factor
     return _run_fold_padded(mesh, h1, h2, v, valid, n_dev, n_local, kind,
                             nonneg, factor)
+
+
+@functools.lru_cache(maxsize=None)
+def _concat_pad(padded):
+    """One program: concatenate each lane of ``parts`` and zero-pad it to
+    ``padded`` rows (one compile per list of part shapes, not eight)."""
+    import jax
+    import jax.numpy as jnp
+
+    def program(parts):
+        out = []
+        for lane in range(4):
+            cat = jnp.concatenate([p[lane] for p in parts])
+            out.append(jnp.pad(cat, (0, padded - cat.shape[0])))
+        return tuple(out)
+
+    return jax.jit(program)
 
 
 @functools.lru_cache(maxsize=None)
@@ -421,13 +426,10 @@ def _live_prefix_sort(n):
     device-side half of :func:`compact_partial`."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     def kernel(h1, h2, v, ok):
-        inv = jnp.where(ok == 1, jnp.uint32(0), jnp.uint32(1))
-        _, sh1, sh2, sv = lax.sort((inv, h1, h2, v), num_keys=1,
-                                   is_stable=True)
-        return sh1, sh2, sv
+        perm = sort_perm(jnp.where(ok == 1, jnp.uint32(0), jnp.uint32(1)))
+        return tuple(jnp.take(a, perm) for a in (h1, h2, v))
 
     return jax.jit(kernel)
 
@@ -483,7 +485,7 @@ def mesh_global_sum(mesh, v):
     from ..obs import trace as _trace
 
     with _trace.span("collective", "global-sum", records=int(total)):
-        out = jax.jit(_shard_map(
+        out = jax.jit(jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(axis),), out_specs=P()))(pv)
     return np.asarray(out).item()

@@ -3697,6 +3697,7 @@ class MTRunner(object):
                 "handoff_edges": (self.plan_report or {}).get(
                     "handoff_edges", 0),
                 "handoff_degrades": sto.handoff_degrades,
+                "lower_fallbacks": sto.lower_fallbacks,
             },
             "streamed_assoc_folds": self.streamed_assoc_folds,
             # Barrier-free pipelining evidence (docs/pipeline.md):

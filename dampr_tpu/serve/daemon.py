@@ -49,6 +49,9 @@ from . import wire as _wire
 
 log = logging.getLogger("dampr_tpu.serve")
 
+#: JAX platform every job worker subprocess runs on (see ``_spawn``).
+WORKER_PLATFORM = "cpu"
+
 
 def _state_dir():
     return settings.serve_dir or os.path.join(settings.scratch_root, "serve")
@@ -271,6 +274,11 @@ class ServeDaemon(object):
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+        # One process per chip: a worker that reached for the TPU while
+        # another process holds it would fail or hang, so every job runs
+        # on the CPU backend until the daemon owns a device executor
+        # (ROADMAP Queue 2.1).
+        env["JAX_PLATFORMS"] = WORKER_PLATFORM
 
         child_log = open(os.path.join(job_dir, "child.log"), "wb")
         try:
@@ -459,8 +467,9 @@ class ServeDaemon(object):
             target=self._dispatch_loop, daemon=True,
             name="dampr-tpu-serve-dispatch")
         self._dispatcher.start()
-        log.info("serve daemon up on %s:%d (%d worker slot(s), state %s)",
-                 self.host, self.port, self.workers, self.state_dir)
+        log.info("serve daemon up on %s:%d (%d worker slot(s) on the %s "
+                 "backend, state %s)", self.host, self.port, self.workers,
+                 WORKER_PLATFORM, self.state_dir)
         return self
 
     def stop(self):
@@ -692,7 +701,8 @@ def main(argv=None):
     signal.signal(signal.SIGTERM, _on_signal)
     signal.signal(signal.SIGINT, _on_signal)
     print("dampr-tpu-serve listening on http://{}:{} ({} worker "
-          "slot(s))".format(daemon.host, daemon.port, daemon.workers),
+          "slot(s); job workers run on the {} backend, never a TPU)".format(
+              daemon.host, daemon.port, daemon.workers, WORKER_PLATFORM),
           flush=True)
     while not stop_evt.is_set():
         stop_evt.wait(0.2)

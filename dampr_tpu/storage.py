@@ -300,7 +300,8 @@ class BlockRef(object):
                 keys, h1, h2 = kmeta
                 from .blocks import Block
 
-                return Block(keys, vals, h1, h2)
+                # Handoff lanes may be pow2-padded past the records.
+                return Block(keys, vals[:len(keys)], h1, h2)
             blk = self._block  # re-check: offload may have just published
             if blk is not None:
                 return blk
@@ -571,6 +572,9 @@ class RunStore(object):
         self.handoff_bytes = 0
         self.d2h_avoided_bytes = 0
         self.handoff_degrades = 0
+        # Lowered map batches or windows handed back to the exact host
+        # grouping (hash collision, non-UTF-8 window, over-wide line).
+        self.lower_fallbacks = 0
         # Overlap executor accounting: bytes of in-flight scan windows /
         # codec output the pipelined map driver holds ahead of the fold.
         # Charged against the same budget as resident blocks (reserving
@@ -609,6 +613,10 @@ class RunStore(object):
     def count_handoff_degrade(self):
         with self._lock:
             self.handoff_degrades += 1
+
+    def count_lower_fallback(self):
+        with self._lock:
+            self.lower_fallbacks += 1
 
     def count_h2d(self, n):
         """Feed bytes shipped to device outside the HBM-tier register path

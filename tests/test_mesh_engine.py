@@ -88,7 +88,7 @@ class TestMeshFoldEngagement:
 
 
 class TestMeshFoldOverBudget:
-    """VERDICT r2 task 2: the mesh path must survive over-budget (spilled)
+    """The mesh path must survive over-budget (spilled)
     inputs by streaming windows through the collective, instead of bailing
     to the host exactly when distribution would pay."""
 

@@ -23,7 +23,7 @@ class TestPackaging:
                                 "dampr-tpu-tfidf", "dampr-tpu-stats",
                                 "dampr-tpu-doctor", "dampr-tpu-lint",
                                 "dampr-tpu-sentry", "dampr-tpu-top",
-                                "dampr-tpu-history"}
+                                "dampr-tpu-history", "dampr-tpu-serve"}
 
     def test_console_entry_points_import(self):
         from dampr_tpu import cli
