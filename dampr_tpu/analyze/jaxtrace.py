@@ -282,7 +282,7 @@ class ChainProgram(object):
             ops = self.spec.ops
             rekey = self.spec.rekey
 
-            def program(lane):
+            def numeric_chain(lane):
                 cur = lane
                 mask = None
                 for kind, f in ops:
@@ -305,7 +305,7 @@ class ChainProgram(object):
                                                keys.shape)
                 return keys, cur, mask
 
-            fn = jax.jit(program)
+            fn = jax.jit(numeric_chain)
             with self._lock:
                 self._jits[key] = fn
         return fn

@@ -37,6 +37,7 @@ import weakref
 
 from . import faults as _faults
 from . import settings
+from .obs import trace as _trace
 from .base import (AssocFoldReducer, ComposedMapper, Filter, FlatMap, Inspect,
                    KeyedInnerJoin, KeyedLeftJoin, KeyedOuterJoin, KeyedReduce,
                    Map, MapAllJoin, MapCrossJoin, MapKeys, MapValues, Mapper,
@@ -126,7 +127,8 @@ def _drive_runner(make_runner, sources, resume):
     attempts = (max(0, settings.run_retries) + 1) if auto else 1
     prev_quarantine = None
     for attempt in range(attempts):
-        runner = make_runner()
+        with _trace.span("run", "setup"):
+            runner = make_runner()
         if prev_quarantine is not None and getattr(
                 runner, "_quarantine", None) is not None:
             # The retry adopts the failed attempt's quarantine: its

@@ -34,10 +34,29 @@ categories (event ``cat``):
 - ``checkpoint`` — resume persist/restore/plan/gc decisions;
 - ``job`` / ``stage`` — per-job spans on worker lanes; one span per
   stage on the ``stages`` lane;
+- ``scan`` / ``device`` — the lowered scan's phases, one span per window
+  or batch (:func:`dampr_tpu.ops.devtime.phase`): its host half
+  (``bounds``, ``line-ids``, ``pad``, ``decode``, ``absorb-misses``) and
+  its device half (``dispatch``, ``wait``, ``fetch``);
+- ``run`` — the run's own steps around the stages (``setup``, ``gc``,
+  ``cleanup``, ``finalize``, ``history``);
 - ``retry`` — instant markers for re-executed jobs.
 
 The emitted subset is documented (and CI-validated) by
 ``docs/trace_schema.json`` + ``tools/validate_trace.py``.
+
+**The device trace.**  Any ``jax.profiler`` session that is collecting —
+``settings.profile_dir``, a user's ``jax.profiler.trace``, a benchmark's
+traced window — also gets the engine's spans, with or without
+``settings.trace``: each span site opens a profiler annotation named
+``dampr.<category>.<name>`` (``dampr.stage.s1:map``,
+``dampr.device.wait``, ``dampr.handoff.table-probe``) on the trace's
+host plane, on the profiler's clock, beside the device's ops.  Names are
+stable; per-call values stay in ``trace.json``'s args.  The device
+programs carry the names of their work (``jit_token_fold``,
+``jit_table_probe``, ``jit_mesh_fold``, ...), so the trace's XLA modules
+say which program ran.  With no session and no tracer a span site costs
+one C++ ``is_enabled()`` check.
 
 **stats.json — the summary** (schema ``dampr-tpu-stats/1``), also
 returned in-memory from every run — traced or not — via
@@ -47,8 +66,9 @@ returned in-memory from every run — traced or not — via
   ``bytes_in/out``, ``spill_count``/``spill_bytes`` (causal attribution:
   charged to the stage whose pressure evicted the block),
   ``merge_gens``/``merge_gen_bytes``, ``retries``, ``seconds``;
-- ``devtime`` — run-scoped device/transfer/codec/codec_wait seconds
-  (epoch/delta snapshots of :mod:`dampr_tpu.ops.devtime`);
+- ``devtime`` — run-scoped device/transfer/codec/codec_wait seconds and
+  the lowered scan's wall-clock unions ``scan_feed``/``scan_wait``/
+  ``scan_drain`` (epoch/delta snapshots of :mod:`dampr_tpu.ops.devtime`);
 - ``overlap`` — configured windows, ``stall_fraction`` (codec_wait /
   wall: the codec time still on the critical path), peak in-flight bytes;
 - ``io`` — the async spill subsystem's shape: ``spill_write_bytes/

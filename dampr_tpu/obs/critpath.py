@@ -77,6 +77,11 @@ _RESOURCE_BY_CAT = {
     # publish backpressure wait rides "stall" spans named stream-wait
     # and classifies as pipeline-stall via _resource_of below.
     "pipeline": "fold",
+    # The host half of the lowered scan's window pass (token bounds,
+    # padding, key decode, miss regroup: ops.devtime.phase) is codec work,
+    # as the codec-window span around it already says; its device half
+    # (dispatch, wait, fetch) records as "device".
+    "scan": "codec",
 }
 
 #: Verdicts that may be *covered* by other work happening concurrently:
@@ -100,12 +105,12 @@ def _resource_of(cat, name):
         return ("pipeline-stall" if "stream" in (name or "")
                 else "overlap-stall")
     if cat == "device":
-        # Both the dispatch ("map-fold") and the drain span are device
+        # The lowered scan's dispatch, wait and fetch are all device
         # time: dispatch is async, so the program's COMPUTE surfaces
-        # inside the drain's block — classifying drain as transfer
-        # would diagnose compute-bound runs as transfer-bound.  The
-        # h2d/d2h split comes from the profiler's sub-phases (and the
-        # hbm spans), not from span names.
+        # inside the wait — classifying the drain as transfer would
+        # diagnose compute-bound runs as transfer-bound.  The h2d/d2h
+        # split comes from the profiler's sub-phases (and the hbm
+        # spans), not from span names.
         return "device"
     return _RESOURCE_BY_CAT.get(cat)
 

@@ -16,8 +16,10 @@ went to.  This module is the attribution layer under ``settings.profile``
 - **device stages**: the double-buffered dispatch loop's sub-phases —
   ``build`` (padded-matrix construction), ``h2d`` (program dispatch +
   feed), ``compute`` (blocked-on-program time at drain), ``d2h``
-  (result fetch) — accumulate separately, decomposing the aggregate
-  ``device`` span the trace records;
+  (result fetch) — accumulate separately, for the classic program and
+  the handoff tier's table probe alike.  They are read from the same
+  clock pair as the lowered scan's spans and ``devtime`` buckets
+  (:class:`dampr_tpu.ops.devtime.phase`);
 - **jobs**: every pool job's wall time lands on its stage, so the
   summary can report *coverage* — the fraction of job thread-seconds the
   per-op attribution explains (the acceptance bar: >= 0.9 on fused

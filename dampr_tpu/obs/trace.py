@@ -28,10 +28,35 @@ Scope: the active tracer is process-global (runs own it run-scoped via
 interleave spans into whichever tracer started last; the runner documents
 this and run-level metrics stay exact regardless (they come from
 run-scoped counters, not spans).
+
+**The profiler's clock.**  Whenever a ``jax.profiler`` session is
+collecting (``jax.profiler.trace``, ``settings.profile_dir``, a
+benchmark's traced window), every ``span()`` also opens a profiler
+annotation named ``dampr.<cat>.<name>`` (``dampr.stage.s1:map``,
+``dampr.scan.wait``), so the engine's spans land on the host plane of the
+device trace, on its clock, beside the device's ops.  Names are stable:
+per-call values (bytes, tokens) stay in the tracer's args, never in the
+annotation's name.  There is no switch besides the session itself: with
+no tracer and no collecting session ``span()`` returns the shared no-op
+after one C++ ``is_enabled()`` check.  Annotations cannot be back-dated,
+so ``now()``/``complete()`` pairs reach the tracer only.
 """
 
 import threading
 import time
+
+try:  # the profiler's own annotation; loading it does not load jax
+    from jaxlib._profiler import TraceMe as _TraceMe
+
+    _collecting = _TraceMe.is_enabled
+except ImportError:  # pragma: no cover - jaxlib ships with the package
+    _TraceMe = None
+
+    def _collecting():
+        return False
+
+#: Prefix of every annotation the engine puts on the profiler's host plane.
+PREFIX = "dampr."
 
 
 class _NoopSpan(object):
@@ -45,6 +70,9 @@ class _NoopSpan(object):
     def __exit__(self, exc_type, exc, tb):
         return False
 
+    def rename(self, name, **args):
+        return None
+
 
 _NOOP = _NoopSpan()
 
@@ -55,10 +83,46 @@ _stack = []
 _lock = threading.Lock()
 
 
-class _Span(object):
-    """A live ``with``-span: records one complete ("X") event on exit."""
+def annotate(cat, name):
+    """The profiler annotation ``dampr.<cat>.<name>``, entered, when a
+    session is collecting; else None.  The caller exits it."""
+    if not _collecting():
+        return None
+    ann = _TraceMe(PREFIX + cat + "." + name)
+    ann.__enter__()
+    return ann
 
-    __slots__ = ("_tracer", "_cat", "_name", "_lane", "_args", "_t0")
+
+class _Annotation(object):
+    """A ``with``-span with no tracer while a profiler session collects:
+    the annotation alone."""
+
+    __slots__ = ("_ann", "_cat", "_name")
+
+    def __init__(self, cat, name):
+        self._cat = cat
+        self._name = name
+        self._ann = None
+
+    def __enter__(self):
+        self._ann = annotate(self._cat, self._name)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+    def rename(self, name, **args):
+        return None
+
+
+class _Span(object):
+    """A live ``with``-span: records one complete ("X") event on exit, and
+    is a profiler annotation meanwhile when a session collects."""
+
+    __slots__ = ("_tracer", "_cat", "_name", "_lane", "_args", "_t0",
+                 "_ann")
 
     def __init__(self, tracer, cat, name, lane, args):
         self._tracer = tracer
@@ -67,8 +131,10 @@ class _Span(object):
         self._lane = lane
         self._args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        self._ann = annotate(self._cat, self._name)
         self._t0 = time.perf_counter()
         return self
 
@@ -76,7 +142,17 @@ class _Span(object):
         self._tracer._record(self._cat, self._name, self._t0,
                              time.perf_counter() - self._t0,
                              self._lane, self._args)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
+
+    def rename(self, name, **args):
+        """The name (and args) the tracer records at exit, for a site that
+        learns what it was only at its end; the profiler annotation keeps
+        the name it opened with."""
+        self._name = name
+        if args:
+            self._args = args
 
 
 class Tracer(object):
@@ -164,7 +240,9 @@ def stop(tracer):
 
 
 def enabled():
-    return _active is not None
+    """Would a span be recorded anywhere: a tracer, or a collecting
+    profiler session?"""
+    return _active is not None or _collecting()
 
 
 def now():
@@ -176,8 +254,18 @@ def now():
 def span(cat, name, lane=None, **args):
     t = _active
     if t is None:
-        return _NOOP
+        if not _collecting():
+            return _NOOP
+        return _Annotation(cat, name)
     return _Span(t, cat, name, lane, args or None)
+
+
+def record(cat, name, t0, dur, lane=None, **args):
+    """Record an interval the caller timed with its own clock pair (a
+    site that feeds one reading to several accounts)."""
+    t = _active
+    if t is not None:
+        t._record(cat, name, t0, dur, lane, args or None)
 
 
 def complete(cat, name, t0, lane=None, **args):
@@ -197,21 +285,20 @@ def instant(cat, name, lane=None, **args):
 
 def timed_iter(items, cat, name, lane=None):
     """Wrap an iterator so each ``next()`` is recorded as one span (the
-    overlapped codec producer's per-window accounting).  Returns ``items``
-    unchanged when tracing is off — zero per-item overhead."""
-    t = _active
-    if t is None:
+    overlapped codec producer's per-window accounting; the ``next()`` that
+    finds the end is one too).  Returns ``items`` unchanged when tracing
+    is off — zero per-item overhead."""
+    if not enabled():
         return items
+    end = object()
 
     def gen():
         it = iter(items)
         while True:
-            t0 = time.perf_counter()
-            try:
-                item = next(it)
-            except StopIteration:
+            with span(cat, name, lane=lane):
+                item = next(it, end)
+            if item is end:
                 return
-            t.complete(cat, name, t0, lane=lane)
             yield item
 
     return gen()

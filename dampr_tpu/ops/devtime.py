@@ -25,13 +25,28 @@ Buckets, accumulated process-wide behind one lock:
                 the serial non-overlapped codec cost is then the whole
                 ``codec`` bucket, since the job thread that runs the
                 codec is by construction not folding meanwhile.
+- ``scan_feed``, ``scan_wait``, ``scan_drain``: WALL-CLOCK unions of the
+                lowered scan's phases (:class:`phase`, at the dispatch and
+                drain sites of ``ops.lower`` and ``ops.handoff``).  Feed:
+                the host builds and launches a batch — token bounds, line
+                ids, the padded matrix, the program call (classic or
+                table probe).  Wait: the host is blocked on a program's
+                outputs (an explicit ``block_until_ready`` before every
+                fetch).  Drain: the fetch and the host work after it — key
+                decode, absorb into the accumulator, host regroup of
+                collisions and misses, the handoff finalize's fetch.  Each
+                bucket counts the wall time in which at least one thread
+                was inside its phase (a count of threads in the phase; the
+                interval opens at 0 -> 1 and closes at 1 -> 0), so
+                concurrent map slots count it once, never their
+                thread-seconds summed.
 
-Times are dispatch-site THREAD-seconds (``codec_wait`` excepted — it is
-a wall-clock interval union, never exceeding elapsed wall): concurrent
-pool workers each add their own elapsed time, so a bucket divided by
-wall time reads like CPU utilization (2.0 = two cores' worth per wall
-second) and can exceed 1.0 on multi-core hosts — same convention as
-`top`.  Thread-seconds inside ``track`` regions include any GIL waits
+Times are dispatch-site THREAD-seconds (``codec_wait`` and the ``scan_*``
+unions excepted — each is a wall-clock interval union, never exceeding
+elapsed wall): concurrent pool workers each add their own elapsed time,
+so a bucket divided by wall time reads like CPU utilization (2.0 = two
+cores' worth per wall second) and can exceed 1.0 on multi-core hosts —
+same convention as `top`.  Thread-seconds inside ``track`` regions include any GIL waits
 the region suffers, so under core contention the ``codec`` bucket is an
 UPPER bound on codec CPU — one more reason the critical-path question
 needs the interval-union bucket.  A jax call that returns an unrealized
@@ -45,10 +60,18 @@ import contextlib
 import threading
 import time
 
+from ..obs import profile as _profile
+from ..obs import trace as _trace
+
 _lock = threading.Lock()
+#: The lowered scan's wall-clock-union buckets (see :class:`phase`).
+UNIONS = ("scan_feed", "scan_wait", "scan_drain")
 _counters = {"device": 0.0, "transfer": 0.0, "codec": 0.0,
              "codec_wait": 0.0}
+_counters.update(dict.fromkeys(UNIONS, 0.0))
 _active = {}  # (thread ident, kind) -> nesting depth inside track(kind)
+_inside = dict.fromkeys(UNIONS, 0)  # threads inside each union's phase
+_since = dict.fromkeys(UNIONS)      # open union interval's start, or None
 
 # codec_wait state: live overlap slots vs slots currently blocked on
 # their own producer's codec.  The union interval is open exactly while
@@ -112,6 +135,14 @@ def stalled_slots():
     return _stalled
 
 
+def _leave_locked(key):
+    depth = _active.get(key, 1) - 1
+    if depth:
+        _active[key] = depth
+    else:
+        _active.pop(key, None)
+
+
 @contextlib.contextmanager
 def track(kind):
     t0 = time.perf_counter()
@@ -135,12 +166,79 @@ def track(kind):
     finally:
         dt = time.perf_counter() - t0
         with _lock:
-            depth = _active.get(key, 1) - 1
-            if depth:
-                _active[key] = depth
-            else:
-                _active.pop(key, None)
+            _leave_locked(key)
             _counters[kind] += dt
+
+
+class phase(object):
+    """One timed region of the lowered scan, read by ONE clock pair that
+    feeds every account of the region:
+
+    - the wall-clock union bucket ``union`` (one of :data:`UNIONS`);
+    - the span ``<cat>/<name>`` (:mod:`..obs.trace`: the run's tracer,
+      and the profiler annotation ``dampr.<cat>.<name>`` when a session
+      collects);
+    - ``obs.profile``'s device sub-phase ``prof`` (``build``/``h2d``/
+      ``compute``/``d2h``) with ``nbytes``, set by the site, when
+      ``settings.profile`` is on;
+    - the thread-seconds bucket ``track`` (``codec``, with its
+      :func:`active_in` bookkeeping, or ``device``), where given.
+
+    Per batch or window, never per record; ``args`` ride the tracer's
+    span only."""
+
+    __slots__ = ("union", "cat", "name", "track", "prof", "args",
+                 "nbytes", "_key", "_ann", "_t0")
+
+    def __init__(self, union, cat, name, track=None, prof=None, **args):
+        self.union = union
+        self.cat = cat
+        self.name = name
+        self.track = track
+        self.prof = prof
+        self.args = args
+        self.nbytes = 0
+        self._key = None
+        self._ann = None
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._ann = _trace.annotate(self.cat, self.name)
+        if self.track == "codec":
+            self._key = (threading.get_ident(), "codec")
+        u = self.union
+        with _lock:
+            # The clock is read under the lock: the union's transitions
+            # then happen in the order of their timestamps.
+            t0 = self._t0 = time.perf_counter()
+            n = _inside[u]
+            if not n:
+                _since[u] = t0
+            _inside[u] = n + 1
+            if self._key is not None:
+                _active[self._key] = _active.get(self._key, 0) + 1
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        u = self.union
+        with _lock:
+            t1 = time.perf_counter()
+            dt = t1 - self._t0
+            n = _inside[u] - 1
+            _inside[u] = n
+            if not n and _since[u] is not None:
+                _counters[u] += t1 - _since[u]
+                _since[u] = None
+            if self.track is not None:
+                _counters[self.track] += dt
+                if self._key is not None:
+                    _leave_locked(self._key)
+        _trace.record(self.cat, self.name, self._t0, dt, **self.args)
+        if self.prof is not None:
+            _profile.device_add(self.prof, dt, self.nbytes)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
 
 
 def active_in(thread_ident, kind):
@@ -180,8 +278,12 @@ def union_seconds(intervals):
 def snapshot():
     with _lock:
         out = dict(_counters)
+        now = time.perf_counter()
         if _all_since is not None:  # fold in the open stall interval
-            out["codec_wait"] += time.perf_counter() - _all_since
+            out["codec_wait"] += now - _all_since
+        for u in UNIONS:  # ... and every open scan-phase interval
+            if _since[u] is not None:
+                out[u] += now - _since[u]
         return out
 
 
@@ -191,8 +293,8 @@ def epoch():
     ``reset()`` this never disturbs other in-flight work's view — two
     sequential or concurrent ``run()`` calls each hold their own epoch and
     read their own deltas, while the process-wide counters stay monotone
-    (``snapshot()`` folds any open stall interval in, so ``codec_wait`` is
-    monotone across snapshots too)."""
+    (``snapshot()`` folds any open interval in, so ``codec_wait`` and the
+    ``scan_*`` unions are monotone across snapshots too)."""
     return snapshot()
 
 
@@ -209,5 +311,9 @@ def reset():
     with _lock:
         for k in _counters:
             _counters[k] = 0.0
+        now = time.perf_counter()
         if _all_since is not None:  # an open interval restarts at zero
-            _all_since = time.perf_counter()
+            _all_since = now
+        for u in UNIONS:
+            if _since[u] is not None:
+                _since[u] = now

@@ -133,8 +133,8 @@ def _table_program(n, L, cap, Lcap, dedup, acc_dtype_name, dedup_k=0):
 
     W = min(L, Lcap)
 
-    def program(mat, lens, lines, tab_h1, tab_slot, tab_mat, tab_lens,
-                acc):
+    def table_probe(mat, lens, lines, tab_h1, tab_slot, tab_mat, tab_lens,
+                    acc):
         h1 = jnp.full((n,), _FNV_OFFSET1, dtype=jnp.uint32)
 
         def body(c, a):
@@ -193,7 +193,7 @@ def _table_program(n, L, cap, Lcap, dedup, acc_dtype_name, dedup_k=0):
         return acc, miss, jnp.sum(miss.astype(jnp.int32))
 
     kwargs = {"donate_argnums": (7,)} if _donate_ok() else {}
-    return jax.jit(program, **kwargs)
+    return jax.jit(table_probe, **kwargs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -202,9 +202,11 @@ def _scatter_program():
     fallback windows) fold into the accumulator with one scatter-add."""
     import jax
 
+    def table_scatter(acc, slots, vals):
+        return acc.at[slots].add(vals)
+
     kwargs = {"donate_argnums": (0,)} if _donate_ok() else {}
-    return jax.jit(lambda acc, slots, vals: acc.at[slots].add(vals),
-                   **kwargs)
+    return jax.jit(table_scatter, **kwargs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -213,12 +215,12 @@ def _set_rows_program():
     (the padding) drop."""
     import jax
 
-    def program(tab_mat, tab_lens, slots, rows, lens):
+    def table_set_rows(tab_mat, tab_lens, slots, rows, lens):
         return (tab_mat.at[slots].set(rows, mode="drop"),
                 tab_lens.at[slots].set(lens, mode="drop"))
 
     kwargs = {"donate_argnums": (0, 1)} if _donate_ok() else {}
-    return jax.jit(program, **kwargs)
+    return jax.jit(table_set_rows, **kwargs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -230,14 +232,14 @@ def _finalize_program(n_partitions):
     import jax
     import jax.numpy as jnp
 
-    def program(acc, idx, seg_of):
+    def table_finalize(acc, idx, seg_of):
         vals = jnp.take(acc, idx)
         live = seg_of < n_partitions
         psum = jax.ops.segment_sum(vals, seg_of, num_segments=n_partitions)
         vmin = jnp.min(jnp.where(live, vals, jnp.iinfo(vals.dtype).max))
         return vals, psum, vmin
 
-    return jax.jit(program)
+    return jax.jit(table_finalize)
 
 
 def _slice(x, start, width):
@@ -251,8 +253,10 @@ def _slice_program(width):
     import jax
     from jax import lax
 
-    return jax.jit(lambda x, start: lax.dynamic_slice(x, (start,),
-                                                      (width,)))
+    def lane_slice(x, start):
+        return lax.dynamic_slice(x, (start,), (width,))
+
+    return jax.jit(lane_slice)
 
 
 def group_token_rows(buf, starts, lens, lines, dedup):
@@ -570,7 +574,16 @@ class HandoffVocab(object):
         """Launch the probe-and-count program over one padded batch; the
         accumulator advances asynchronously (double-buffered like the
         classic dispatch).  Returns the drain handle, or None when the
-        job must degrade (overflow guard)."""
+        job must degrade (overflow guard).  The whole launch — guards,
+        table sync, program call — is the feed phase ``table-probe``."""
+        with devtime.phase("scan_feed", "handoff", "table-probe",
+                           track="device", prof="h2d", tokens=int(n),
+                           bytes=int(mat.nbytes)) as ph:
+            ph.nbytes = mat.nbytes
+            return self._dispatch(mat, lens_p, lines_p, starts, lens,
+                                  lines, n)
+
+    def _dispatch(self, mat, lens_p, lines_p, starts, lens, lines, n):
         import jax.numpy as jnp
 
         npad, L = mat.shape
@@ -595,13 +608,10 @@ class HandoffVocab(object):
         nbytes = mat.nbytes + lens_p.nbytes + lines_p.nbytes
         if self.store is not None:
             self.store.count_h2d(nbytes)
-        with devtime.track("device"), _trace.span(
-                "handoff", "table-probe", tokens=int(n),
-                bytes=int(nbytes)):
-            self.acc, miss, n_miss = fn(
-                jnp.asarray(mat), jnp.asarray(lens_p),
-                jnp.asarray(lines_p), self.tab_h1, self.tab_slot,
-                self.tab_mat, self.tab_lens, self.acc)
+        self.acc, miss, n_miss = fn(
+            jnp.asarray(mat), jnp.asarray(lens_p), jnp.asarray(lines_p),
+            self.tab_h1, self.tab_slot, self.tab_mat, self.tab_lens,
+            self.acc)
         self.table_batches += 1
         return _TableBatch(miss, n_miss, starts, lens, lines, n, npad)
 
@@ -614,17 +624,28 @@ class HandoffVocab(object):
         counts, which the degrade flush drops) — the caller must emit
         ``batch.miss_idx``'s tokens through the exact host path or they
         are lost."""
-        n_miss = int(batch.n_miss)
-        fetched = 4
+        import jax
+
+        with devtime.phase("scan_wait", "device", "wait", track="device",
+                           prof="compute", tokens=int(batch.n)):
+            jax.block_until_ready(batch.n_miss)
+        with devtime.phase("scan_drain", "device", "fetch", track="device",
+                           prof="d2h") as ph:
+            n_miss = int(batch.n_miss)
+            fetched = 4
+            if n_miss:
+                miss = np.asarray(batch.miss)[:batch.n]
+                fetched += batch.npad  # the bool lane
+            ph.nbytes = fetched
         ok = True
         if n_miss:
-            miss = np.asarray(batch.miss)[:batch.n]
-            fetched += batch.npad  # the bool lane
-            idx = np.flatnonzero(miss)
-            batch.miss_idx = idx
-            ok = self._absorb_miss_tokens(
-                buf, batch.starts[idx], batch.lens[idx],
-                batch.lines[idx] if batch.lines is not None else None)
+            with devtime.phase("scan_drain", "scan", "absorb-misses",
+                               tokens=n_miss):
+                idx = np.flatnonzero(miss)
+                batch.miss_idx = idx
+                ok = self._absorb_miss_tokens(
+                    buf, batch.starts[idx], batch.lens[idx],
+                    batch.lines[idx] if batch.lines is not None else None)
         if self.store is not None:
             self.store.count_d2h(fetched)
             if ok:
@@ -750,8 +771,8 @@ class HandoffVocab(object):
         for s, e, o in zip(starts, ends, offs):
             idx[o:o + e - s] = perm[s:e]
             seg_of[o:o + e - s] = sorted_pid[s]
-        with devtime.track("device"), _trace.span(
-                "handoff", "finalize", records=int(self.nslots)):
+        with devtime.phase("scan_drain", "handoff", "finalize-fetch",
+                           track="device", records=int(self.nslots)):
             vals, psum, vmin = _finalize_program(n_partitions)(
                 self.acc, jnp.asarray(idx), jnp.asarray(seg_of))
             psum = np.asarray(psum)

@@ -116,7 +116,7 @@ def _fnv_jit():
     import jax.numpy as jnp
     from jax import lax
 
-    def kernel(mat, lens):
+    def hash_tokens(mat, lens):
         n, L = mat.shape
         h1 = jnp.full((n,), _FNV_OFFSET1, dtype=jnp.uint32)
         h2 = jnp.full((n,), _FNV_OFFSET2, dtype=jnp.uint32)
@@ -132,7 +132,7 @@ def _fnv_jit():
         h1, h2 = lax.fori_loop(0, L, body, (h1, h2))
         return h1, h2
 
-    return jax.jit(kernel)
+    return jax.jit(hash_tokens)
 
 
 @functools.lru_cache(maxsize=None)
@@ -149,12 +149,12 @@ def _mix_int_jit():
         h = h ^ (h >> 16)
         return h
 
-    def kernel(lo, hi):
+    def hash_mix(lo, hi):
         h1 = fmix(lo ^ jnp.uint32(0x9E3779B9), hi)
         h2 = fmix(lo ^ jnp.uint32(0x85EBCA6B), hi ^ jnp.uint32(0xC2B2AE35))
         return h1, h2
 
-    return jax.jit(kernel)
+    return jax.jit(hash_mix)
 
 
 def _use_device(n):

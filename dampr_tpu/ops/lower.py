@@ -38,12 +38,10 @@ batch boundaries are unobservable in the results.
 """
 
 import functools
-import time
 
 import numpy as np
 
 from .. import settings
-from ..obs import profile as _profile
 from ..obs import trace as _trace
 from . import devtime
 from .segment import sort_perm
@@ -100,7 +98,7 @@ def _token_fold_jit(n, L, dedup, pallas, interpret):
 
     from .hashing import _FNV_OFFSET1, _FNV_OFFSET2, _FNV_PRIME1, _FNV_PRIME2
 
-    def program(mat, lens, lines):
+    def token_fold(mat, lens, lines):
         # -- dual-lane FNV over the byte columns (== hashing._fnv_jit) --
         h1 = jnp.full((n,), _FNV_OFFSET1, dtype=jnp.uint32)
         h2 = jnp.full((n,), _FNV_OFFSET2, dtype=jnp.uint32)
@@ -182,7 +180,7 @@ def _token_fold_jit(n, L, dedup, pallas, interpret):
         rep_orig = jnp.take(perm, start_pos)  # original index of each rep
         return sh1, sh2, tot, live, rep_orig, collisions
 
-    return jax.jit(program)
+    return jax.jit(token_fold)
 
 
 def _lower_interpret():
@@ -375,9 +373,8 @@ class DeviceTokenFoldSink(object):
     def _pad_batch(self, buf, starts, lens, lines):
         """Shared padded-matrix construction for both program shapes."""
         n = len(starts)
-        prof = _profile.active()
-        t0p = time.perf_counter() if prof is not None else 0.0
-        with devtime.track("codec"):
+        with devtime.phase("scan_feed", "scan", "pad", track="codec",
+                           prof="build") as ph:
             L = _len_bucket(lens.max())
             npad = max(_pow2(n),
                        8192 if settings.lower_pallas_segfold else 8)
@@ -391,18 +388,16 @@ class DeviceTokenFoldSink(object):
             lines_p = np.zeros(npad, dtype=np.int32)
             if lines is not None:
                 lines_p[:n] = lines
-        if prof is not None:
-            prof.device_add("build", time.perf_counter() - t0p,
-                            mat.nbytes)
+            ph.nbytes = mat.nbytes
         return mat, lens_p, lines_p
 
     def _dispatch(self, buf, starts, lens, lines):
         """Pad one batch to its shape bucket and launch the classic
         program; h2d payload bytes are charged to the store's HBM
-        counters.  Under ``settings.profile`` the loop's sub-phases
-        decompose: ``build`` (padded-matrix construction, host) and
-        ``h2d`` (program dispatch + argument feed) here, ``compute``/
-        ``d2h`` at drain."""
+        counters.  The feed's phases (``devtime.phase``) are ``pad``
+        (``build`` under ``settings.profile``) and ``dispatch`` (the
+        program call and its argument feed, ``h2d``); ``wait``
+        (``compute``) and ``fetch`` (``d2h``) follow at drain."""
         n = len(starts)
         from .. import faults as _faults
 
@@ -410,7 +405,6 @@ class DeviceTokenFoldSink(object):
         # job and rides the job retry loop (the whole-chunk fallback
         # paths keep results byte-identical on re-execution).
         _faults.check("device_dispatch")
-        prof = _profile.active()
         mat, lens_p, lines_p = self._pad_batch(buf, starts, lens, lines)
         npad, L = mat.shape
         fn = _token_fold_jit(npad, L, self.dedup,
@@ -419,14 +413,12 @@ class DeviceTokenFoldSink(object):
         nbytes = mat.nbytes + lens_p.nbytes + lines_p.nbytes
         if self.store is not None:
             self.store.count_h2d(nbytes)
-        t0p = time.perf_counter() if prof is not None else 0.0
-        with devtime.track("device"), _trace.span(
-                "device", "map-fold", tokens=n, bytes=nbytes):
+        # Dispatch is async: this phase is the launch + feed cost; the
+        # program's run time surfaces as ``wait`` at drain.
+        with devtime.phase("scan_feed", "device", "dispatch", track="device",
+                           prof="h2d", tokens=n, bytes=nbytes) as ph:
+            ph.nbytes = nbytes
             out = fn(mat, lens_p, lines_p)
-        if prof is not None:
-            # Dispatch is async: this phase is the launch + feed cost;
-            # the program's run time surfaces as ``compute`` at drain.
-            prof.device_add("h2d", time.perf_counter() - t0p, nbytes)
         self.batches += 1
         return _Batch(out, starts, lens, n)
 
@@ -441,13 +433,8 @@ class DeviceTokenFoldSink(object):
             _faults.check("device_dispatch")
             mat, lens_p, lines_p = self._pad_batch(buf, starts, lens,
                                                    lines)
-            prof = _profile.active()
-            t0p = time.perf_counter() if prof is not None else 0.0
             batch = self._hv.dispatch(mat, lens_p, lines_p, starts, lens,
                                       lines, len(starts))
-            if prof is not None:
-                prof.device_add("h2d", time.perf_counter() - t0p,
-                                mat.nbytes)
             if batch is not None:
                 self.batches += 1
                 return batch
@@ -514,28 +501,29 @@ class DeviceTokenFoldSink(object):
         partial-count Block (vocabulary-sized).  Collisions re-group the
         batch on host.  With the handoff live, survivors seed the device
         vocabulary instead of emitting (returns None)."""
-        prof = _profile.active()
-        with devtime.track("device"), _trace.span("device", "drain",
-                                                  tokens=batch.n):
-            if prof is not None:
-                # Split blocked-on-program time from the result fetch:
-                # block_until_ready waits for the compute, the asarray
-                # conversions below are then pure d2h movement.
-                import jax
+        import jax
 
-                t0p = time.perf_counter()
-                jax.block_until_ready(batch.out)
-                t1p = time.perf_counter()
-                prof.device_add("compute", t1p - t0p)
+        with devtime.phase("scan_wait", "device", "wait", track="device",
+                           prof="compute", tokens=batch.n):
+            jax.block_until_ready(batch.out)
+        # The outputs are ready: the conversions are pure d2h movement.
+        with devtime.phase("scan_drain", "device", "fetch", track="device",
+                           prof="d2h") as ph:
             sh1, sh2, tot, live, rep_orig, collisions = (
                 np.asarray(a) for a in batch.out)
-        d2h_bytes = (sh1.nbytes + sh2.nbytes + tot.nbytes
-                     + live.nbytes + rep_orig.nbytes)
-        if prof is not None:
-            prof.device_add("d2h", time.perf_counter() - t1p, d2h_bytes)
+            ph.nbytes = d2h_bytes = (sh1.nbytes + sh2.nbytes + tot.nbytes
+                                     + live.nbytes + rep_orig.nbytes)
         if self.store is not None:
             self.store.count_d2h(d2h_bytes)
-        if int(collisions):
+        with devtime.phase("scan_drain", "scan", "decode"):
+            return self._decode(buf, batch, out, sh1, sh2, tot, live,
+                                rep_orig, int(collisions))
+
+    def _decode(self, buf, batch, out, sh1, sh2, tot, live, rep_orig,
+                collisions):
+        """The host half of a classic drain: decode the survivors' keys
+        and emit them, or seed the handoff vocabulary with them."""
+        if collisions:
             lines = None
             if self.dedup:
                 # line ids were consumed by the program; rebuild them for
@@ -650,14 +638,18 @@ class DeviceTokenFoldSink(object):
                         self.store.count_d2h_avoided(
                             CLASSIC_DRAIN_BYTES_PER_SLOT * len(blk))
                 return out
-        with devtime.track("codec"):
+        with devtime.phase("scan_feed", "scan", "bounds", track="codec",
+                           bytes=len(buf)):
             if self.lower:
                 buf = _LOWER[buf]
             starts, lens = _token_bounds(buf, self.mode)
         n = len(starts)
         if n == 0:
             return ()
-        line_id = self._line_ids(buf, starts) if self.dedup else None
+        line_id = None
+        if self.dedup:
+            with devtime.phase("scan_feed", "scan", "line-ids"):
+                line_id = self._line_ids(buf, starts)
 
         short = lens <= _SHORT_TOKEN
         long_idx = np.flatnonzero(~short)
@@ -721,7 +713,8 @@ class DeviceTokenFoldSink(object):
         path; at most one side is non-empty."""
         if self._hv is None:
             return (), {}
-        return self._hv.finalize(store, n_partitions)
+        with _trace.span("handoff", "finalize"):
+            return self._hv.finalize(store, n_partitions)
 
 
 def device_window_sink(mapper, store=None, handoff=False, jobs=1):

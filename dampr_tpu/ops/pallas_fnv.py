@@ -69,11 +69,12 @@ def _build(L, interpret):
         h1_ref[0, :] = h1
         h2_ref[0, :] = h2
 
-    def run(mat_t, lens):
+    def fnv_hash(mat_t, lens):
         n = mat_t.shape[1]
         grid = (n // _ROW_TILE,)
         return pl.pallas_call(
             kernel,
+            name="fnv_hash",
             out_shape=(jax.ShapeDtypeStruct((1, n), jnp.int32),
                        jax.ShapeDtypeStruct((1, n), jnp.int32)),
             grid=grid,
@@ -88,7 +89,7 @@ def _build(L, interpret):
             interpret=interpret,
         )(mat_t, lens)
 
-    return jax.jit(run)
+    return jax.jit(fnv_hash)
 
 
 def fnv_pallas(mat, lens, interpret=False):

@@ -231,9 +231,10 @@ def _segfold_call(n_tiles, interpret):
         per_tile = R // 8
         return (jnp.minimum((i + 1) * per_tile, n_tiles * per_tile - 1), 0)
 
-    def call(h1, h2, v, inv):
+    def segfold(h1, h2, v, inv):
         return pl.pallas_call(
             kernel,
+            name="segfold",
             grid=(n_tiles,),
             in_specs=[
                 pl.BlockSpec((R, L), tile_map),
@@ -256,7 +257,7 @@ def _segfold_call(n_tiles, interpret):
             interpret=interpret,
         )(h1, h2, v, inv, h1, h2, inv)
 
-    return jax.jit(call)
+    return jax.jit(segfold)
 
 
 def segfold_sorted(h1, h2, v, inv, interpret=False):

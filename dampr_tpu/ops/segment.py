@@ -114,11 +114,11 @@ def _lexsort_jit():
     import jax
     import jax.numpy as jnp
 
-    def kernel(valid, h1, h2):
+    def hash_lexsort(valid, h1, h2):
         perm = sort_perm(valid, h1, h2)
         return jnp.take(h1, perm), jnp.take(h2, perm), perm
 
-    return jax.jit(kernel)
+    return jax.jit(hash_lexsort)
 
 
 def hash_sort_perm(h1, h2):
@@ -294,7 +294,7 @@ def _segment_fold_jit(kind, num_segments):
     import jax
     import jax.numpy as jnp
 
-    def kernel(vals, seg_ids):
+    def segment_fold(vals, seg_ids):
         if kind == "sum":
             return jax.ops.segment_sum(vals, seg_ids, num_segments=num_segments)
         if kind == "min":
@@ -303,7 +303,7 @@ def _segment_fold_jit(kind, num_segments):
             return jax.ops.segment_max(vals, seg_ids, num_segments=num_segments)
         raise ValueError(kind)
 
-    return jax.jit(kernel)
+    return jax.jit(segment_fold)
 
 
 _NP_FOLD = {

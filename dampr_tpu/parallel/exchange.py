@@ -113,13 +113,13 @@ def _build_exchange(mesh, axis, capacity, gather=False):
         # mesh_keyed_fold's gather path).
         kwargs["check_vma"] = False
 
-    def program(bb, ln):
+    def blob_all_to_all(bb, ln):
         return jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(axis), P(axis)),
             out_specs=(out_spec, out_spec), **kwargs)(bb, ln)
 
-    return jax.jit(program)
+    return jax.jit(blob_all_to_all)
 
 
 #: Shape of the LAST exchange this process ran (observability): steps,
@@ -438,11 +438,11 @@ def _build_share(mesh, axis):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    def per_device(t):
+    def skew_share(t):
         return lax.all_gather(t, axis, tiled=True)
 
-    return jax.jit(jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
-                              out_specs=P(), check_vma=False))
+    return jax.jit(jax.shard_map(skew_share, mesh=mesh, in_specs=(P(axis),),
+                                 out_specs=P(), check_vma=False))
 
 
 _warned_no_clock = False

@@ -46,11 +46,11 @@ def _ring_allreduce_program(mesh, axis, op):
             acc = combine(acc, rot)
         return acc
 
-    def program(x):
+    def ring_allreduce(x):
         return jax.shard_map(
             per_device, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis))(x)
 
-    return jax.jit(program)
+    return jax.jit(ring_allreduce)
 
 
 def ring_allreduce(mesh, x, op="sum"):
@@ -89,11 +89,11 @@ def _ring_allgather_program(mesh, axis):
             rid = (rid - 1) % n_dev
         return jnp.concatenate(parts, axis=0)
 
-    def program(x):
+    def ring_allgather(x):
         return jax.shard_map(
             per_device, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis))(x)
 
-    return jax.jit(program)
+    return jax.jit(ring_allgather)
 
 
 def ring_allgather(mesh, x):

@@ -52,12 +52,12 @@ def _build_train_step(mesh, lr, axis):
     def global_loss(params, X, y):
         return jnp.mean(per_shard_loss(params, X, y))
 
-    def step(params, X, y):
+    def sgd_step(params, X, y):
         loss, grads = jax.value_and_grad(global_loss)(params, X, y)
         new_params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
         return new_params, loss
 
-    return jax.jit(step)
+    return jax.jit(sgd_step)
 
 
 def train_step(mesh, params, X, y, lr=0.1):

@@ -213,7 +213,7 @@ def _build_fold_program(mesh, n_dev, n_local, capacity, kind, v_dtype_name,
             out_valid = lax.all_gather(out_valid, axis, tiled=True)
         return fh1, fh2, fv, out_valid, total_dropped
 
-    def program(h1, h2, v, valid):
+    def mesh_fold(h1, h2, v, valid):
         out_spec = P() if gather else P(axis)
         kwargs = {}
         if gather:
@@ -227,7 +227,7 @@ def _build_fold_program(mesh, n_dev, n_local, capacity, kind, v_dtype_name,
             out_specs=(out_spec, out_spec, out_spec, out_spec, P()),
             **kwargs)(h1, h2, v, valid)
 
-    return jax.jit(program)
+    return jax.jit(mesh_fold)
 
 
 def _pad_pow2(n, floor=8):
@@ -410,14 +410,14 @@ def _concat_pad(padded):
     import jax
     import jax.numpy as jnp
 
-    def program(parts):
+    def concat_pad(parts):
         out = []
         for lane in range(4):
             cat = jnp.concatenate([p[lane] for p in parts])
             out.append(jnp.pad(cat, (0, padded - cat.shape[0])))
         return tuple(out)
 
-    return jax.jit(program)
+    return jax.jit(concat_pad)
 
 
 @functools.lru_cache(maxsize=None)
@@ -427,11 +427,11 @@ def _live_prefix_sort(n):
     import jax
     import jax.numpy as jnp
 
-    def kernel(h1, h2, v, ok):
+    def live_prefix_sort(h1, h2, v, ok):
         perm = sort_perm(jnp.where(ok == 1, jnp.uint32(0), jnp.uint32(1)))
         return tuple(jnp.take(a, perm) for a in (h1, h2, v))
 
-    return jax.jit(kernel)
+    return jax.jit(live_prefix_sort)
 
 
 def compact_partial(part):
@@ -479,13 +479,13 @@ def mesh_global_sum(mesh, v):
 
     axis = settings.mesh_axis
 
-    def per_device(x):
+    def global_sum(x):
         return jax.lax.psum(jnp.sum(x), axis)
 
     from ..obs import trace as _trace
 
     with _trace.span("collective", "global-sum", records=int(total)):
         out = jax.jit(jax.shard_map(
-            per_device, mesh=mesh,
+            global_sum, mesh=mesh,
             in_specs=(P(axis),), out_specs=P()))(pv)
     return np.asarray(out).item()

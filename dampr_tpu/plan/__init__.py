@@ -40,6 +40,7 @@ that fired.  See ``docs/plan.md``.
 import time
 
 from .. import settings
+from ..obs import trace as _trace
 from . import cost, explain, ir, lower, passes, pipeline
 from .explain import explain_text
 from .ir import graph_signature
@@ -85,6 +86,11 @@ def apply_to_runner(runner, outputs):
     graph = getattr(runner, "graph", None)
     if graph is None or not hasattr(graph, "stages"):
         return None
+    with _trace.span("plan", "apply"):
+        return _apply(runner, graph, outputs)
+
+
+def _apply(runner, graph, outputs):
     t0 = time.perf_counter()
     if not settings.optimize:
         report = empty_report(graph, enabled=False)

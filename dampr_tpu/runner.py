@@ -206,48 +206,41 @@ def _overlap_stream(items, store, size_of=None):
     thread = threading.Thread(target=produce, daemon=True,
                               name="dampr-tpu-codec")
 
+    def wait_item():
+        # Stall accounting per poll slice: the slot counts as
+        # blocked-on-codec only while THIS job's producer thread is
+        # executing the native codec (devtime.active_in) — wait caused by
+        # producer-side IO/Python is real pipeline wait but is not
+        # codec-attributable (the ``codec`` bucket doesn't count it
+        # either), and a sibling job's codec is not what this fold is
+        # blocked on.
+        while True:
+            stalled = devtime.active_in(thread.ident, "codec")
+            if stalled:
+                devtime.slot_stall()
+            try:
+                return q.get(timeout=0.05)
+            except _queue.Empty:
+                pass
+            finally:
+                if stalled:
+                    devtime.slot_unstall()
+            if state["done"] and q.empty():
+                return _END, 0
+
     def gen():
         thread.start()
         devtime.slot_enter()
         try:
             while True:
-                # Stall accounting per poll slice: the slot counts as
-                # blocked-on-codec only while THIS job's producer thread
-                # is executing the native codec (devtime.active_in) —
-                # wait caused by producer-side IO/Python is real pipeline
-                # wait but is not codec-attributable (the ``codec``
-                # bucket doesn't count it either), and a sibling job's
-                # codec is not what this fold is blocked on.
-                wait_t0 = 0.0
-                while True:
-                    try:
-                        item, nb = q.get_nowait()
-                        break
-                    except _queue.Empty:
-                        pass
-                    if not wait_t0:
-                        wait_t0 = _trace.now()
-                    stalled = devtime.active_in(thread.ident, "codec")
-                    if stalled:
-                        devtime.slot_stall()
-                    try:
-                        item, nb = q.get(timeout=0.05)
-                        got = True
-                    except _queue.Empty:
-                        got = False
-                    finally:
-                        if stalled:
-                            devtime.slot_unstall()
-                    if got:
-                        break
-                    if state["done"] and q.empty():
-                        item, nb = _END, 0
-                        break
-                if wait_t0:
+                try:
+                    item, nb = q.get_nowait()
+                except _queue.Empty:
                     # Consumer-side pipeline wait (this slot's fold was
                     # blocked on its producer) — the per-slot view of what
                     # devtime's codec_wait aggregates across all slots.
-                    _trace.complete("stall", "pipe-wait", wait_t0)
+                    with _trace.span("stall", "pipe-wait"):
+                        item, nb = wait_item()
                     _metrics.counter_add("overlap.consumer_stalls", 1)
                 if item is _END:
                     if state["err"] is not None:
@@ -306,6 +299,12 @@ def _overlap_stream(items, store, size_of=None):
             drain()
 
     return gen()
+
+
+def _stage_kind(stage):
+    """``map``, ``reduce`` or ``sink``: what a stage is, by its type."""
+    return ("map" if isinstance(stage, GMap) else
+            "reduce" if isinstance(stage, GReduce) else "sink")
 
 
 class _FoldDeclined(Exception):
@@ -370,6 +369,10 @@ class _StreamFolder(object):
         if len(series) > 512:
             del series[::2]
 
+    def _must_wait(self, nb):
+        return (self._pending > 0 and self._pending + nb > self.bound
+                and not self._disabled)
+
     def publish(self, idx, mapping):
         """Dispatch-thread side: charge, bound, enqueue.  ``mapping`` is
         the committed job result ({pid: [refs]}); the folder may replace
@@ -381,28 +384,24 @@ class _StreamFolder(object):
                  for ref in refs)
         if nb <= 0:
             return
-        wait_t0 = 0.0
         with self._cv:
-            while (self._pending > 0 and self._pending + nb > self.bound
-                    and not self._disabled):
-                if not wait_t0:
-                    wait_t0 = _trace.now()
-                self._cv.wait(0.05)
+            if self._must_wait(nb):
+                # "stream-wait" (not the overlap executor's "pipe-wait"):
+                # critpath classifies this name as pipeline-stall, whose
+                # doctor fix (raise pipeline_queue_bytes) differs from
+                # the overlap knobs.
+                with _trace.span("stall", "stream-wait"):
+                    wait_t0 = time.perf_counter()
+                    while self._must_wait(nb):
+                        self._cv.wait(0.05)
+                    self.stats["stall_seconds"] += (time.perf_counter()
+                                                    - wait_t0)
             if self._disabled:
-                if wait_t0:
-                    self.stats["stall_seconds"] += _trace.now() - wait_t0
                 return
             self._pending += nb
             self.stats["queue_peak_bytes"] = max(
                 self.stats["queue_peak_bytes"], self._pending)
             self._sample_depth()
-        if wait_t0:
-            self.stats["stall_seconds"] += _trace.now() - wait_t0
-            # "stream-wait" (not the overlap executor's "pipe-wait"):
-            # critpath classifies this name as pipeline-stall, whose
-            # doctor fix (raise pipeline_queue_bytes) differs from the
-            # overlap knobs.
-            _trace.complete("stall", "stream-wait", wait_t0)
         self.store.reserve_overlap(nb)
         self.stats["published"] += 1
         self.stats["bytes_in"] += nb
@@ -1534,8 +1533,9 @@ class MTRunner(object):
                                     run_mode=run_mode, pin=pin)
         replaced = {}
         try:
+            # A broadcast join's jobs trace as "cross".
             results = self._pool_run(
-                job, chunks, n_maps, label="map",
+                job, chunks, n_maps, label="cross" if supplementary else "map",
                 speculative=self._speculation_ok(stage),
                 on_result=folder.publish if folder is not None else None)
         finally:
@@ -3442,8 +3442,9 @@ class MTRunner(object):
             try:
                 # Built on failure too: a partial timeline + stage stats
                 # is exactly what a crashed run's postmortem needs.
-                self._finalize_obs(wall_start, time.time() - wall_start,
-                                   devtime.delta(epoch))
+                with _trace.span("run", "finalize"):
+                    self._finalize_obs(wall_start, time.time() - wall_start,
+                                       devtime.delta(epoch))
             except Exception:
                 log.warning("stats/trace finalize failed", exc_info=True)
             finally:
@@ -3818,37 +3819,41 @@ class MTRunner(object):
             # Run-history corpus: one compact record per FINALIZED run
             # (failed runs would poison the adaptation medians) — the
             # accumulated telemetry plan/cost.py and doctor consume.
-            from .obs import history as _history
+            with _trace.span("run", "history"):
+                self._append_history(summary)
 
-            hpath = _history.append(summary)
-            proc = summary.get("process") or {}
-            if (hpath and settings.sentry_window > 0
-                    and not proc.get("process_id")):
-                # Long-horizon telemetry: fold this run into the compact
-                # per-fingerprint series (rank 0 only — sibling ranks'
-                # records are rank-tagged trail, not run-level points),
-                # then ask the sentry whether the newest point regressed
-                # against its trailing baseline.  Warn-only here: a
-                # finalized run must never fail on its own telemetry.
-                try:
-                    from .obs import sentry as _sentry
-                    from .obs import timeseries as _timeseries
+    def _append_history(self, summary):
+        from .obs import history as _history
 
-                    _timeseries.append_from_summary(summary)
-                    findings = _sentry.check_run(self.name, summary=summary)
-                    if findings:
-                        summary["sentry"] = findings
-                        if self.logstream is not None:
-                            for f in findings:
-                                self.logstream.emit(
-                                    "warn", "sentry-regression",
-                                    "{metric} regressed: {value:g} vs "
-                                    "baseline median {median:g} "
-                                    "(z={z:.1f}, window={window})".format(
-                                        **f),
-                                    data=f)
-                except Exception:
-                    log.warning("telemetry sentry failed", exc_info=True)
+        hpath = _history.append(summary)
+        proc = summary.get("process") or {}
+        if (hpath and settings.sentry_window > 0
+                and not proc.get("process_id")):
+            # Long-horizon telemetry: fold this run into the compact
+            # per-fingerprint series (rank 0 only — sibling ranks'
+            # records are rank-tagged trail, not run-level points),
+            # then ask the sentry whether the newest point regressed
+            # against its trailing baseline.  Warn-only here: a
+            # finalized run must never fail on its own telemetry.
+            try:
+                from .obs import sentry as _sentry
+                from .obs import timeseries as _timeseries
+
+                _timeseries.append_from_summary(summary)
+                findings = _sentry.check_run(self.name, summary=summary)
+                if findings:
+                    summary["sentry"] = findings
+                    if self.logstream is not None:
+                        for f in findings:
+                            self.logstream.emit(
+                                "warn", "sentry-regression",
+                                "{metric} regressed: {value:g} vs "
+                                "baseline median {median:g} "
+                                "(z={z:.1f}, window={window})".format(
+                                    **f),
+                                data=f)
+            except Exception:
+                log.warning("telemetry sentry failed", exc_info=True)
 
     def _run(self, outputs, cleanup=True):
         from . import resume as _resume
@@ -3864,7 +3869,8 @@ class MTRunner(object):
             # downgrade can never leak the flock fd (which would block
             # other runs' GC under this name until process exit).
             if guard.exclusive:
-                _resume.gc_unreferenced(self.store.root)
+                with _trace.span("run", "gc"):
+                    _resume.gc_unreferenced(self.store.root)
             guard.share()
             return self._run_stages(outputs, cleanup)
         except BaseException:
@@ -4009,7 +4015,6 @@ class MTRunner(object):
                 needed.update(stage.inputs)
         for sid, stage in enumerate(self.graph.stages):
             t0 = time.time()
-            t0_span = _trace.now()
             snap = self._pressure_snap()
             self.store.set_stage(sid)
             # Fault attribution context: the stage the exchange watchdog
@@ -4034,9 +4039,7 @@ class MTRunner(object):
                 # gauge, so the time series shows stage boundaries.
                 self._status.update({
                     "sid": sid + 1, "n_stages": n_stages,
-                    "kind": ("map" if isinstance(stage, GMap) else
-                             "reduce" if isinstance(stage, GReduce)
-                             else "sink"),
+                    "kind": _stage_kind(stage),
                     "stage_t0": t0, "jobs_total": 0, "jobs_done": 0})
                 _metrics.gauge_set("run.stage", sid)
 
@@ -4044,193 +4047,190 @@ class MTRunner(object):
                 log.info("Stage %s/%s skipped: every consumer was restored "
                          "from checkpoint", sid + 1, n_stages)
                 continue
-            log.info("Stage %s/%s: %r", sid + 1, n_stages, stage)
-            if sid in plan:
-                result, nrec = _resume.restore_stage(
-                    self.store.root, plan[sid])
-                env[stage.output] = result
-                if not isinstance(stage, GSink):
-                    to_delete.append(stage.output)
-                st = StageStats(sid, "resumed-" + (
-                    "map" if isinstance(stage, GMap) else
-                    "reduce" if isinstance(stage, GReduce) else "sink"))
-                st.n_jobs = 0
-                st.records_out = nrec
-                st.seconds = time.time() - t0
-                self._fill_stage_io(st, stage, env, result, snap)
-                self.stats.append(st)
-                _trace.complete("stage", "s{}:{}".format(sid, st.kind),
-                                t0_span, lane="stages", records=nrec)
-                log.info("Stage %s resumed: %s", sid + 1, st.as_dict())
-                continue
-            if reuse_ctl is not None and reuse_ctl.handles(sid):
-                out = None
-                try:
-                    out = reuse_ctl.apply(sid, stage, env)
-                except Exception:
-                    # Exactness contract: a cache entry that fails mid-
-                    # apply degrades to recompute, never to wrong
-                    # results — fall through to normal execution (the
-                    # need-set kept an incremental stage's tap input
-                    # live; full mounts were validated at plan time).
-                    log.warning("reuse: stage %s falls back to recompute",
-                                sid + 1, exc_info=True)
-                    reuse_ctl.note_fallback(sid)
-                if out is not None:
-                    result, nrec, rkind = out
+            with _trace.span("stage", "s{}:{}".format(
+                    sid, _stage_kind(stage)), lane="stages") as stage_span:
+                log.info("Stage %s/%s: %r", sid + 1, n_stages, stage)
+                if sid in plan:
+                    result, nrec = _resume.restore_stage(
+                        self.store.root, plan[sid])
                     env[stage.output] = result
                     if not isinstance(stage, GSink):
                         to_delete.append(stage.output)
-                    # Mounted frames persist no resume manifest: their
-                    # scratch hardlinks must be DELETED (not released)
-                    # at cleanup, exactly like volatile stages' blocks.
-                    volatile_sources.add(stage.output)
-                    self.store.drain_writes()
-                    st = StageStats(sid, rkind + "-" + (
-                        "map" if isinstance(stage, GMap) else
-                        "reduce" if isinstance(stage, GReduce) else "sink"))
+                    st = StageStats(sid, "resumed-" + _stage_kind(stage))
                     st.n_jobs = 0
                     st.records_out = nrec
                     st.seconds = time.time() - t0
                     self._fill_stage_io(st, stage, env, result, snap)
                     self.stats.append(st)
-                    _trace.complete("stage", "s{}:{}".format(sid, st.kind),
-                                    t0_span, lane="stages", records=nrec)
-                    log.info("Stage %s %s from reuse cache: %s", sid + 1,
-                             rkind, st.as_dict())
+                    stage_span.rename("s{}:{}".format(sid, st.kind),
+                                      records=nrec)
+                    log.info("Stage %s resumed: %s", sid + 1, st.as_dict())
                     continue
-            if isinstance(stage, GMap):
-                if (sid not in fused
-                        and len(stage.inputs) == 1
-                        and type(stage.mapper) is base.Map
-                        and stage.mapper.mapper is base._identity
-                        and stage.combiner is None
-                        and "binop" not in stage.options
-                        and not stage.options.get("memory")
-                        and not self.resume
-                        and stage.inputs[0] not in outputs
-                        and isinstance(env[stage.inputs[0]],
-                                       storage.PartitionSet)
-                        and env[stage.inputs[0]].n_partitions
-                        == self.n_partitions
-                        and self._alias_provenance_ok(stage,
-                                                      env[stage.inputs[0]])):
-                    # Identity checkpoint over an already-materialized
-                    # partition set: alias it instead of re-registering
-                    # (and re-spilling) every byte through a copy stage.
-                    # The alias takes over deletion duty from the input.
-                    result = env[stage.inputs[0]]
-                    nrec, njobs = result.total_records(), 0
-                    if stage.inputs[0] in to_delete:
-                        to_delete.remove(stage.inputs[0])
-                    env[stage.output] = result
-                    to_delete.append(stage.output)
-                    st = StageStats(sid, "map-alias")
-                    st.records_out = nrec
-                    st.seconds = time.time() - t0
-                    self._fill_stage_io(st, stage, env, result, snap)
-                    self.stats.append(st)
-                    _trace.complete("stage", "s{}:map-alias".format(sid),
-                                    t0_span, lane="stages", records=nrec)
-                    log.info("Stage %s aliased (identity checkpoint): %s",
-                             sid + 1, st.as_dict())
-                    continue
-                if sid in self._chain_results:
-                    # Consumer half of a streamed chain: its jobs already
-                    # ran, overlapped with the producer's, at the
-                    # producer's turn (docs/pipeline.md).  Normal stage
-                    # bookkeeping below still applies.
-                    result, nrec, njobs = self._chain_results.pop(sid)
-                elif sid in fused:
-                    result, nrec, njobs = fused.pop(sid)
-                else:
-                    chained = None
-                    hint = self._pipeline_edges.get(sid)
-                    if (hint is not None and hint["mode"] == "chain"
-                            and settings.pipeline_enabled()):
-                        chained = self._run_chain(
-                            sid, stage, hint["dst"], env)
-                    if chained is not None:
-                        result, nrec, njobs = chained
-                        to_delete.append(stage.output)
+                if reuse_ctl is not None and reuse_ctl.handles(sid):
+                    out = None
+                    try:
+                        out = reuse_ctl.apply(sid, stage, env)
+                    except Exception:
+                        # Exactness contract: a cache entry that fails mid-
+                        # apply degrades to recompute, never to wrong
+                        # results — fall through to normal execution (the
+                        # need-set kept an incremental stage's tap input
+                        # live; full mounts were validated at plan time).
+                        log.warning("reuse: stage %s falls back to recompute",
+                                    sid + 1, exc_info=True)
+                        reuse_ctl.note_fallback(sid)
+                    if out is not None:
+                        result, nrec, rkind = out
                         env[stage.output] = result
+                        if not isinstance(stage, GSink):
+                            to_delete.append(stage.output)
+                        # Mounted frames persist no resume manifest: their
+                        # scratch hardlinks must be DELETED (not released)
+                        # at cleanup, exactly like volatile stages' blocks.
+                        volatile_sources.add(stage.output)
                         self.store.drain_writes()
-                        st = StageStats(sid, "map-chained")
-                        st.n_jobs = njobs
+                        st = StageStats(sid, rkind + "-" + _stage_kind(stage))
+                        st.n_jobs = 0
                         st.records_out = nrec
                         st.seconds = time.time() - t0
                         self._fill_stage_io(st, stage, env, result, snap)
                         self.stats.append(st)
-                        _trace.complete(
-                            "stage", "s{}:map-chained".format(sid),
-                            t0_span, lane="stages", records=nrec,
-                            jobs=njobs)
-                        log.info("Stage %s chained into s%s: %s", sid + 1,
-                                 hint["dst"] + 1, st.as_dict())
+                        stage_span.rename("s{}:{}".format(sid, st.kind),
+                                          records=nrec)
+                        log.info("Stage %s %s from reuse cache: %s", sid + 1,
+                                 rkind, st.as_dict())
                         continue
-                    group = [g for g in self._scan_share_group(
-                        sid, stage, env)
-                        if g[0] not in plan
-                        and (required is None or g[0] in required)
-                        and (reuse_ctl is None
-                             or not reuse_ctl.handles(g[0]))]
-                    if group:
-                        members = [(sid, stage)] + group
-                        outs = self.run_map_group(
-                            [s for s, _ in members],
-                            [st for _, st in members], env)
-                        for (msid, _), out in zip(members[1:], outs[1:]):
-                            fused[msid] = out
-                        result, nrec, njobs = outs[0]
+                if isinstance(stage, GMap):
+                    if (sid not in fused
+                            and len(stage.inputs) == 1
+                            and type(stage.mapper) is base.Map
+                            and stage.mapper.mapper is base._identity
+                            and stage.combiner is None
+                            and "binop" not in stage.options
+                            and not stage.options.get("memory")
+                            and not self.resume
+                            and stage.inputs[0] not in outputs
+                            and isinstance(env[stage.inputs[0]],
+                                           storage.PartitionSet)
+                            and env[stage.inputs[0]].n_partitions
+                            == self.n_partitions
+                            and self._alias_provenance_ok(
+                                stage, env[stage.inputs[0]])):
+                        # Identity checkpoint over an already-materialized
+                        # partition set: alias it instead of re-registering
+                        # (and re-spilling) every byte through a copy stage.
+                        # The alias takes over deletion duty from the input.
+                        result = env[stage.inputs[0]]
+                        nrec, njobs = result.total_records(), 0
+                        if stage.inputs[0] in to_delete:
+                            to_delete.remove(stage.inputs[0])
+                        env[stage.output] = result
+                        to_delete.append(stage.output)
+                        st = StageStats(sid, "map-alias")
+                        st.records_out = nrec
+                        st.seconds = time.time() - t0
+                        self._fill_stage_io(st, stage, env, result, snap)
+                        self.stats.append(st)
+                        stage_span.rename("s{}:map-alias".format(sid),
+                                          records=nrec)
+                        log.info("Stage %s aliased (identity checkpoint): %s",
+                                 sid + 1, st.as_dict())
+                        continue
+                    if sid in self._chain_results:
+                        # Consumer half of a streamed chain: its jobs already
+                        # ran, overlapped with the producer's, at the
+                        # producer's turn (docs/pipeline.md).  Normal stage
+                        # bookkeeping below still applies.
+                        result, nrec, njobs = self._chain_results.pop(sid)
+                    elif sid in fused:
+                        result, nrec, njobs = fused.pop(sid)
                     else:
-                        result, nrec, njobs = self.run_map(sid, stage, env)
-                kind = "map"
-                to_delete.append(stage.output)
-            elif isinstance(stage, GReduce):
-                result, nrec, njobs = self.run_reduce(sid, stage, env)
-                kind = "reduce"
-                to_delete.append(stage.output)
-            elif isinstance(stage, GSink):
-                result, nrec, njobs = self.run_sink(sid, stage, env)
-                kind = "sink"  # durable: never cleaned up
-            else:
-                raise TypeError("Unknown stage type: {!r}".format(stage))
+                        chained = None
+                        hint = self._pipeline_edges.get(sid)
+                        if (hint is not None and hint["mode"] == "chain"
+                                and settings.pipeline_enabled()):
+                            chained = self._run_chain(
+                                sid, stage, hint["dst"], env)
+                        if chained is not None:
+                            result, nrec, njobs = chained
+                            to_delete.append(stage.output)
+                            env[stage.output] = result
+                            self.store.drain_writes()
+                            st = StageStats(sid, "map-chained")
+                            st.n_jobs = njobs
+                            st.records_out = nrec
+                            st.seconds = time.time() - t0
+                            self._fill_stage_io(st, stage, env, result, snap)
+                            self.stats.append(st)
+                            stage_span.rename(
+                                "s{}:map-chained".format(sid),
+                                records=nrec, jobs=njobs)
+                            log.info("Stage %s chained into s%s: %s", sid + 1,
+                                     hint["dst"] + 1, st.as_dict())
+                            continue
+                        group = [g for g in self._scan_share_group(
+                            sid, stage, env)
+                            if g[0] not in plan
+                            and (required is None or g[0] in required)
+                            and (reuse_ctl is None
+                                 or not reuse_ctl.handles(g[0]))]
+                        if group:
+                            members = [(sid, stage)] + group
+                            outs = self.run_map_group(
+                                [s for s, _ in members],
+                                [st for _, st in members], env)
+                            for (msid, _), out in zip(members[1:], outs[1:]):
+                                fused[msid] = out
+                            result, nrec, njobs = outs[0]
+                        else:
+                            result, nrec, njobs = self.run_map(sid, stage, env)
+                    kind = "map"
+                    to_delete.append(stage.output)
+                elif isinstance(stage, GReduce):
+                    result, nrec, njobs = self.run_reduce(sid, stage, env)
+                    kind = "reduce"
+                    to_delete.append(stage.output)
+                elif isinstance(stage, GSink):
+                    result, nrec, njobs = self.run_sink(sid, stage, env)
+                    kind = "sink"  # durable: never cleaned up
+                else:
+                    raise TypeError("Unknown stage type: {!r}".format(stage))
 
-            env[stage.output] = result
-            # Stage-boundary write barrier: every spill this stage's
-            # registration pressure queued publishes now, so per-stage
-            # spill attribution stays causal and checkpoint persistence
-            # below sees settled refs (a ref mid-write has no path yet
-            # and would be pointlessly re-written).
-            self.store.drain_writes()
-            if self.resume:
-                _resume.persist_stage(
-                    self.store, sid, stage_fps[sid], result, nrec)
-                if _resume.is_volatile(stage_fps[sid]):
-                    volatile_sources.add(stage.output)
-            if reuse_ctl is not None:
-                # Cross-run publish rides the same settled-refs barrier
-                # as checkpoint persistence: on-disk blocks hardlink in
-                # for free, RAM blocks encode once.  Never fails the
-                # run; chaos/quarantined runs are gated off inside.
-                reuse_ctl.maybe_publish(sid, stage, result, nrec)
-            # Ride the plan's shuffle choice on the stage's materialized
-            # partitions: lazily-read sorted outputs (sort_by) decide
-            # host-vs-mesh range redistribution at read time, after the
-            # stage walk is gone.
-            if isinstance(result, storage.PartitionSet):
-                result.shuffle_target = self._shuffle_targets.get(sid)
-            st = StageStats(sid, kind)
-            st.target = (stage.options or {}).get("exec_target", "host")
-            st.shuffle_target = self._shuffle_targets.get(sid)
-            st.n_jobs = njobs
-            st.records_out = nrec
-            st.seconds = time.time() - t0
-            self._fill_stage_io(st, stage, env, result, snap)
-            self.stats.append(st)
-            _trace.complete("stage", "s{}:{}".format(sid, kind), t0_span,
-                            lane="stages", records=nrec, jobs=njobs)
-            log.info("Stage %s done: %s", sid + 1, st.as_dict())
+                env[stage.output] = result
+                # Stage-boundary write barrier: every spill this stage's
+                # registration pressure queued publishes now, so per-stage
+                # spill attribution stays causal and checkpoint persistence
+                # below sees settled refs (a ref mid-write has no path yet
+                # and would be pointlessly re-written).
+                self.store.drain_writes()
+                if self.resume:
+                    _resume.persist_stage(
+                        self.store, sid, stage_fps[sid], result, nrec)
+                    if _resume.is_volatile(stage_fps[sid]):
+                        volatile_sources.add(stage.output)
+                if reuse_ctl is not None:
+                    # Cross-run publish rides the same settled-refs barrier
+                    # as checkpoint persistence: on-disk blocks hardlink in
+                    # for free, RAM blocks encode once.  Never fails the
+                    # run; chaos/quarantined runs are gated off inside.
+                    reuse_ctl.maybe_publish(sid, stage, result, nrec)
+                # Ride the plan's shuffle choice on the stage's materialized
+                # partitions: lazily-read sorted outputs (sort_by) decide
+                # host-vs-mesh range redistribution at read time, after the
+                # stage walk is gone.
+                if isinstance(result, storage.PartitionSet):
+                    result.shuffle_target = self._shuffle_targets.get(sid)
+                st = StageStats(sid, kind)
+                st.target = (stage.options or {}).get("exec_target", "host")
+                st.shuffle_target = self._shuffle_targets.get(sid)
+                st.n_jobs = njobs
+                st.records_out = nrec
+                st.seconds = time.time() - t0
+                self._fill_stage_io(st, stage, env, result, snap)
+                self.stats.append(st)
+                stage_span.rename("s{}:{}".format(sid, kind),
+                                  records=nrec, jobs=njobs)
+                log.info("Stage %s done: %s", sid + 1, st.as_dict())
 
         # Final write barrier: OutputDataset readers and post-run tools see
         # every spill published (per-stage drains cover the loop; this
@@ -4260,24 +4260,29 @@ class MTRunner(object):
                 ret.append(CatDataset(list(entry.chunks())))
 
         if cleanup:
-            for source in to_delete:
-                if source in keep:
-                    continue
-                entry = env.get(source)
-                if any(env.get(k) is entry for k in keep):
-                    # identity-checkpoint alias of a kept output: the
-                    # PartitionSet is shared, deletion would empty both
-                    continue
-                if isinstance(entry, storage.PartitionSet):
-                    if self.resume and source not in volatile_sources:
-                        # Durable runs keep intermediate checkpoints on disk
-                        # (a modified rerun resumes from the longest valid
-                        # prefix) but release RAM residency now.
-                        entry.release(self.store)
-                    else:
-                        # Volatile stages persist no manifest and can never
-                        # be resumed — retaining their spilled blocks would
-                        # grow the named scratch root without bound.
-                        entry.delete(self.store)
-
+            with _trace.span("run", "cleanup"):
+                self._cleanup(to_delete, keep, env, volatile_sources)
         return ret
+
+    def _cleanup(self, to_delete, keep, env, volatile_sources):
+        """Delete (or, on durable runs, release) the run's intermediates,
+        sparing the requested outputs and their aliases."""
+        for source in to_delete:
+            if source in keep:
+                continue
+            entry = env.get(source)
+            if any(env.get(k) is entry for k in keep):
+                # identity-checkpoint alias of a kept output: the
+                # PartitionSet is shared, deletion would empty both
+                continue
+            if isinstance(entry, storage.PartitionSet):
+                if self.resume and source not in volatile_sources:
+                    # Durable runs keep intermediate checkpoints on disk
+                    # (a modified rerun resumes from the longest valid
+                    # prefix) but release RAM residency now.
+                    entry.release(self.store)
+                else:
+                    # Volatile stages persist no manifest and can never
+                    # be resumed — retaining their spilled blocks would
+                    # grow the named scratch root without bound.
+                    entry.delete(self.store)

@@ -158,20 +158,21 @@ class TestAttribution:
 
 
 class TestDeviceSubPhases:
-    def test_lowered_stage_decomposes(self, profiled, tmp_path):
+    @pytest.mark.parametrize("handoff", ["off", "on"])
+    def test_lowered_stage_decomposes(self, profiled, tmp_path, handoff):
         """A device-lowered scanner stage records build/h2d/compute/d2h
         sub-phases with byte counts (the double-buffered dispatch loop's
-        brackets)."""
+        brackets), on the classic program and on the handoff tier's
+        table probe alike."""
         from dampr_tpu.ops.text import TokenCounts
 
-        old = settings.lower
-        old_handoff = settings.handoff
+        old = (settings.lower, settings.handoff, settings.scan_window_bytes)
         settings.lower = "1"
-        # The classic dispatch loop is what decomposes into these four
-        # brackets; the handoff tier's bootstrap/probe path replaces it
-        # on this edge and has its own observability pins
-        # (test_handoff).
-        settings.handoff = "off"
+        settings.handoff = handoff
+        # Several windows per job: on this backend the handoff leg seeds
+        # its vocabulary from the first window on the host, and the later
+        # windows run the table probe.
+        settings.scan_window_bytes = 16 * 1024
         try:
             # pair_values=False + fold_values is the device-eligible
             # map->fold shape (the bench's): no Rekey between scanner
@@ -180,7 +181,7 @@ class TestDeviceSubPhases:
                   .custom_mapper(TokenCounts(mode="word", lower=True,
                                              pair_values=False))
                   .fold_values(operator.add)
-                  .run("prof-device"))
+                  .run("prof-device-" + handoff))
             prof = em.stats()["profile"]
             dev = [s for s in prof["stages"] if s["device"]]
             assert dev, prof["stages"]
@@ -198,8 +199,8 @@ class TestDeviceSubPhases:
             assert counts["alpha"] > 1000
             em.delete()
         finally:
-            settings.lower = old
-            settings.handoff = old_handoff
+            (settings.lower, settings.handoff,
+             settings.scan_window_bytes) = old
 
 
 class TestProfilerUnit:

@@ -11,7 +11,8 @@ Checks:
    attribute of :mod:`dampr_tpu.settings` (a suggestion for a knob
    that's gone is worse than no suggestion).
 2. **trace span kinds form a closed set** — every literal category
-   passed to ``trace.span(...)`` / ``trace.instant(...)`` in the
+   passed to ``trace.span(...)`` / ``trace.instant(...)`` (or as the
+   second argument of ``devtime.phase(...)``) in the
    package source is declared in ``docs/trace_schema.json``'s
    ``x-span-kinds``, and every declared kind still appears in the
    source (no dead schema entries).
@@ -75,7 +76,8 @@ def check_playbook_knobs(root, errors):
 
 
 _SPAN_RX = re.compile(
-    r"""(?:trace|_trace)\.(?:span|instant)\(\s*['"]([a-z_0-9]+)['"]""")
+    r"""(?:(?:trace|_trace)\.(?:span|instant)\(|devtime\.phase\(\s*"""
+    r"""['"][a-z_]+['"],)\s*['"]([a-z_0-9]+)['"]""")
 
 
 def check_span_kinds(root, sources, errors):
