@@ -10,15 +10,18 @@ keeps a device-resident vocabulary:
 - ``acc``        — per-slot count accumulator (int32 lanes, int64 under
   x64), updated in place by every batch (buffer donation where the
   backend supports it);
-- ``tab_h1``/``tab_slot`` — the sorted hash-lookup lanes batches probe
-  with one vectorized ``searchsorted``;
+- ``tab_index`` — a hashed bucket index of ``2 * cap`` ``(h1, slot)``
+  rows: each token reads its two candidate buckets, derived from its
+  FNV ``h1``, with two row gathers (no search loop); the host places
+  every new slot there (two-choice cuckoo, :meth:`HandoffVocab._place`)
+  and publishes the changed rows with the next batch;
 - ``tab_mat``/``tab_lens`` — the vocabulary's raw byte rows, so every
   probe HIT is verified byte-for-byte inside the program (a 64-bit — or
   32-bit — hash collision can never merge distinct tokens: mismatching
   bytes route to the exact host miss path instead).
 
 Batches whose tokens are mostly in the table run the **table program**:
-single-lane FNV + searchsorted + byte verify + (for per-line dedup) a
+single-lane FNV + bucket probe + byte verify + (for per-line dedup) a
 two-lane ``(slot, line)`` sort + scatter-add — roughly a third of the
 classic program's cost, because the five-lane ``lax.sort`` over the full
 token stream disappears.  Early batches (and vocabulary-shift phases)
@@ -72,9 +75,29 @@ _TABLE_REVERT_MISS_FRAC = 0.25
 _I32_GUARD = 1 << 30
 _I64_GUARD = 1 << 62
 
+#: Smallest vocabulary capacity (slots); the table grows in powers of two.
+_MIN_CAP = 4096
+
+#: The bucket index holds ``2 * cap`` rows, so it is at most half full.
+#: A token's two buckets are the low bits of its FNV ``h1`` and the high
+#: bits of ``h1 * _GOLDEN`` (Knuth's multiplicative hash).  An insert
+#: whose buckets are both taken relocates occupants to their other
+#: bucket at most ``_MAX_KICKS`` times; the entry then left homeless
+#: stays out of the index, and its tokens miss to the exact host path.
+_GOLDEN = 0x9E3779B1
+_MAX_KICKS = 32
+
 
 def _pow2(n, floor=4096):
     return max(floor, 1 << max(0, (int(n) - 1).bit_length()))
+
+
+def _buckets(h1, rows):
+    """The two buckets of ``h1`` in a ``rows``-row index (``rows`` a
+    power of two): a Python int, or a ``uint64`` array of hashes.  The
+    table program computes the same pair on the device in ``uint32``."""
+    shift = 32 - (rows.bit_length() - 1)
+    return h1 & (rows - 1), ((h1 * _GOLDEN) & 0xFFFFFFFF) >> shift
 
 
 @functools.lru_cache(maxsize=None)
@@ -115,11 +138,11 @@ _DEDUP_WINDOW = 16
 def _table_program(n, L, cap, Lcap, dedup, acc_dtype_name, dedup_k=0):
     """One compiled probe-and-count program per shape bucket.
 
-    hash (single FNV lane) -> searchsorted into the sorted table ->
+    hash (single FNV lane) -> two bucket-row gathers from the index ->
     byte-verified hit mask -> dedup'd (slot, line) scatter-add into the
     donated accumulator.  Returns (acc, miss mask, miss count); misses
-    (new vocabulary, hash duplicates, byte mismatches) are handled
-    exactly on the host.
+    (new vocabulary, slots not in the index, byte mismatches) are
+    handled exactly on the host.
 
     ``dedup_k > 0``: every line in the batch spans at most ``dedup_k``
     tokens (host-verified per batch), so a duplicate (slot, line) pair
@@ -132,9 +155,10 @@ def _table_program(n, L, cap, Lcap, dedup, acc_dtype_name, dedup_k=0):
     from .hashing import _FNV_OFFSET1, _FNV_PRIME1
 
     W = min(L, Lcap)
+    rows = 2 * cap
+    shift = 32 - (rows.bit_length() - 1)
 
-    def table_probe(mat, lens, lines, tab_h1, tab_slot, tab_mat, tab_lens,
-                    acc):
+    def table_probe(mat, lens, lines, tab_index, tab_mat, tab_lens, acc):
         h1 = jnp.full((n,), _FNV_OFFSET1, dtype=jnp.uint32)
 
         def body(c, a):
@@ -144,12 +168,22 @@ def _table_program(n, L, cap, Lcap, dedup, acc_dtype_name, dedup_k=0):
 
         h1 = lax.fori_loop(0, L, body, h1)
 
-        pos = jnp.clip(jnp.searchsorted(tab_h1, h1), 0, cap - 1)
-        cand = jnp.take(tab_slot, pos)
+        # Two independent row gathers: a bucket holds the token's slot
+        # when its h1 matches and it is not empty (slot ``cap``); the
+        # first bucket wins.  The byte verify below decides the hit.
+        r1 = jnp.take(tab_index, (h1 & (rows - 1)).astype(jnp.int32),
+                      axis=0, mode="clip")
+        r2 = jnp.take(tab_index,
+                      ((h1 * jnp.uint32(_GOLDEN)) >> shift).astype(jnp.int32),
+                      axis=0, mode="clip")
+        in1 = (r1[:, 0] == h1) & (r1[:, 1] < cap)
+        in2 = (r2[:, 0] == h1) & (r2[:, 1] < cap)
+        cand = jnp.where(in1, r1[:, 1],
+                         jnp.where(in2, r2[:, 1], 0)).astype(jnp.int32)
         valid = lens > 0
-        same = valid & (jnp.take(tab_h1, pos) == h1) \
-            & (jnp.take(tab_lens, cand) == lens)
-        rep = jnp.take(tab_mat, cand, axis=0)
+        same = valid & (in1 | in2) \
+            & (jnp.take(tab_lens, cand, mode="clip") == lens)
+        rep = jnp.take(tab_mat, cand, axis=0, mode="clip")
         if Lcap > W:
             rep = lax.slice(rep, (0, 0), (n, W))
         mw = mat if L == W else lax.slice(mat, (0, 0), (n, W))
@@ -192,7 +226,7 @@ def _table_program(n, L, cap, Lcap, dedup, acc_dtype_name, dedup_k=0):
                 jnp.where(same, 1, 0).astype(acc.dtype))
         return acc, miss, jnp.sum(miss.astype(jnp.int32))
 
-    kwargs = {"donate_argnums": (7,)} if _donate_ok() else {}
+    kwargs = {"donate_argnums": (6,)} if _donate_ok() else {}
     return jax.jit(table_probe, **kwargs)
 
 
@@ -211,15 +245,17 @@ def _scatter_program():
 
 @functools.lru_cache(maxsize=None)
 def _set_rows_program():
-    """Stage vocabulary rows into the device table; slots past the table
-    (the padding) drop."""
+    """Stage vocabulary rows and changed index rows into the device
+    table; slots and positions past the table (the padding) drop."""
     import jax
 
-    def table_set_rows(tab_mat, tab_lens, slots, rows, lens):
+    def table_set_rows(tab_mat, tab_lens, tab_index, slots, rows, lens,
+                       pos, index_rows):
         return (tab_mat.at[slots].set(rows, mode="drop"),
-                tab_lens.at[slots].set(lens, mode="drop"))
+                tab_lens.at[slots].set(lens, mode="drop"),
+                tab_index.at[pos].set(index_rows, mode="drop"))
 
-    kwargs = {"donate_argnums": (0, 1)} if _donate_ok() else {}
+    kwargs = {"donate_argnums": (0, 1, 2)} if _donate_ok() else {}
     return jax.jit(table_set_rows, **kwargs)
 
 
@@ -335,13 +371,15 @@ class HandoffVocab(object):
         self.slot_bytes = []  # canonical utf-8 bytes per slot
         self.h1 = []          # python ints (host lanes)
         self.h2 = []
-        self._tab_dirty = True
-        self._lanes_forced = False  # regrow reallocated the lanes
-        self._lanes_deferred = 0    # slots inserted since last rebuild
         self._pending_rows = []   # (slot, bytes) not yet on device
+        # Host copy of the bucket index (``uint32[2 * cap, 2]`` rows of
+        # (h1, slot), slot ``cap`` = empty); None until the next sync
+        # builds it for the current cap.  ``_dirty`` holds the rows
+        # changed since the device copy was published.
+        self._index = None
+        self._dirty = set()
         self.acc = None
-        self.tab_h1 = None
-        self.tab_slot = None
+        self.tab_index = None
         self.tab_mat = None
         self.tab_lens = None
         self.total_added = 0
@@ -355,11 +393,16 @@ class HandoffVocab(object):
     def _guard(self):
         return _I64_GUARD if _acc_dtype() == np.int64 else _I32_GUARD
 
+    @staticmethod
+    def _table_bytes(cap, Lcap):
+        """Device bytes of a ``cap``-slot table: byte rows, lengths, the
+        ``2 * cap`` 8-byte index rows, and the accumulator (+ sink)."""
+        return int(cap * (Lcap + 4 + 16) + (cap + 1) * _acc_dtype().itemsize)
+
     def device_bytes(self):
         if self.cap == 0:
             return 0
-        return int(self.cap * (self.Lcap + 4 + 4 + 4)
-                   + (self.cap + 1) * _acc_dtype().itemsize)
+        return self._table_bytes(self.cap, self.Lcap)
 
     def _ensure_capacity(self, need_slots, need_len):
         """Grow the device table (pow2 slots, pow2 byte width).  Returns
@@ -376,15 +419,14 @@ class HandoffVocab(object):
 
         new_cap = self.cap
         while need_slots > (new_cap or 0):
-            new_cap = _pow2(max(need_slots, 4096, (new_cap or 0) * 2))
+            new_cap = _pow2(max(need_slots, _MIN_CAP, (new_cap or 0) * 2),
+                            _MIN_CAP)
         new_L = self.Lcap
         while need_len > new_L and new_L < _SHORT_TOKEN + 1:
             new_L *= 2
         if new_cap == self.cap and new_L == self.Lcap:
             return True
-        projected = int(new_cap * (new_L + 12)
-                        + (new_cap + 1) * _acc_dtype().itemsize)
-        if projected > self.budget:
+        if self._table_bytes(new_cap, new_L) > self.budget:
             return False
         old_acc, old_nslots = self.acc, self.nslots
         self.acc = jnp.zeros(new_cap + 1, dtype=_acc_dtype())
@@ -396,20 +438,87 @@ class HandoffVocab(object):
         self.Lcap = new_L
         # Re-stage every row: the widened/regrown matrices start empty.
         self._pending_rows = list(enumerate(self.slot_bytes))
-        self._tab_dirty = True
-        # The lookup lanes were sized for the old cap: the next sync
-        # MUST rebuild them (the program bucket keys on cap).
-        self._lanes_forced = True
+        # The index was sized for the old cap (the program bucket keys
+        # on cap): the next sync builds it anew.
+        self._index = None
         return True
 
+    # -- the bucket index ----------------------------------------------------
+    def _place(self, slot, h):
+        """Put ``slot`` (FNV hash ``h``) into the host index: its first
+        bucket, else its second, else a bounded cuckoo walk that moves
+        occupants to their other bucket.  A slot whose ``h1`` another
+        slot already holds, or the entry a walk leaves homeless, stays
+        out: its tokens miss to the exact host path."""
+        ix, empty = self._index, self.cap
+        b1, b2 = _buckets(h, len(ix))
+        if ((ix[b1, 1] != empty and ix[b1, 0] == h)
+                or (ix[b2, 1] != empty and ix[b2, 0] == h)):
+            return
+        b = b1 if ix[b1, 1] == empty else b2
+        for _ in range(_MAX_KICKS):
+            if ix[b, 1] == empty:
+                break
+            old_h, old_slot = int(ix[b, 0]), int(ix[b, 1])
+            ix[b] = (h, slot)
+            self._dirty.add(b)
+            h, slot = old_h, old_slot
+            o1, o2 = _buckets(h, len(ix))
+            b = o2 if b == o1 else o1
+        else:
+            if ix[b, 1] != empty:
+                return
+        ix[b] = (h, slot)
+        self._dirty.add(b)
+
+    def _build_index(self):
+        """The host index for the current cap, from every slot: each
+        slot's first bucket where it is the first claimant, then its
+        second bucket where that is still empty, then :meth:`_place` for
+        the few left (vectorized: a rebuild places the whole vocabulary)."""
+        ix = np.zeros((2 * self.cap, 2), dtype=np.uint32)
+        ix[:, 1] = self.cap
+        self._index = ix
+        if not self.nslots:
+            return
+        h = np.asarray(self.h1, dtype=np.uint64)
+        _u, slots = np.unique(h, return_index=True)  # first slot per h1
+        slots.sort()
+        h = h[slots]
+        left = np.ones(len(slots), dtype=bool)
+        for b in _buckets(h, len(ix)):
+            i = np.flatnonzero(left)
+            i = i[ix[b[i], 1] == self.cap]
+            _u, first = np.unique(b[i], return_index=True)
+            i = i[first]
+            ix[b[i], 0] = h[i]
+            ix[b[i], 1] = slots[i]
+            left[i] = False
+        for i in np.flatnonzero(left):
+            self._place(int(slots[i]), int(h[i]))
+
+    def unplaced(self):
+        """Vocabulary slots that are not in the index (0 before the
+        first sync has built it)."""
+        if self._index is None:
+            return 0
+        return self.nslots - int(np.count_nonzero(
+            self._index[:, 1] < self.cap))
+
     def _sync_table(self):
-        """Publish staged host rows + the sorted lookup lanes to device
-        (h2d charged for what actually moves)."""
+        """Publish staged host rows and the changed index rows to device
+        in one padded scatter-set; a regrow uploads the whole index it
+        built (h2d charged for what actually moves)."""
         import jax
         import jax.numpy as jnp
 
         moved = 0
-        if self._pending_rows:
+        if self._index is None:
+            self._build_index()
+            self.tab_index = jax.device_put(self._index)
+            moved += self._index.nbytes
+            self._dirty = set()
+        if self._pending_rows or self._dirty:
             slots = np.fromiter((s for s, _b in self._pending_rows),
                                 dtype=np.int32,
                                 count=len(self._pending_rows))
@@ -422,47 +531,30 @@ class HandoffVocab(object):
                 w = min(len(b), self.Lcap)
                 rows[i, :w] = np.frombuffer(b[:w], dtype=np.uint8)
                 lens[i] = len(b)
-            # Pad the staged rows to a power-of-two count (pad slots
-            # point past the table and drop): one compiled update per
-            # bucket, not one per distinct staging size.
-            m = _pow2(len(slots), 256)
+            pos = np.fromiter(self._dirty, dtype=np.int32,
+                              count=len(self._dirty))
+            index_rows = self._index[pos]
+            # Pad both to one power-of-two count (pad slots and
+            # positions point past the table and drop): one compiled
+            # update per bucket, not one per distinct staging size.
+            m = _pow2(max(len(slots), len(pos)), 256)
             slots = np.pad(slots, (0, m - len(slots)),
                            constant_values=self.cap)
             rows = np.pad(rows, ((0, m - len(rows)), (0, 0)))
             lens = np.pad(lens, (0, m - len(lens)))
-            self.tab_mat, self.tab_lens = _set_rows_program()(
-                self.tab_mat, self.tab_lens, jnp.asarray(slots),
-                jnp.asarray(rows), jnp.asarray(lens))
-            moved += rows.nbytes + lens.nbytes
+            pos = np.pad(pos, (0, m - len(pos)),
+                         constant_values=len(self._index))
+            index_rows = np.pad(index_rows, ((0, m - len(index_rows)),
+                                             (0, 0)))
+            self.tab_mat, self.tab_lens, self.tab_index = (
+                _set_rows_program()(
+                    self.tab_mat, self.tab_lens, self.tab_index,
+                    jnp.asarray(slots), jnp.asarray(rows),
+                    jnp.asarray(lens), jnp.asarray(pos),
+                    jnp.asarray(index_rows)))
+            moved += rows.nbytes + lens.nbytes + index_rows.nbytes
             self._pending_rows = []
-        if self._tab_dirty and (
-                self.tab_h1 is None or self._lanes_forced
-                or self._lanes_deferred >= max(1024, self.nslots >> 4)):
-            # One argsort per REBUILD beats per-insert sorted-array
-            # maintenance (np.insert is a full copy — O(vocab^2) across a
-            # bootstrap) — and rebuilds themselves are deferred until
-            # enough slots accumulated (~6% of the vocabulary), because
-            # each one re-sorts and re-uploads the whole cap-sized lane
-            # pair.  Deferral is exact: a slot absent from the lanes
-            # simply keeps MISSING to the host absorb path, which finds
-            # it in ``bytes2slot`` and scatters into the same
-            # accumulator row.  A regrow always rebuilds (the lanes were
-            # reallocated for the new cap).  Pad positions carry the max
-            # hash; a bogus hit there fails the byte/length verify, so
-            # no validity lane is needed.
-            h1a = np.asarray(self.h1, dtype=np.uint32)
-            order = np.argsort(h1a, kind="stable")
-            th1 = np.full(self.cap, np.uint32(0xFFFFFFFF),
-                          dtype=np.uint32)
-            th1[:len(order)] = h1a[order]
-            tsl = np.zeros(self.cap, dtype=np.int32)
-            tsl[:len(order)] = order
-            self.tab_h1 = jax.device_put(th1)
-            self.tab_slot = jax.device_put(tsl)
-            moved += th1.nbytes + tsl.nbytes
-            self._tab_dirty = False
-            self._lanes_forced = False
-            self._lanes_deferred = 0
+            self._dirty = set()
         if moved and self.store is not None:
             self.store.count_h2d(moved)
 
@@ -480,8 +572,8 @@ class HandoffVocab(object):
         self.h1.append(int(h1))
         self.h2.append(int(h2))
         self._pending_rows.append((slot, raw))
-        self._tab_dirty = True
-        self._lanes_deferred += 1
+        if self._index is not None:
+            self._place(slot, self.h1[-1])
         return slot
 
     def lookup_or_insert(self, raws, keys=None, h1=None, h2=None):
@@ -610,8 +702,7 @@ class HandoffVocab(object):
             self.store.count_h2d(nbytes)
         self.acc, miss, n_miss = fn(
             jnp.asarray(mat), jnp.asarray(lens_p), jnp.asarray(lines_p),
-            self.tab_h1, self.tab_slot, self.tab_mat, self.tab_lens,
-            self.acc)
+            self.tab_index, self.tab_mat, self.tab_lens, self.acc)
         self.table_batches += 1
         return _TableBatch(miss, n_miss, starts, lens, lines, n, npad)
 
@@ -648,6 +739,7 @@ class HandoffVocab(object):
                     batch.lines[idx] if batch.lines is not None else None)
         if self.store is not None:
             self.store.count_d2h(fetched)
+            self.store.count_table_probe(batch.n, batch.n - n_miss)
             if ok:
                 # Only a batch that stayed on the tier claims the
                 # avoided drain (a degrading batch is leaving it).
@@ -712,8 +804,7 @@ class HandoffVocab(object):
 
     def _reset(self):
         self.acc = None
-        self.tab_h1 = self.tab_slot = None
-        self.tab_mat = self.tab_lens = None
+        self.tab_index = self.tab_mat = self.tab_lens = None
         self.cap = 0
         self.nslots = 0
         self.bytes2slot = {}
@@ -722,9 +813,8 @@ class HandoffVocab(object):
         self.h1 = []
         self.h2 = []
         self._pending_rows = []
-        self._tab_dirty = True
-        self._lanes_forced = False
-        self._lanes_deferred = 0
+        self._index = None
+        self._dirty = set()
         self.table_mode = False
 
     def finalize(self, store, n_partitions):
@@ -745,6 +835,7 @@ class HandoffVocab(object):
         if self.device_bytes() + self.nslots * 16 > self.budget:
             blk = self.degrade("hbm budget exceeded at finalize")
             return ((blk,) if blk is not None else ()), {}
+        store.count_table_unplaced(self.unplaced())
         h1 = np.asarray(self.h1, dtype=np.uint32)
         h2 = np.asarray(self.h2, dtype=np.uint32)
         order = np.lexsort((h2, h1))

@@ -479,11 +479,12 @@ class DeviceTokenFoldSink(object):
         the (degraded) accumulator: group them exactly on host —
         ``_host_batch``, the same grouping the classic collision
         fallback uses — and emit the block.  ``count_d2h`` charges the
-        miss-evidence fetch when :meth:`HandoffVocab.drain` has not
-        already done so."""
+        miss-evidence fetch, and counts the batch's probe, when
+        :meth:`HandoffVocab.drain` has not already done so."""
         n_miss = int(batch.n_miss)
         if count_d2h and self.store is not None:
             self.store.count_d2h((batch.npad if n_miss else 0) + 4)
+            self.store.count_table_probe(batch.n, batch.n - n_miss)
         if not n_miss:
             return
         if batch.miss_idx is None:

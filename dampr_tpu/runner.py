@@ -3699,6 +3699,11 @@ class MTRunner(object):
                     "handoff_edges", 0),
                 "handoff_degrades": sto.handoff_degrades,
                 "lower_fallbacks": sto.lower_fallbacks,
+                # The table programs' bucket index: tokens probed and
+                # hit, and slots its jobs ended with outside the index.
+                "table_probe_tokens": sto.table_probe_tokens,
+                "table_probe_hits": sto.table_probe_hits,
+                "table_index_unplaced": sto.table_index_unplaced,
             },
             "streamed_assoc_folds": self.streamed_assoc_folds,
             # Barrier-free pipelining evidence (docs/pipeline.md):

@@ -572,6 +572,12 @@ class RunStore(object):
         self.handoff_bytes = 0
         self.d2h_avoided_bytes = 0
         self.handoff_degrades = 0
+        # The table programs' bucket index: tokens probed, tokens that
+        # hit a verified slot, and vocabulary slots left out of the index
+        # at job end (their tokens took the exact host miss path).
+        self.table_probe_tokens = 0
+        self.table_probe_hits = 0
+        self.table_index_unplaced = 0
         # Lowered map batches or windows handed back to the exact host
         # grouping (hash collision, non-UTF-8 window, over-wide line).
         self.lower_fallbacks = 0
@@ -609,6 +615,17 @@ class RunStore(object):
         evidence counter)."""
         with self._lock:
             self.d2h_avoided_bytes += n
+
+    def count_table_probe(self, tokens, hits):
+        """One drained table-program batch: its tokens and its hits."""
+        with self._lock:
+            self.table_probe_tokens += tokens
+            self.table_probe_hits += hits
+
+    def count_table_unplaced(self, n):
+        """A finalized job's vocabulary slots outside its bucket index."""
+        with self._lock:
+            self.table_index_unplaced += n
 
     def count_handoff_degrade(self):
         with self._lock:

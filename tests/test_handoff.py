@@ -336,6 +336,196 @@ class TestExactness:
         assert stats["device"]["handoff_bytes"] > 0
 
 
+#: Two lowercase words with the same 32-bit FNV-1a ``h1`` (458778).
+_SAME_H1 = ("gckxr", "ydtrd")
+
+#: The three table-program variants: plain counts, and per-line dedup
+#: with lines short enough for the windowed compares (``dedup_k`` 16)
+#: or too long for them (the ``(slot, line)`` sort, ``dedup_k`` 0).
+_VARIANTS = {"count": (False, 8, 0), "docfreq-window": (True, 8, 16),
+             "docfreq-sort": (True, 24, 0)}
+
+
+def _windows(rng, tokens_per_line, phases, every=()):
+    """One window of whole lines per ``(words, n_lines)`` phase; the
+    second window also holds each of ``every`` once."""
+    wins = []
+    for i, (words, n_lines) in enumerate(phases):
+        lines = [" ".join(rng.choice(words, size=tokens_per_line))
+                 for _ in range(n_lines)]
+        if i == 1:
+            lines += [" ".join(every[j:j + tokens_per_line])
+                      for j in range(0, len(every), tokens_per_line)]
+        wins.append(("\n".join(lines) + "\n").encode())
+    return wins
+
+
+class _IndexSpy(object):
+    """Drives a handoff sink over whole windows and records, per table
+    batch, the vocabulary bytes its published index left out, the batch's
+    tokens and which of them missed, and the ``dedup_k`` it ran."""
+
+    def __init__(self, monkeypatch):
+        self.batches = []     # (unplaced bytes, token bytes, missed set)
+        self.dedup_ks = set()
+        self.caps = []
+        spy = self
+        orig_dispatch = handoff_mod.HandoffVocab._dispatch
+        orig_drain = handoff_mod.HandoffVocab.drain
+        orig_program = handoff_mod._table_program
+
+        def dispatch(hv, *a):
+            batch = orig_dispatch(hv, *a)
+            # After the sync: the host index is the one the program reads.
+            placed = {int(s) for s in hv._index[:, 1] if s < hv.cap}
+            spy._unplaced = {hv.slot_bytes[s] for s in range(hv.nslots)
+                             if s not in placed}
+            spy.caps.append(hv.cap)
+            return batch
+
+        def drain(hv, buf, batch):
+            out = orig_drain(hv, buf, batch)
+            toks = [buf[s:s + ln].tobytes()
+                    for s, ln in zip(batch.starts, batch.lens)]
+            missed = set(batch.miss_idx.tolist()
+                         if batch.miss_idx is not None else ())
+            spy.batches.append((spy._unplaced, toks, missed, int(batch.n),
+                                int(batch.n_miss)))
+            return out
+
+        def program(n, L, cap, Lcap, dedup, acc, dedup_k=0):
+            spy.dedup_ks.add(dedup_k)
+            return orig_program(n, L, cap, Lcap, dedup, acc, dedup_k)
+
+        monkeypatch.setattr(handoff_mod.HandoffVocab, "_dispatch", dispatch)
+        monkeypatch.setattr(handoff_mod.HandoffVocab, "drain", drain)
+        monkeypatch.setattr(handoff_mod, "_table_program", program)
+
+    def unplaced_tokens(self):
+        """Tokens of unplaced slots in table batches; asserts each one
+        took the miss path."""
+        n = 0
+        for unplaced, toks, missed, _n, _m in self.batches:
+            for i, t in enumerate(toks):
+                if t in unplaced:
+                    assert i in missed, t
+                    n += 1
+        return n
+
+
+def _run_handoff_sink(mapper, windows, name):
+    """Every window through a handoff sink, then its job-end finalize:
+    the counts and the store."""
+    from dampr_tpu.ops import lower as ops_lower
+
+    store = RunStore(name, budget=1 << 26)
+    store.handoff_active = True
+    got = Counter()
+    try:
+        sink = ops_lower.device_window_sink(mapper, store=store,
+                                            handoff=True)
+        blocks = []
+        for win in windows:
+            blocks += list(sink.add(win) or ())
+        assert not sink._hv.degraded
+        fblocks, hmap = sink.finalize_handoff(store, 4)
+        blocks += list(fblocks)
+        blocks += [ref.get() for refs in hmap.values() for ref in refs]
+        for blk in blocks:
+            for k, v in zip(blk.keys, blk.values):
+                got[k] += int(v)
+    finally:
+        store.cleanup()
+    return got, store
+
+
+def _host_counts(windows, dedup):
+    """The same windows through the host codec's exact grouping."""
+    from dampr_tpu.ops.text import chunk_doc_freq, chunk_token_counts
+
+    fn = chunk_doc_freq if dedup else chunk_token_counts
+    got = Counter()
+    for win in windows:
+        blk = fn(win, "word", True, False)
+        for k, v in zip(blk.keys, blk.values):
+            got[k] += int(v)
+    return got
+
+
+class TestBucketIndex:
+    """The table program's hashed bucket index: a slot the index leaves
+    out (both buckets taken, or another slot holds its ``h1``) and a
+    regrow's rebuilt index all keep the counts exact, on every dedup
+    variant."""
+
+    @pytest.mark.parametrize("variant", sorted(_VARIANTS))
+    @pytest.mark.parametrize("case", ["tiny-index", "same-h1", "regrow"])
+    def test_index_exact(self, monkeypatch, case, variant):
+        from dampr_tpu.ops.text import TokenCounts
+
+        dedup, per_line, dedup_k = _VARIANTS[variant]
+        rng = np.random.RandomState(13)
+        base = ["b%d" % i for i in range(56)]
+        if case == "tiny-index":
+            # cap 64, 128 buckets, no relocation: about 40% of the
+            # vocabulary lands where both buckets are already taken.
+            monkeypatch.setattr(handoff_mod, "_MIN_CAP", 64)
+            monkeypatch.setattr(handoff_mod, "_MAX_KICKS", 0)
+            fresh = ["n%d" % i for i in range(6)]
+            phases = [(base, 60), (base + fresh, 60), (base + fresh, 60)]
+        elif case == "same-h1":
+            base += list(_SAME_H1)
+            phases = [(base, 60), (base, 60)]
+        else:
+            # The second window's misses grow the vocabulary past cap 64
+            # inside table mode: the index is rebuilt for cap 128, and
+            # the third window finds every word there.
+            monkeypatch.setattr(handoff_mod, "_MIN_CAP", 64)
+            fresh = ["r%d" % i for i in range(20)]
+            phases = [(base, 60), (base * 3 + fresh, 60),
+                      (base * 3 + fresh, 60)]
+        windows = _windows(rng, per_line, phases,
+                           fresh if case == "regrow" else ())
+        spy = _IndexSpy(monkeypatch)
+        mapper = (DocFreq if dedup else TokenCounts)(
+            mode="word", lower=True, pair_values=False)
+        got, store = _run_handoff_sink(mapper, windows,
+                                       "index-%s-%s" % (case, variant))
+
+        assert got == _host_counts(windows, dedup)
+        assert spy.batches, "no table batch ran"
+        assert spy.dedup_ks == {dedup_k} if dedup else {0}
+        if case == "regrow":
+            assert spy.caps == [64, 128], spy.caps
+            # The last batch found the regrown vocabulary in the index.
+            _u, _t, _m, n, n_miss = spy.batches[-1]
+            assert n_miss == 0 and store.table_index_unplaced == 0
+        else:
+            assert spy.unplaced_tokens() > 0
+            assert store.table_index_unplaced > 0
+        if case == "same-h1":
+            assert store.table_index_unplaced == 1
+
+    def test_probe_counters_add_up(self, monkeypatch):
+        """``table_probe_tokens`` / ``table_probe_hits`` are each table
+        batch's tokens and its tokens less its misses."""
+        rng = np.random.RandomState(4)
+        base = ["c%d" % i for i in range(90)]
+        fresh = ["f%d" % i for i in range(30)]
+        windows = _windows(rng, 8, [(base, 80), (base + fresh, 80),
+                                    (base + fresh, 80)])
+        spy = _IndexSpy(monkeypatch)
+        _got, store = _run_handoff_sink(
+            DocFreq(mode="word", lower=True, pair_values=False), windows,
+            "index-counters")
+        assert len(spy.batches) == 2
+        assert store.table_probe_tokens == sum(
+            b[3] for b in spy.batches) == 1280
+        assert store.table_probe_hits == sum(
+            b[3] - b[4] for b in spy.batches)
+        assert 0 < store.table_probe_hits < store.table_probe_tokens
+
+
 class TestDegradeAndKill:
     def test_budget_exceeded_mid_stage_degrades_exactly(self, tmp_path):
         corpus = _corpus(tmp_path, vocab=4000, n_lines=2500)
@@ -684,6 +874,8 @@ class TestObservability:
         assert dev["handoff_edges"] >= 1
         assert dev["handoff_bytes"] > 0
         assert dev["d2h_avoided_bytes"] > 0
+        assert 0 < dev["table_probe_hits"] <= dev["table_probe_tokens"]
+        assert dev["table_index_unplaced"] >= 0
         spans = stats.get("spans") or {}
         assert "handoff" in spans, spans
         # schema-valid trace including the handoff spans

@@ -74,21 +74,25 @@ def test_token_fold_program(spec, dedup):
 @pytest.mark.parametrize("dedup_k", [0, 16])
 def test_handoff_table_program(spec, dedup_k):
     """The table probe program at its 4096-row floor, per-line dedup
-    both ways (windowed compares and the (slot, line) sort)."""
+    both ways (windowed compares and the (slot, line) sort), probing a
+    ``(2 * cap, 2)`` bucket index with two row gathers and no search
+    loop."""
     from dampr_tpu.ops import handoff
 
     n, L, cap, Lcap = 4096, 16, 4096, 16
     fn = handoff._table_program.__wrapped__(n, L, cap, Lcap, True, "int32",
                                             dedup_k)
-    _compile(fn, spec((n, L), np.uint8), spec((n,), np.int32),
-             spec((n,), np.int32), spec((cap,), np.uint32),
-             spec((cap,), np.int32), spec((cap, Lcap), np.uint8),
-             spec((cap,), np.int32), spec((cap + 1,), np.int32))
+    text = _compile(fn, spec((n, L), np.uint8), spec((n,), np.int32),
+                    spec((n,), np.int32), spec((2 * cap, 2), np.uint32),
+                    spec((cap, Lcap), np.uint8), spec((cap,), np.int32),
+                    spec((cap + 1,), np.int32))
+    # One loop is left, the FNV hash over the byte columns.
+    assert text.count(" while(") == 1, text.count(" while(")
 
 
 def test_handoff_scatter_stage_and_finalize_programs(spec):
-    """The accumulator scatter, the table-row staging and the job-end
-    partition gather, at pow2 buckets."""
+    """The accumulator scatter, the table-row and index-row staging
+    and the job-end partition gather, at pow2 buckets."""
     from dampr_tpu.ops import handoff
 
     cap = 4096
@@ -97,8 +101,9 @@ def test_handoff_scatter_stage_and_finalize_programs(spec):
              spec((1024,), np.int32))
     _compile(handoff._set_rows_program.__wrapped__(),
              spec((cap, 16), np.uint8), spec((cap,), np.int32),
-             spec((256,), np.int32), spec((256, 16), np.uint8),
-             spec((256,), np.int32))
+             spec((2 * cap, 2), np.uint32), spec((256,), np.int32),
+             spec((256, 16), np.uint8), spec((256,), np.int32),
+             spec((256,), np.int32), spec((256, 2), np.uint32))
     _compile(handoff._finalize_program.__wrapped__(64),
              spec((cap + 1,), np.int32), spec((8192,), np.int32),
              spec((8192,), np.int32))
