@@ -32,7 +32,8 @@ categories (event ``cat``):
   read rounds, compaction markers;
 - ``collective`` — mesh keyed folds, byte exchanges, global sums;
 - ``checkpoint`` — resume persist/restore/plan/gc decisions;
-- ``job`` / ``stage`` — per-job spans on worker lanes; one span per
+- ``job`` / ``stage`` — per-job spans on worker lanes (a lowered map
+  job's carries ``chip``, the mesh position it ran on); one span per
   stage on the ``stages`` lane;
 - ``scan`` / ``device`` — the lowered scan's phases, one span per window
   or batch (:func:`dampr_tpu.ops.devtime.phase`): its host half
@@ -66,9 +67,14 @@ returned in-memory from every run — traced or not — via
   ``bytes_in/out``, ``spill_count``/``spill_bytes`` (causal attribution:
   charged to the stage whose pressure evicted the block),
   ``merge_gens``/``merge_gen_bytes``, ``retries``, ``seconds``;
-- ``devtime`` — run-scoped device/transfer/codec/codec_wait seconds and
+- ``devtime`` — run-scoped device/transfer/codec/codec_wait seconds,
   the lowered scan's wall-clock unions ``scan_feed``/``scan_wait``/
-  ``scan_drain`` (epoch/delta snapshots of :mod:`dampr_tpu.ops.devtime`);
+  ``scan_drain`` and the collective fold's ``mesh_fold`` (epoch/delta
+  snapshots of :mod:`dampr_tpu.ops.devtime`);
+- ``device`` — device seconds, h2d/d2h bytes, the lowering and handoff
+  counters, and where the work ran (:mod:`dampr_tpu.parallel.placement`):
+  ``scan_chips``, ``h2d_bytes_by_chip``, ``fold_chips`` and
+  ``fold_a2a_bytes``;
 - ``overlap`` — configured windows, ``stall_fraction`` (codec_wait /
   wall: the codec time still on the critical path), peak in-flight bytes;
 - ``io`` — the async spill subsystem's shape: ``spill_write_bytes/

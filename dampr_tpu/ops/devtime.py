@@ -40,9 +40,14 @@ Buckets, accumulated process-wide behind one lock:
                 interval opens at 0 -> 1 and closes at 1 -> 0), so
                 concurrent map slots count it once, never their
                 thread-seconds summed.
+- ``mesh_fold``: WALL-CLOCK union of the collective fold's device work
+                (``parallel.shuffle``, ``runner._mesh_reduce``): each
+                chip's assembly of the fold's input, every keyed-fold
+                program call with its ``dropped`` fetch, the per-chip
+                compaction, and the final fold's fetch.
 
-Times are dispatch-site THREAD-seconds (``codec_wait`` and the ``scan_*``
-unions excepted — each is a wall-clock interval union, never exceeding
+Times are dispatch-site THREAD-seconds (``codec_wait`` and the
+:data:`UNIONS` excepted — each is a wall-clock interval union, never exceeding
 elapsed wall): concurrent pool workers each add their own elapsed time,
 so a bucket divided by wall time reads like CPU utilization (2.0 = two
 cores' worth per wall second) and can exceed 1.0 on multi-core hosts —
@@ -64,8 +69,9 @@ from ..obs import profile as _profile
 from ..obs import trace as _trace
 
 _lock = threading.Lock()
-#: The lowered scan's wall-clock-union buckets (see :class:`phase`).
-UNIONS = ("scan_feed", "scan_wait", "scan_drain")
+#: The wall-clock-union buckets (see :class:`phase`): the lowered scan's
+#: three phases, and the collective fold's device work.
+UNIONS = ("scan_feed", "scan_wait", "scan_drain", "mesh_fold")
 _counters = {"device": 0.0, "transfer": 0.0, "codec": 0.0,
              "codec_wait": 0.0}
 _counters.update(dict.fromkeys(UNIONS, 0.0))

@@ -283,7 +283,7 @@ def _lane_safe_values(v, kind):
 
 
 def mesh_keyed_fold(mesh, h1, h2, v, kind="sum", capacity_factor=None,
-                    raw=False):
+                    raw=False, store=None):
     """Distributed keyed fold over a device mesh.
 
     ``h1``/``h2``: uint32 hash lanes, ``v``: numeric values (int32/int64/
@@ -297,6 +297,8 @@ def mesh_keyed_fold(mesh, h1, h2, v, kind="sum", capacity_factor=None,
     host fetch/compact, so callers accumulating partials across windows
     (runner._mesh_reduce) never round-trip intermediates through the host —
     they re-fold partials with :func:`mesh_keyed_refold` and fetch once.
+    ``store`` (a run's store) counts the fold's chips and its all_to_all's
+    off-chip bytes.
     """
     import jax
 
@@ -347,7 +349,8 @@ def mesh_keyed_fold(mesh, h1, h2, v, kind="sum", capacity_factor=None,
         elif v.dtype == np.int64:
             nonneg = len(v) * int(v.max()) <= _I64_MAX
     fh1, fh2, fv, ok = _run_fold_padded(
-        mesh, ph1, ph2, pv, pvalid, n_dev, n_local, kind, nonneg, factor)
+        mesh, ph1, ph2, pv, pvalid, n_dev, n_local, kind, nonneg, factor,
+        store)
     if raw:
         return fh1, fh2, fv, ok
     mask = np.asarray(ok) == 1
@@ -356,13 +359,14 @@ def mesh_keyed_fold(mesh, h1, h2, v, kind="sum", capacity_factor=None,
 
 
 def _run_fold_padded(mesh, h1, h2, v, valid, n_dev, n_local, kind, nonneg,
-                     factor):
+                     factor, store=None):
     """Shared capacity-retry loop over already-padded (host or device)
     arrays: compile the program for the current capacity bucket, run,
-    double on overflow."""
+    double on overflow.  Each call, its ``dropped`` fetch included, is a
+    ``mesh_fold`` phase; ``store`` counts its chips and the bytes its
+    all_to_all sends off-chip (:func:`a2a_bytes`)."""
     import jax
 
-    from ..obs import trace as _trace
     from ..ops import devtime
 
     capacity = max(8, int(-(-n_local // n_dev) * factor))
@@ -372,71 +376,275 @@ def _run_fold_padded(mesh, h1, h2, v, valid, n_dev, n_local, kind, nonneg,
         prog = _build_fold_program(mesh, n_dev, n_local, capacity, kind,
                                    np.dtype(v.dtype).name, axis, nonneg,
                                    gather)
-        with devtime.track("device"), _trace.span(
-                "collective", "keyed-fold:{}".format(kind),
-                records=int(n_local * n_dev), capacity=int(capacity)):
+        with devtime.phase("mesh_fold", "collective",
+                           "keyed-fold:{}".format(kind), track="device",
+                           records=int(n_local * n_dev),
+                           capacity=int(capacity)):
             fh1, fh2, fv, ok, dropped = prog(h1, h2, v, valid)
             dropped = int(dropped)
+        if store is not None:
+            store.count_fold(n_dev, a2a_bytes(n_dev, capacity, v.dtype))
         if dropped == 0:
             return fh1, fh2, fv, ok
         capacity *= 2
 
 
-def mesh_keyed_refold(mesh, parts, kind, nonneg=False, capacity_factor=None):
-    """Re-fold device-resident partials from ``mesh_keyed_fold(raw=True)``.
+def a2a_bytes(n_dev, capacity, v_dtype):
+    """Bytes one fold program's all_to_all sends off-chip: every chip
+    sends ``n_dev`` rows of ``capacity`` (ok, h1, h2, value) entries, and
+    the ``n_dev - 1`` rows addressed to other chips leave it."""
+    entry = 12 + np.dtype(v_dtype).itemsize
+    return int(n_dev * (n_dev - 1) * capacity * entry)
 
-    ``parts``: list of (h1, h2, v, ok) jax arrays.  Everything — concat,
-    padding, the collective fold — stays on device; only the overflow
-    scalar is fetched per retry.  Lane safety is the CALLER's contract: it
-    must bound the elementwise abs-sum across every window it folded (the
-    engine tracks the running bound host-side before uploading windows),
-    because partial magnitudes are bounded by element magnitudes.  All
-    parts must share one value dtype (the engine guards this)."""
+
+def mesh_keyed_refold(mesh, parts, kind, nonneg=False, capacity_factor=None,
+                      store=None):
+    """Re-fold device-resident partials: ``mesh_keyed_fold(raw=True)``
+    outputs and the handoff tier's refs.
+
+    ``parts``: list of ``(h1, h2, v, ok)`` partials, each on one chip or
+    spread over ``mesh`` (:func:`chip_pieces`); ``ok`` marks the live
+    rows, or is the count of a valid prefix.  Each chip assembles the
+    pieces it holds into its shard of the fold's input
+    (:func:`assemble_by_chip`), so no partial crosses chips before the
+    fold's own all_to_all and none goes through the host; only the
+    overflow scalar is fetched per retry.  Lane safety is the CALLER's
+    contract: it must bound the elementwise abs-sum across every window
+    it folded (the engine tracks the running bound host-side before
+    uploading windows), because partial magnitudes are bounded by element
+    magnitudes.  All parts must share one value dtype (the engine guards
+    this)."""
+    from ..ops import devtime
+
     n_dev = mesh_size(mesh)
-    total = sum(int(p[0].shape[0]) for p in parts)
-    n_local = _pad_pow2(-(-total // n_dev))
-    h1, h2, v, valid = _concat_pad(n_local * n_dev)(
-        [tuple(p) for p in parts])
+    with devtime.phase("mesh_fold", "collective", "assemble",
+                       track="device", parts=len(parts)):
+        h1, h2, v, valid, n_local = assemble_by_chip(mesh, parts)
 
     factor = capacity_factor or settings.shuffle_capacity_factor
     return _run_fold_padded(mesh, h1, h2, v, valid, n_dev, n_local, kind,
-                            nonneg, factor)
+                            nonneg, factor, store)
+
+
+def _positions(mesh):
+    return {d.id: i for i, d in enumerate(mesh.devices.flat)}
+
+
+def part_chips(mesh, part):
+    """``(mesh position, rows)`` of each piece :func:`chip_pieces` makes
+    of ``part``, without touching its lanes."""
+    h1 = part[0]
+    n = int(h1.shape[0])
+    devs = h1.sharding.device_set
+    if len(devs) == 1:
+        return [(_positions(mesh).get(next(iter(devs)).id, 0), n)]
+    pos = _positions(mesh)
+    if h1.is_fully_replicated:
+        s = -(-n // mesh_size(mesh))
+        return [(pos[sh.device.id], s) for sh in h1.addressable_shards]
+    return [(pos[sh.device.id], int(sh.data.shape[0]))
+            for sh in h1.addressable_shards]
+
+
+def chip_pieces(mesh, part):
+    """``part`` as ``(mesh position, (h1, h2, v, ok))`` pieces, each on
+    the chip at that position, one per chip of this process that holds
+    rows of it: a partial on one chip stays whole; one sharded over the
+    mesh (a fold program's output) splits into its shards; a replicated
+    one (multi-process fold programs all_gather their outputs) splits
+    evenly, each chip slicing its own copy.  A partial on a chip outside
+    the mesh is copied to the mesh's first chip."""
+    import jax
+
+    h1 = part[0]
+    devs = h1.sharding.device_set
+    pos = _positions(mesh)
+    if len(devs) == 1:
+        d = next(iter(devs))
+        if d.id in pos:
+            return [(pos[d.id], tuple(part))]
+        first = mesh.devices.flat[0]
+        return [(0, tuple(jax.device_put(x, first) if hasattr(x, "shape")
+                          else x for x in part))]
+    shards = [{sh.device.id: sh.data for sh in lane.addressable_shards}
+              for lane in part]
+    out = []
+    for sh in h1.addressable_shards:
+        i = sh.device.id
+        piece = tuple(lane[i] for lane in shards)
+        if h1.is_fully_replicated:
+            piece = _replica_slice(mesh_size(mesh))(*piece,
+                                                    np.int32(pos[i]))
+        out.append((pos[i], piece))
+    return out
 
 
 @functools.lru_cache(maxsize=None)
-def _concat_pad(padded):
-    """One program: concatenate each lane of ``parts`` and zero-pad it to
-    ``padded`` rows (one compile per list of part shapes, not eight)."""
+def _replica_slice(n_dev):
+    """Chip ``k``'s even share of a replicated partial: rows
+    ``[k * s, (k + 1) * s)``, ``s = ceil(n / n_dev)``; rows past the
+    partial's end are dead."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
-    def concat_pad(parts):
-        out = []
-        for lane in range(4):
-            cat = jnp.concatenate([p[lane] for p in parts])
-            out.append(jnp.pad(cat, (0, padded - cat.shape[0])))
-        return tuple(out)
+    def replica_slice(h1, h2, v, ok, k):
+        n = h1.shape[0]
+        s = -(-n // n_dev)
+        lanes = [jnp.pad(x, (0, n_dev * s - n)) for x in (h1, h2, v, ok)]
+        return tuple(lax.dynamic_slice(x, (k * s,), (s,)) for x in lanes)
 
-    return jax.jit(concat_pad)
+    return jax.jit(replica_slice)
+
+
+def assemble_by_chip(mesh, parts):
+    """The fold's input from partials where they live: each chip writes
+    the pieces it holds (:func:`chip_pieces`), one after another, into
+    its own zeroed buffer of ``n_local`` rows — the power of two over the
+    largest chip's rows — and the chips' buffers become one array sharded
+    over ``mesh`` (``P(settings.mesh_axis)``).  Returns ``(h1, h2, v,
+    valid, n_local)``.  One write program per (buffer, piece) shape,
+    whichever chips the pieces are on."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from .placement import local_chips
+
+    chips = local_chips(mesh)
+    held = {p: [] for p, _d in chips}
+    for part in parts:
+        for p, piece in chip_pieces(mesh, part):
+            if int(piece[0].shape[0]):
+                held[p].append(piece)
+    n_local = _pad_pow2(max(sum(int(x[0].shape[0]) for x in pieces)
+                            for pieces in held.values()))
+    v_dtype = parts[0][2].dtype
+    bufs = ([], [], [], [])
+    for p, dev in chips:
+        with jax.default_device(dev):
+            lanes = (jnp.zeros(n_local, jnp.uint32),
+                     jnp.zeros(n_local, jnp.uint32),
+                     jnp.zeros(n_local, v_dtype),
+                     jnp.zeros(n_local, jnp.uint32))
+            at = 0
+            for h1, h2, v, ok in held[p]:
+                prefix = not hasattr(ok, "shape")
+                lanes = _write_part(prefix)(
+                    *lanes, h1, h2, v, np.int32(ok) if prefix else ok,
+                    np.int32(at))
+                at += int(h1.shape[0])
+        for buf, lane in zip(bufs, lanes):
+            buf.append(lane)
+    sharding = NamedSharding(mesh, P(settings.mesh_axis))
+    shape = (n_local * mesh_size(mesh),)
+    return tuple(jax.make_array_from_single_device_arrays(shape, sharding,
+                                                          buf)
+                 for buf in bufs) + (n_local,)
+
+
+@functools.lru_cache(maxsize=None)
+def _write_part(prefix):
+    """Write one piece's lanes into a chip's buffers at row ``at`` (the
+    buffers donated where the backend permits); ``prefix``: ``ok`` is the
+    count of the piece's valid leading rows, not a lane."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ..ops.handoff import _donate_ok
+
+    def assemble_part(h1b, h2b, vb, okb, h1, h2, v, ok, at):
+        if prefix:
+            ok = (jnp.arange(h1.shape[0], dtype=jnp.int32)
+                  < ok).astype(jnp.uint32)
+        return tuple(lax.dynamic_update_slice(b, x.astype(b.dtype), (at,))
+                     for b, x in ((h1b, h1), (h2b, h2), (vb, v), (okb, ok)))
+
+    kwargs = {"donate_argnums": (0, 1, 2, 3)} if _donate_ok() else {}
+    return jax.jit(assemble_part, **kwargs)
+
+
+def compact_by_chip(mesh, part):
+    """A fold program's output, compacted (:func:`compact_partial`) on
+    each chip that holds rows of it: a sharded output compacts shard by
+    shard in one program over the mesh (a sort over the whole array would
+    gather it to one chip; one program per chip would compile once per
+    chip), every shard cut to the pow2 over the most live rows any shard
+    holds; a replicated or one-chip output compacts whole."""
+    h1 = part[0]
+    if len(h1.sharding.device_set) == 1 or h1.is_fully_replicated:
+        return compact_partial(part)
+    n = int(h1.shape[0]) // mesh_size(mesh)
+    *lanes, nlive = _shard_live_prefix_sort(mesh, n)(*part)
+    m = _pad_pow2(max(1, int(np.max(nlive))))
+    if m >= n:
+        return part
+    return _shard_prefix(mesh, m)(*lanes)
+
+
+def _live_first(h1, h2, v, ok):
+    """Stable sort moving live (ok == 1) entries to a prefix, and the
+    live count."""
+    import jax.numpy as jnp
+
+    live = ok == 1
+    perm = sort_perm(jnp.where(live, jnp.uint32(0), jnp.uint32(1)))
+    return tuple(jnp.take(a, perm) for a in (h1, h2, v, ok)) + (
+        jnp.sum(live.astype(jnp.int32)),)
 
 
 @functools.lru_cache(maxsize=None)
 def _live_prefix_sort(n):
-    """Stable sort moving live (ok == 1) entries to a prefix — the
-    device-side half of :func:`compact_partial`."""
+    """:func:`_live_first` on one chip — the device-side half of
+    :func:`compact_partial`."""
     import jax
-    import jax.numpy as jnp
 
     def live_prefix_sort(h1, h2, v, ok):
-        perm = sort_perm(jnp.where(ok == 1, jnp.uint32(0), jnp.uint32(1)))
-        return tuple(jnp.take(a, perm) for a in (h1, h2, v))
+        return _live_first(h1, h2, v, ok)
 
     return jax.jit(live_prefix_sort)
 
 
+@functools.lru_cache(maxsize=None)
+def _shard_live_prefix_sort(mesh, n):
+    """:func:`_live_first` on every chip's ``n``-row shard at once; the
+    live counts come back as one per chip."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(settings.mesh_axis)
+
+    def per_shard(h1, h2, v, ok):
+        *lanes, nlive = _live_first(h1, h2, v, ok)
+        return tuple(lanes) + (nlive[None],)
+
+    def shard_live_prefix_sort(h1, h2, v, ok):
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,) * 4,
+                             out_specs=(spec,) * 5)(h1, h2, v, ok)
+
+    return jax.jit(shard_live_prefix_sort)
+
+
+@functools.lru_cache(maxsize=None)
+def _shard_prefix(mesh, m):
+    """Every chip's shard cut to its first ``m`` rows."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(settings.mesh_axis)
+
+    def shard_prefix(*lanes):
+        return jax.shard_map(lambda *xs: tuple(x[:m] for x in xs),
+                             mesh=mesh, in_specs=(spec,) * 4,
+                             out_specs=(spec,) * 4)(*lanes)
+
+    return jax.jit(shard_prefix)
+
+
 def compact_partial(part):
     """Shrink a device-resident ``(h1, h2, v, ok)`` partial to (a pow2
-    pad of) its LIVE entries.
+    pad of) its LIVE entries, on the chip that holds it.
 
     Fold programs return capacity-padded lanes — ~1.5x their input,
     dead rows included — so accumulating partials through repeated
@@ -446,20 +654,15 @@ def compact_partial(part):
     compaction round bounds every partial at the distinct-key count
     instead.  Costs one scalar fetch (the live count); shapes stay pow2,
     so compile buckets stay bounded."""
-    import jax.numpy as jnp
-
     h1, h2, v, ok = part
     n = int(h1.shape[0])
     if n == 0:
         return part
-    nlive = int(jnp.sum(jnp.where(ok == 1, 1, 0)))
-    m = _pad_pow2(max(1, nlive))
+    sh1, sh2, sv, sok, nlive = _live_prefix_sort(n)(h1, h2, v, ok)
+    m = _pad_pow2(max(1, int(nlive)))
     if m >= n:
         return part
-    sh1, sh2, sv = _live_prefix_sort(n)(h1, h2, v, ok)
-    okc = (jnp.arange(m, dtype=jnp.int32)
-           < jnp.int32(nlive)).astype(jnp.uint32)
-    return sh1[:m], sh2[:m], sv[:m], okc
+    return sh1[:m], sh2[:m], sv[:m], sok[:m]
 
 
 def mesh_global_sum(mesh, v):

@@ -37,6 +37,7 @@ import numpy as np
 
 from . import base, faults as _faults, settings, storage
 from .parallel import mitigate as _mitigate
+from .parallel import placement as _placement
 from .blocks import Block, BlockBuilder
 from .dataset import BlockDataset, Chunker, Dataset, SinkDataset
 from .graph import GInput, GMap, GReduce, GSink
@@ -203,7 +204,14 @@ def _overlap_stream(items, store, size_of=None):
                 except _queue.Full:
                     continue
 
-    thread = threading.Thread(target=produce, daemon=True,
+    # The producer dispatches the job's programs: on the job's chip.
+    on_chip = _placement.carried()
+
+    def produce_on_chip():
+        with on_chip():
+            produce()
+
+    thread = threading.Thread(target=produce_on_chip, daemon=True,
                               name="dampr-tpu-codec")
 
     def wait_item():
@@ -1160,12 +1168,15 @@ class MTRunner(object):
         return True
 
     def _pool_run(self, fn, jobs, n_workers, label=None, speculative=True,
-                  on_result=None):
+                  on_result=None, place=None):
         """``on_result(idx, result)`` — the pipelined executor's publish
         hook — runs on the dispatching thread as each job's COMMITTED
         result is collected (attempt rollback, retries, and speculation
         all resolved), in job order.  It may block (backpressure); job
-        workers keep running ahead, bounded by the store budget."""
+        workers keep running ahead, bounded by the store budget.
+        ``place(job)`` — the context each attempt of a job runs in
+        (``parallel.placement``: the job's chip); its span carries
+        ``chip``."""
         retries = settings.job_retries
         if retries:
             inner = fn
@@ -1216,7 +1227,9 @@ class MTRunner(object):
 
             def fn(job, _inner=traced):  # noqa: F811 - span per job, on the
                 #                          worker thread = one lane per slot
-                with _trace.span("job", label):
+                chip = _placement.current()
+                args = {} if chip is None else {"chip": chip}
+                with _trace.span("job", label, **args):
                     return _inner(job)
 
         # Speculative duplicate attempts re-run the job but must not
@@ -1259,6 +1272,16 @@ class MTRunner(object):
                 finally:
                     m.counter_add("run.jobs_done", 1)
                     st["jobs_done"] = st.get("jobs_done", 0) + 1
+
+        if place is not None:
+            # Outermost: every attempt, and the job's span, on its chip.
+            def on_chip(inner):
+                def placed(job):
+                    with place(job):
+                        return inner(job)
+                return placed
+
+            fn, fn_speculative = on_chip(fn), on_chip(fn_speculative)
 
         def collect(results_iter):
             out = []
@@ -1487,6 +1510,22 @@ class MTRunner(object):
         return (_ChainedOutput(acct["records_in"]), acct["records_in"],
                 len(chunks))
 
+    def _scan_placer(self, stages, chunks):
+        """Where a lowered map stage's jobs run: each on one chip of the
+        data mesh the collective fold uses (``parallel.placement``).
+        None when the lowering claimed none of ``stages``."""
+        if not any(s.options.get("exec_target") == "device"
+                   for s in stages):
+            return None
+        from .ops import lower as ops_lower
+        from .parallel.mesh import data_mesh
+
+        if not any(ops_lower.claims(s.mapper) is not None for s in stages):
+            return None
+        mesh = data_mesh()
+        self.store.mesh_chips = len(_placement.local_chips(mesh))
+        return _placement.placer(chunks, mesh)
+
     # -- stage input views --------------------------------------------------
     def _as_chunks(self, entry):
         """Entry (tap Chunker or PartitionSet) -> list of map-job datasets
@@ -1537,7 +1576,9 @@ class MTRunner(object):
             results = self._pool_run(
                 job, chunks, n_maps, label="cross" if supplementary else "map",
                 speculative=self._speculation_ok(stage),
-                on_result=folder.publish if folder is not None else None)
+                on_result=folder.publish if folder is not None else None,
+                place=(None if supplementary
+                       else self._scan_placer((stage,), chunks)))
         finally:
             if folder is not None:
                 # finish() drains even on a failed stage, so every queued
@@ -1810,7 +1851,8 @@ class MTRunner(object):
         n_maps = min(s.options.get("n_maps", self.n_maps) for s in stages)
         results = self._pool_run(group_job, chunks, n_maps,
                                  label="map-group",
-                                 speculative=self._speculation_ok(*stages))
+                                 speculative=self._speculation_ok(*stages),
+                                 place=self._scan_placer(stages, chunks))
 
         ret = []
         for i in range(len(stages)):
@@ -2373,10 +2415,12 @@ class MTRunner(object):
             return None
 
         from .blocks import _concat_cols
+        from .ops import devtime
         from .ops.hashing import combine64
         from .parallel import mesh_keyed_fold
-        from .parallel.shuffle import mesh_keyed_refold
-        from .parallel.mesh import data_mesh
+        from .parallel.shuffle import (compact_by_chip, mesh_keyed_refold,
+                                       part_chips)
+        from .parallel.mesh import data_mesh, mesh_size
 
         mesh = data_mesh()
         x64 = jax.config.jax_enable_x64
@@ -2396,7 +2440,12 @@ class MTRunner(object):
         # sort + Python dict.
         kt = {"segs": [], "n": 0}  # [(u64 sorted, keys)], total entries
 
-        partials = []  # folded (h1, h2, v) lane triples
+        # Folded (h1, h2, v, ok) partials, each on the chip that holds
+        # it or spread over the mesh (``ok``: a live-row lane, or the
+        # count of a valid prefix), and per chip the partials and lanes
+        # it holds.
+        partials = []
+        held = {}
 
         def keys_equal(a, b):
             if a.dtype != object and b.dtype != object:
@@ -2484,28 +2533,34 @@ class MTRunner(object):
         acc = {"abs": 0, "dtype": None, "nonneg": True,
                "lane_max": 2 ** 64}
 
-        def compact():
-            from .parallel.shuffle import compact_partial
+        def add(part):
+            partials.append(part)
+            for chip, rows in part_chips(mesh, part):
+                n, lanes = held.get(chip, (0, 0))
+                held[chip] = (n + 1, lanes + rows)
 
-            # compact_partial bounds the padded lanes at the distinct-key
-            # count: refold outputs are capacity-padded (~1.5x input,
-            # dead rows included), so re-feeding them uncompacted grows
-            # the accumulated partial geometrically across rounds.
-            f = compact_partial(mesh_keyed_refold(
-                mesh, partials, op.kind, nonneg=acc["nonneg"]))
+        def compact():
+            # Compaction bounds the padded lanes at the distinct-key
+            # count, on each chip: refold outputs are capacity-padded
+            # (~1.5x input, dead rows included), so re-feeding them
+            # uncompacted grows the accumulated partial geometrically
+            # across rounds.
+            f = mesh_keyed_refold(mesh, partials, op.kind,
+                                  nonneg=acc["nonneg"], store=self.store)
             del partials[:]
-            partials.append(f)
+            held.clear()
+            with devtime.phase("mesh_fold", "collective", "compact"):
+                add(compact_by_chip(mesh, f))
 
         def maybe_compact():
-            # Compact by accumulated LANE volume, not partial count:
-            # handoff refs are vocabulary-sized (hundreds of tiny
-            # partials are cheaper to hold than to re-fold), while the
-            # window path's partials are capacity-sized and must not
-            # stack past device memory.
-            if len(partials) > 1 and (
-                    len(partials) >= 256
-                    or sum(int(p[0].shape[0]) for p in partials)
-                    >= _REFOLD_LANE_CAP):
+            # Compact by accumulated LANE volume, not partial count, on
+            # any one chip: handoff refs are vocabulary-sized (hundreds
+            # of tiny partials are cheaper to hold than to re-fold),
+            # while the window path's partials are capacity-sized and
+            # must not stack past device memory.
+            if len(partials) > 1 and any(
+                    n >= 256 or lanes >= _REFOLD_LANE_CAP
+                    for n, lanes in held.values()):
                 compact()
 
         def flush(win_blocks):
@@ -2554,14 +2609,15 @@ class MTRunner(object):
             h1, h2 = blk.hashes()
             merge_table(blk.keys, h1, h2)
             try:
-                f = mesh_keyed_fold(mesh, h1, h2, vals, op.kind, raw=True)
+                f = mesh_keyed_fold(mesh, h1, h2, vals, op.kind, raw=True,
+                                    store=self.store)
             except ValueError:
                 raise _HostPath
             if acc["dtype"] is None:
                 acc["dtype"] = f[2].dtype
             elif f[2].dtype != acc["dtype"]:
                 raise _HostPath  # mixed lane dtypes across windows
-            partials.append(f)
+            add(f)
             maybe_compact()
 
         _I32 = 2 ** 31 - 1
@@ -2579,9 +2635,7 @@ class MTRunner(object):
             where the host array last existed.  One deterministic final
             refold replaces the former per-ref fold programs, so compile
             buckets stay bounded regardless of ref count or arrival
-            order."""
-            import jax as _jax
-
+            order.  The ref stays on the chip its map job ran on."""
             dv, dh1, dh2 = ref.device_lanes()
             keys, h1, h2 = ref.host_meta()
             lane_dt = np.dtype(dv.dtype)
@@ -2605,10 +2659,7 @@ class MTRunner(object):
                 acc["dtype"] = dv.dtype
             elif dv.dtype != acc["dtype"]:
                 raise _HostPath  # mixed lane dtypes across windows
-            n_lanes = int(dv.shape[0])
-            ok = np.zeros(n_lanes, dtype=np.uint32)
-            ok[:len(ref)] = 1
-            partials.append((dh1, dh2, dv, _jax.device_put(ok)))
+            add((dh1, dh2, dv, len(ref)))
             maybe_compact()
 
         try:
@@ -2647,9 +2698,12 @@ class MTRunner(object):
         # rows.  The async refold dispatches materialize here, so this IS
         # the stage's final fold work (the span the host combine path
         # emits at its own final fold).
-        with _trace.span("fold", "final-fold"):
-            rh1, rh2, rv, rok = partials[0]
-            mask = np.asarray(rok) == 1
+        with devtime.phase("mesh_fold", "fold", "final-fold"):
+            (rh1, rh2, rv, rok), = partials
+            if hasattr(rok, "shape"):
+                mask = np.asarray(rok) == 1
+            else:  # a lone handoff ref: its valid prefix
+                mask = np.arange(int(rh1.shape[0])) < rok
             fh1 = np.asarray(rh1)[mask]
             fh2 = np.asarray(rh2)[mask]
             fv = np.asarray(rv)[mask]
@@ -2665,7 +2719,7 @@ class MTRunner(object):
         pset, nrec = self._emit_keyed_fold(out_keys, fv, fh1, fh2, pin)
         self.mesh_folds += 1
         log.info("mesh fold: %d keys folded across %d devices",
-                 nrec, len(jax.devices()))
+                 nrec, mesh_size(mesh))
         return pset, nrec, 1
 
     def _code_exchange_batch(self, batch, op):
@@ -3704,6 +3758,15 @@ class MTRunner(object):
                 "table_probe_tokens": sto.table_probe_tokens,
                 "table_probe_hits": sto.table_probe_hits,
                 "table_index_unplaced": sto.table_index_unplaced,
+                # Chips at work (parallel.placement): chips whose lowered
+                # map jobs fed their programs, the lowered feed's h2d
+                # bytes per chip, the mesh size of the collective fold
+                # (0: none ran) and the bytes its all_to_all moved
+                # between chips.
+                "scan_chips": sum(1 for b in sto.h2d_by_chip.values() if b),
+                "h2d_bytes_by_chip": sto.h2d_chip_list(),
+                "fold_chips": sto.fold_chips,
+                "fold_a2a_bytes": sto.fold_a2a_bytes,
             },
             "streamed_assoc_folds": self.streamed_assoc_folds,
             # Barrier-free pipelining evidence (docs/pipeline.md):

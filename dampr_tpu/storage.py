@@ -581,6 +581,15 @@ class RunStore(object):
         # Lowered map batches or windows handed back to the exact host
         # grouping (hash collision, non-UTF-8 window, over-wide line).
         self.lower_fallbacks = 0
+        # Chips the lowered map jobs were placed over
+        # (``parallel.placement``), the lowered feed's h2d bytes per chip
+        # (mesh position -> bytes), the mesh size of the collective fold
+        # programs run (0: none) and the bytes their all_to_all moved
+        # between chips.
+        self.mesh_chips = 1
+        self.h2d_by_chip = {}
+        self.fold_chips = 0
+        self.fold_a2a_bytes = 0
         # Overlap executor accounting: bytes of in-flight scan windows /
         # codec output the pipelined map driver holds ahead of the fold.
         # Charged against the same budget as resident blocks (reserving
@@ -637,9 +646,29 @@ class RunStore(object):
 
     def count_h2d(self, n):
         """Feed bytes shipped to device outside the HBM-tier register path
-        (the lowered map programs' padded token matrices)."""
+        (the lowered map programs' padded token matrices), charged to
+        the chip the calling job is placed on (the default device, 0,
+        outside any placement)."""
+        from .parallel import placement
+
+        chip = placement.current() or 0
         with self._lock:
             self.h2d_bytes += n
+            self.h2d_by_chip[chip] = self.h2d_by_chip.get(chip, 0) + n
+
+    def count_fold(self, n_dev, a2a_bytes):
+        """One collective fold program call over ``n_dev`` chips, and the
+        bytes its all_to_all sent off-chip."""
+        with self._lock:
+            self.fold_chips = n_dev
+            self.fold_a2a_bytes += a2a_bytes
+
+    def h2d_chip_list(self):
+        """The lowered feed's h2d bytes per chip, one entry per chip the
+        map jobs were placed over."""
+        with self._lock:
+            n = max([self.mesh_chips] + [c + 1 for c in self.h2d_by_chip])
+            return [self.h2d_by_chip.get(c, 0) for c in range(n)]
 
     def count_spill_read(self, nbytes, secs):
         with self._lock:

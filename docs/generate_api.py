@@ -76,6 +76,7 @@ MODULES = [
     "dampr_tpu.ops.lower",
     "dampr_tpu.parallel",
     "dampr_tpu.parallel.mesh",
+    "dampr_tpu.parallel.placement",
     "dampr_tpu.parallel.shuffle",
     "dampr_tpu.parallel.exchange",
     "dampr_tpu.parallel.replan",

@@ -147,3 +147,67 @@ def test_mesh_keyed_fold_on_four_chips(topo):
         mesh, 4, n_local, 1536, "sum", "int32", "shards", True)
     text = _compile(fn, *args)
     assert "all-to-all" in text
+
+
+#: The four-chip word count's fold buckets: each chip's shard of the
+#: fold's input (``n_local`` rows: its jobs' vocabulary-sized handoff refs
+#: of up to 512 rows, or its compacted share of the keys), the capacity
+#: the fold derives from it, and the rows a chip's fold output holds.
+N_LOCAL = 1 << 17
+CAPACITY = 49152
+
+
+@pytest.mark.parametrize("prefix, rows", [(True, 512), (False, 8192)])
+def test_chip_assembly_write(spec, prefix, rows):
+    """One piece written into a chip's shard buffers: a handoff ref
+    (valid prefix count) or a compacted piece (live-row lane)."""
+    from dampr_tpu.parallel import shuffle
+
+    i32, u32 = np.int32, np.uint32
+    fn = shuffle._write_part.__wrapped__(prefix)
+    bufs = [spec((N_LOCAL,), dt) for dt in (u32, u32, i32, u32)]
+    piece = [spec((rows,), dt) for dt in (u32, u32, i32)]
+    ok = spec((), i32) if prefix else spec((rows,), u32)
+    _compile(fn, *bufs, *piece, ok, spec((), i32))
+
+
+def _sharded(topo, rows, dtypes):
+    """Argument shapes of ``rows`` per chip, sharded over the four
+    described chips, and their mesh."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(topo.devices), ("shards",))
+    sharded = NamedSharding(mesh, P("shards"))
+    return mesh, [jax.ShapeDtypeStruct((4 * rows,), dt, sharding=sharded)
+                  for dt in dtypes]
+
+
+LANES = (np.uint32, np.uint32, np.int32, np.uint32)
+
+
+def test_chip_compaction(topo, spec):
+    """The fold's output compacted on every chip at once: each chip's
+    share sorted live-first with its live count, then cut to a prefix,
+    one program each over the four described chips (the mesh axis is
+    ``settings.mesh_axis``'s default); and on one chip, the one-chip
+    cells' compaction."""
+    from dampr_tpu.parallel import shuffle
+
+    n = 4 * CAPACITY
+    mesh, args = _sharded(topo, n, LANES)
+    _compile(shuffle._shard_live_prefix_sort.__wrapped__(mesh, n), *args)
+    _compile(shuffle._shard_prefix.__wrapped__(mesh, 1 << 15), *args)
+    _compile(shuffle._live_prefix_sort.__wrapped__(n),
+             *[spec((n,), dt) for dt in LANES])
+
+
+def test_mesh_fold_at_the_four_chip_cells_bucket(topo):
+    """The keyed fold over an input sharded over the four described
+    chips, at the four-chip word count's bucket."""
+    from dampr_tpu.parallel import shuffle
+
+    mesh, args = _sharded(topo, N_LOCAL, LANES)
+    fn = shuffle._build_fold_program.__wrapped__(
+        mesh, 4, N_LOCAL, CAPACITY, "sum", "int32", "shards", True)
+    assert "all-to-all" in _compile(fn, *args)
