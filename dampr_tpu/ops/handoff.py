@@ -1,50 +1,79 @@
-"""Cross-stage device-resident handoff: the per-job vocabulary tier.
+"""Cross-stage device-resident handoff: the stage vocabulary tier.
 
 This is the execution half of the plan's ``handoff="device"`` edge
 decision (:mod:`dampr_tpu.plan.lower`): when a lowered scanner map feeds
 a device-lowered associative sum fold, the map's program outputs never
 round-trip through the host spill path (d2h fetch -> host combine ->
-pickle -> frame encode -> spill -> re-read -> h2d).  Instead each job
-keeps a device-resident vocabulary:
+pickle -> frame encode -> spill -> re-read -> h2d).  Instead the stage
+keeps a device-resident vocabulary on each chip, in two parts.
 
-- ``acc``        — per-slot count accumulator (int32 lanes, int64 under
-  x64), updated in place by every batch (buffer donation where the
-  backend supports it);
+The shared part, :class:`StageVocab`, is one per lowered handoff stage
+and chip (:class:`StageVocabs`, keyed by ``parallel.placement.current()``)
+for one stage of one ``run()``; every map job placed on that chip uses
+it, and it goes with the stage:
+
+- the slot map — ``bytes2slot``, ``keys``, ``slot_bytes`` and the hash
+  lanes ``h1``/``h2`` — append-only, so a slot id stays valid in every
+  job's accumulator;
 - ``tab_index`` — a hashed bucket index of ``2 * cap`` ``(h1, slot)``
   rows: each token reads its two candidate buckets, derived from its
   FNV ``h1``, with two row gathers (no search loop); the host places
-  every new slot there (two-choice cuckoo, :meth:`HandoffVocab._place`)
+  every new slot there (two-choice cuckoo, :meth:`StageVocab._place`)
   and publishes the changed rows with the next batch;
 - ``tab_mat``/``tab_lens`` — the vocabulary's raw byte rows, so every
   probe HIT is verified byte-for-byte inside the program (a 64-bit — or
   32-bit — hash collision can never merge distinct tokens: mismatching
   bytes route to the exact host miss path instead).
 
+The per-job part, :class:`HandoffVocab`, is the job's own ``acc``
+(per-slot count accumulator, int32 lanes, int64 under x64, updated in
+place by every batch with buffer donation where the backend supports
+it), its lane guard, its table/classic mode and its degrade state.
+
+Concurrency: slot inserts and table publication take the shared lock.
+A table dispatch takes one consistent snapshot ``(cap, Lcap, tab_index,
+tab_mat, tab_lens)`` under it and runs outside it.  A new table version
+is a new set of arrays — the shared arrays are never donated, because
+another job's in-flight probe may still read the version it took.  A
+probe against an older snapshot stays exact: its hits are byte-verified
+against that snapshot's rows, and anything newer misses to the host
+path, which finds the slot.  When the shared ``cap`` grew, the job grows
+its accumulator to the snapshot's ``cap + 1`` (the last row is the
+program's sink) before it dispatches.
+
 Batches whose tokens are mostly in the table run the **table program**:
 single-lane FNV + bucket probe + byte verify + (for per-line dedup) a
 two-lane ``(slot, line)`` sort + scatter-add — roughly a third of the
 classic program's cost, because the five-lane ``lax.sort`` over the full
-token stream disappears.  Early batches (and vocabulary-shift phases)
-bootstrap through the classic hash->sort->segment program
-(:mod:`.lower`), whose drained survivors seed the table; on the CPU
-backend the job's first whole window seeds it through the native host
-codec instead (:func:`_host_bootstrap` — cached hash lanes, no
-re-hash, the window's tokenize/pad/dispatch skipped outright).
+token stream disappears.  One job per (stage, chip) bootstraps the
+vocabulary through the classic hash->sort->segment program
+(:mod:`.lower`), whose drained survivors seed the table; the chip's other
+jobs wait for that seed at their first dispatch and start in table mode.
+On the CPU backend the bootstrapping job's first whole window seeds it
+through the native host codec instead (:func:`_host_bootstrap` — cached
+hash lanes, no re-hash, the window's tokenize/pad/dispatch skipped
+outright).  A job whose table batches miss past the revert bar
+(vocabulary shift) goes back to classic batches on its own.
 
-At job end the accumulator becomes per-partition HBM-resident
+At job end the job's accumulator becomes per-partition HBM-resident
 :class:`~dampr_tpu.storage.BlockRef` s (``BlockRef.from_device_lanes``)
-that the consuming fold (``runner._mesh_reduce``) consumes in place.
+that the consuming fold (``runner._mesh_reduce``) consumes in place —
+only the slots this job counted: other jobs' words, and the slots a
+failed or speculative attempt inserted, read zero there and never enter
+a partial.
 
 Exactness contract: every count lands in a slot either (a) verified
 byte-identical to the slot's bytes inside a program, or (b) through the
 host miss/fallback path keyed by canonical UTF-8 bytes.  Degrades — HBM
 budget exceeded, int32 overflow risk, vocabulary overflow — flush the
-accumulator into one hash-sorted host block and hand the rest of the job
-to the classic spill path, byte-identically.
+job's accumulator into one hash-sorted host block and hand the rest of
+the job to the classic spill path, byte-identically; the shared table
+stays for the chip's other jobs.
 """
 
 import functools
 import logging
+import threading
 
 import numpy as np
 
@@ -87,9 +116,31 @@ _MIN_CAP = 4096
 _GOLDEN = 0x9E3779B1
 _MAX_KICKS = 32
 
+#: Rows a host-to-device update of the table or an accumulator is cut
+#: into: a small update pads to the first count, a larger one goes in
+#: pieces of the second.  The jobs sharing a vocabulary insert in an
+#: order the host's scheduling decides, so the update sizes vary from
+#: run to run; these two are the only shapes they compile.
+_UPDATE_ROWS = (256, 4096)
+
 
 def _pow2(n, floor=4096):
     return max(floor, 1 << max(0, (int(n) - 1).bit_length()))
+
+
+def _pieces(n):
+    """``(start, stop, rows)`` pieces of an ``n``-row update, each padded
+    to ``rows`` (:data:`_UPDATE_ROWS`)."""
+    small, big = _UPDATE_ROWS
+    if n <= small:
+        return [(0, n, small)]
+    return [(a, min(n, a + big), big) for a in range(0, n, big)]
+
+
+def _pad(x, rows, value=0):
+    """``x`` padded along its first axis to ``rows`` with ``value``."""
+    return np.pad(x, [(0, rows - len(x))] + [(0, 0)] * (x.ndim - 1),
+                  constant_values=value)
 
 
 def _buckets(h1, rows):
@@ -118,8 +169,8 @@ def _acc_dtype():
 def _host_bootstrap():
     """On the CPU backend the classic bootstrap program is pure
     overhead: its five-lane ``lax.sort`` runs on the very cores the
-    native host codec would use at ~20x the throughput — so an
-    empty-vocabulary job seeds the table from its FIRST WHOLE WINDOW
+    native host codec would use at ~20x the throughput — so the job
+    seeding a chip's vocabulary does so from its FIRST WHOLE WINDOW
     through that codec (whose blocks carry cached hash lanes: no
     re-hash, no row sort), skipping the window's tokenize/pad/dispatch
     entirely.  A real accelerator keeps the classic bootstrap: the
@@ -245,8 +296,10 @@ def _scatter_program():
 
 @functools.lru_cache(maxsize=None)
 def _set_rows_program():
-    """Stage vocabulary rows and changed index rows into the device
-    table; slots and positions past the table (the padding) drop."""
+    """Stage vocabulary rows and changed index rows into a new version of
+    the device table; slots and positions past the table (the padding)
+    drop.  Nothing is donated: the old version's arrays may still feed
+    another job's in-flight probe."""
     import jax
 
     def table_set_rows(tab_mat, tab_lens, tab_index, slots, rows, lens,
@@ -255,25 +308,18 @@ def _set_rows_program():
                 tab_lens.at[slots].set(lens, mode="drop"),
                 tab_index.at[pos].set(index_rows, mode="drop"))
 
-    kwargs = {"donate_argnums": (0, 1, 2)} if _donate_ok() else {}
-    return jax.jit(table_set_rows, **kwargs)
+    return jax.jit(table_set_rows)
 
 
 @functools.lru_cache(maxsize=None)
-def _finalize_program(n_partitions):
-    """Gather the accumulator into partition order (``idx``) and sum each
-    partition's counts (``seg_of``; pad rows carry ``n_partitions`` and
-    drop).  Returns the gathered lanes, the per-partition sums and the
-    smallest count."""
+def _finalize_program():
+    """Gather the accumulator into partition order (``idx``; pad rows
+    read the sink slot)."""
     import jax
     import jax.numpy as jnp
 
-    def table_finalize(acc, idx, seg_of):
-        vals = jnp.take(acc, idx)
-        live = seg_of < n_partitions
-        psum = jax.ops.segment_sum(vals, seg_of, num_segments=n_partitions)
-        vmin = jnp.min(jnp.where(live, vals, jnp.iinfo(vals.dtype).max))
-        return vals, psum, vmin
+    def table_finalize(acc, idx):
+        return jnp.take(acc, idx)
 
     return jax.jit(table_finalize)
 
@@ -347,22 +393,35 @@ class _TableBatch(object):
         self.miss_idx = None
 
 
-class HandoffVocab(object):
-    """Per-job device-resident vocabulary + accumulator (one per lowered
-    handoff-edge map job; never shared across jobs or threads).
+def _acc_bytes(cap):
+    """Device bytes of one job's accumulator for a ``cap``-slot table
+    (+ the sink row)."""
+    return int((cap + 1) * _acc_dtype().itemsize)
 
-    ``budget`` is THIS vocabulary's share of the run's handoff budget:
-    the runner divides ``settings.effective_handoff_budget()`` by the
-    stage's concurrent job count, so N parallel jobs can never hold
-    N x budget of device memory between them (each job degrades
-    gracefully at its share instead of the fleet hitting an allocator
-    failure)."""
 
-    def __init__(self, store, dedup, budget=None):
+def _table_bytes(cap, Lcap):
+    """Device bytes of a ``cap``-slot shared table: byte rows, lengths
+    and the ``2 * cap`` 8-byte index rows."""
+    return int(cap * (Lcap + 4 + 16))
+
+
+class StageVocab(object):
+    """The shared part of a handoff vocabulary: slot map, hash lanes,
+    byte rows and bucket index, host and device, for the map jobs of one
+    stage placed on one chip (see the module docstring).
+
+    ``budget`` is the sum of the shares of the jobs attached to it
+    (:meth:`attach`): the table is charged once against it, together
+    with one accumulator per attached job.  Where the jobs meet the same
+    words, as the jobs over one corpus do, that is less than each job's
+    own table and accumulator took, so such a stage fits wherever it
+    fitted with a vocabulary per job."""
+
+    def __init__(self, store):
         self.store = store
-        self.dedup = dedup
-        self.budget = (int(budget) if budget is not None
-                       else settings.effective_handoff_budget())
+        self.lock = threading.Condition()
+        self.budget = 0
+        self.jobs = 0
         self.nslots = 0
         self.cap = 0
         self.Lcap = 8
@@ -378,41 +437,57 @@ class HandoffVocab(object):
         # changed since the device copy was published.
         self._index = None
         self._dirty = set()
-        self.acc = None
         self.tab_index = None
         self.tab_mat = None
         self.tab_lens = None
-        self.total_added = 0
-        self.table_mode = False
-        self.degraded = False
-        self.degrade_reason = None
-        self.table_batches = 0
-        self.classic_batches = 0
+        # Bootstrap: ``seeded`` once a job's first drain put the
+        # vocabulary in; ``_seeding`` is the job doing it meanwhile.
+        self.seeded = False
+        self._seeding = None
+
+    # -- jobs ----------------------------------------------------------------
+    def attach(self, share):
+        with self.lock:
+            self.budget += share
+            self.jobs += 1
+
+    def detach(self, share):
+        with self.lock:
+            self.budget -= share
+            self.jobs -= 1
+
+    def join(self, job):
+        """A job's first dispatch: True when the vocabulary is seeded
+        (the job starts in table mode); False when ``job`` now seeds it
+        and must call :meth:`end_seed`.  A seed in flight is waited for."""
+        with self.lock:
+            while not self.seeded and self._seeding is not None:
+                self.lock.wait()
+            if self.seeded:
+                return True
+            self._seeding = job
+            return False
+
+    def end_seed(self, job):
+        """``job``'s seeding ended, drained or failed: the jobs waiting
+        in :meth:`join` go on (and one of them seeds, if nothing did)."""
+        with self.lock:
+            if self._seeding is job:
+                self._seeding = None
+                self.seeded = self.nslots > 0
+                self.lock.notify_all()
 
     # -- capacity ----------------------------------------------------------
-    def _guard(self):
-        return _I64_GUARD if _acc_dtype() == np.int64 else _I32_GUARD
-
-    @staticmethod
-    def _table_bytes(cap, Lcap):
-        """Device bytes of a ``cap``-slot table: byte rows, lengths, the
-        ``2 * cap`` 8-byte index rows, and the accumulator (+ sink)."""
-        return int(cap * (Lcap + 4 + 16) + (cap + 1) * _acc_dtype().itemsize)
-
-    def device_bytes(self):
-        if self.cap == 0:
-            return 0
-        return self._table_bytes(self.cap, self.Lcap)
-
     def _ensure_capacity(self, need_slots, need_len):
-        """Grow the device table (pow2 slots, pow2 byte width).  Returns
-        False when growth would exceed the handoff budget — the caller
-        degrades.  Row width never grows past the probe-able maximum:
-        batches only carry tokens <= ``text._SHORT_TOKEN`` bytes, and a
-        hit requires ``tab_lens == lens``, so a LONGER token's row can
-        never verify — such tokens get a slot, hash lanes, and an
-        accumulator row, but their stored bytes truncate (dead for
-        probing either way) instead of widening every slot's row."""
+        """Grow the device table (pow2 slots, pow2 byte width) as a new
+        version.  Returns False when growth would exceed the budget —
+        the caller degrades.  Row width never grows past the probe-able
+        maximum: batches only carry tokens <= ``text._SHORT_TOKEN``
+        bytes, and a hit requires ``tab_lens == lens``, so a LONGER
+        token's row can never verify — such tokens get a slot, hash
+        lanes, and an accumulator row, but their stored bytes truncate
+        (dead for probing either way) instead of widening every slot's
+        row."""
         import jax.numpy as jnp
 
         from .text import _SHORT_TOKEN
@@ -426,12 +501,9 @@ class HandoffVocab(object):
             new_L *= 2
         if new_cap == self.cap and new_L == self.Lcap:
             return True
-        if self._table_bytes(new_cap, new_L) > self.budget:
+        if (_table_bytes(new_cap, new_L)
+                + max(1, self.jobs) * _acc_bytes(new_cap)) > self.budget:
             return False
-        old_acc, old_nslots = self.acc, self.nslots
-        self.acc = jnp.zeros(new_cap + 1, dtype=_acc_dtype())
-        if old_acc is not None and old_nslots:
-            self.acc = self.acc.at[:old_nslots].set(old_acc[:old_nslots])
         self.tab_mat = jnp.zeros((new_cap, new_L), dtype=jnp.uint8)
         self.tab_lens = jnp.full((new_cap,), -1, dtype=jnp.int32)
         self.cap = new_cap
@@ -497,18 +569,22 @@ class HandoffVocab(object):
         for i in np.flatnonzero(left):
             self._place(int(slots[i]), int(h[i]))
 
-    def unplaced(self):
-        """Vocabulary slots that are not in the index (0 before the
-        first sync has built it)."""
-        if self._index is None:
-            return 0
-        return self.nslots - int(np.count_nonzero(
-            self._index[:, 1] < self.cap))
+    def unplaced(self, slots):
+        """How many of ``slots`` are not in the index (0 before the first
+        sync has built it)."""
+        with self.lock:
+            ix = self._index
+            if ix is None:
+                return 0
+            placed = np.zeros(self.cap + 1, dtype=bool)
+            placed[ix[:, 1]] = True
+        return int(np.count_nonzero(~placed[slots]))
 
     def _sync_table(self):
         """Publish staged host rows and the changed index rows to device
         in one padded scatter-set; a regrow uploads the whole index it
-        built (h2d charged for what actually moves)."""
+        built (h2d charged for what actually moves).  Caller holds the
+        lock."""
         import jax
         import jax.numpy as jnp
 
@@ -534,36 +610,38 @@ class HandoffVocab(object):
             pos = np.fromiter(self._dirty, dtype=np.int32,
                               count=len(self._dirty))
             index_rows = self._index[pos]
-            # Pad both to one power-of-two count (pad slots and
-            # positions point past the table and drop): one compiled
-            # update per bucket, not one per distinct staging size.
-            m = _pow2(max(len(slots), len(pos)), 256)
-            slots = np.pad(slots, (0, m - len(slots)),
-                           constant_values=self.cap)
-            rows = np.pad(rows, ((0, m - len(rows)), (0, 0)))
-            lens = np.pad(lens, (0, m - len(lens)))
-            pos = np.pad(pos, (0, m - len(pos)),
-                         constant_values=len(self._index))
-            index_rows = np.pad(index_rows, ((0, m - len(index_rows)),
-                                             (0, 0)))
-            self.tab_mat, self.tab_lens, self.tab_index = (
-                _set_rows_program()(
-                    self.tab_mat, self.tab_lens, self.tab_index,
-                    jnp.asarray(slots), jnp.asarray(rows),
-                    jnp.asarray(lens), jnp.asarray(pos),
-                    jnp.asarray(index_rows)))
-            moved += rows.nbytes + lens.nbytes + index_rows.nbytes
+            # Both go in the same pieces (pad slots and positions point
+            # past the table and drop).
+            for a, b, m in _pieces(max(len(slots), len(pos))):
+                piece = (_pad(slots[a:b], m, self.cap), _pad(rows[a:b], m),
+                         _pad(lens[a:b], m),
+                         _pad(pos[a:b], m, len(self._index)),
+                         _pad(index_rows[a:b], m))
+                self.tab_mat, self.tab_lens, self.tab_index = (
+                    _set_rows_program()(
+                        self.tab_mat, self.tab_lens, self.tab_index,
+                        *(jnp.asarray(x) for x in piece)))
+                moved += sum(x.nbytes for x in piece[1:3] + piece[4:])
             self._pending_rows = []
             self._dirty = set()
         if moved and self.store is not None:
             self.store.count_h2d(moved)
 
+    def snapshot(self):
+        """One consistent published table version ``(cap, Lcap,
+        tab_index, tab_mat, tab_lens)``, every staged row in it; None
+        when even an empty table exceeds the budget."""
+        with self.lock:
+            if not self._ensure_capacity(max(self.nslots, 1), self.Lcap):
+                return None
+            self._sync_table()
+            return (self.cap, self.Lcap, self.tab_index, self.tab_mat,
+                    self.tab_lens)
+
     # -- host-side insert/lookup -------------------------------------------
     def _insert(self, raw, key, h1, h2):
-        """New slot for canonical bytes ``raw`` (caller checked absence).
-        Returns the slot, or -1 when the table cannot grow (degrade)."""
-        if not self._ensure_capacity(self.nslots + 1, len(raw)):
-            return -1
+        """New slot for canonical bytes ``raw`` (caller checked absence
+        and capacity, and holds the lock)."""
         slot = self.nslots
         self.nslots += 1
         self.bytes2slot[raw] = slot
@@ -578,31 +656,142 @@ class HandoffVocab(object):
 
     def lookup_or_insert(self, raws, keys=None, h1=None, h2=None):
         """Slots for a list of canonical utf-8 byte strings; unseen ones
-        insert (hash lanes computed here unless provided).  Returns an
-        int32 array, or None when the table refused to grow."""
+        insert (hash lanes computed here unless provided).  Returns
+        ``(slots, inserted)`` — an int32 array, or None when the table
+        refused to grow — with the number of slots this call added."""
         from . import hashing
 
-        slots = np.empty(len(raws), dtype=np.int32)
-        new_at = [i for i, b in enumerate(raws)
-                  if b not in self.bytes2slot]
-        if new_at and (keys is None or h1 is None):
-            nk = np.empty(len(new_at), dtype=object)
-            for j, i in enumerate(new_at):
-                nk[j] = raws[i].decode("utf-8", "replace")
-            nh1, nh2 = hashing.hash_keys(nk)
-            for j, i in enumerate(new_at):
-                s = self._insert(raws[i], nk[j], nh1[j], nh2[j])
-                if s < 0:
-                    return None
-        elif new_at:
-            for i in new_at:
-                s = self._insert(raws[i], keys[i], h1[i], h2[i])
-                if s < 0:
-                    return None
-        get = self.bytes2slot.get
-        for i, b in enumerate(raws):
-            slots[i] = get(b)
-        return slots
+        with self.lock:
+            get = self.bytes2slot.get
+            new_at = [i for i, b in enumerate(raws) if get(b) is None]
+            if new_at:
+                # One capacity step for the whole call: a duplicate among
+                # the new raws only over-asks by its count.
+                need_len = max(len(raws[i]) for i in new_at)
+                if not self._ensure_capacity(self.nslots + len(new_at),
+                                             need_len):
+                    return None, 0
+                if keys is None or h1 is None:
+                    nk = np.empty(len(new_at), dtype=object)
+                    for j, i in enumerate(new_at):
+                        nk[j] = raws[i].decode("utf-8", "replace")
+                    nh1, nh2 = hashing.hash_keys(nk)
+                else:
+                    nk = [keys[i] for i in new_at]
+                    nh1 = [h1[i] for i in new_at]
+                    nh2 = [h2[i] for i in new_at]
+                before = self.nslots
+                for j, i in enumerate(new_at):
+                    if raws[i] not in self.bytes2slot:
+                        self._insert(raws[i], nk[j], nh1[j], nh2[j])
+                inserted = self.nslots - before
+            else:
+                inserted = 0
+            slots = np.fromiter((get(b) for b in raws), dtype=np.int32,
+                                count=len(raws))
+        if inserted and self.store is not None:
+            self.store.count_slot_inserts(inserted)
+        return slots, inserted
+
+    def lanes(self, n):
+        """Keys and hash lanes of the first ``n`` slots."""
+        with self.lock:
+            keys = np.empty(n, dtype=object)
+            keys[:] = self.keys[:n]
+            return (keys, np.asarray(self.h1[:n], dtype=np.uint32),
+                    np.asarray(self.h2[:n], dtype=np.uint32))
+
+
+class StageVocabs(object):
+    """One lowered handoff stage's :class:`StageVocab` s, one per chip,
+    for one run of the stage: the runner makes one with the stage's job
+    factory and drops it with the stage."""
+
+    def __init__(self, store):
+        self.store = store
+        self._lock = threading.Lock()
+        self._by_chip = {}
+
+    def current(self):
+        """The vocabulary of the chip this thread's work is placed on."""
+        from ..parallel import placement
+
+        chip = placement.current()
+        with self._lock:
+            sv = self._by_chip.get(chip)
+            if sv is None:
+                sv = self._by_chip[chip] = StageVocab(self.store)
+            return sv
+
+
+class HandoffVocab(object):
+    """The per-job part of a handoff vocabulary: one lowered map job's
+    accumulator over a :class:`StageVocab` (its own when ``shared`` is
+    None), its lane guard, table/classic mode and degrade state.
+
+    ``budget`` is THIS job's share of the run's handoff budget: the
+    runner divides ``settings.effective_handoff_budget()`` by the
+    stage's concurrent job count, so N parallel jobs can never hold
+    N x budget of device memory between them (each job degrades
+    gracefully at its share instead of the fleet hitting an allocator
+    failure).  The job adds its share to the shared vocabulary's budget
+    while it is attached."""
+
+    def __init__(self, store, dedup, budget=None, shared=None):
+        self.store = store
+        self.dedup = dedup
+        self.budget = (int(budget) if budget is not None
+                       else settings.effective_handoff_budget())
+        self.shared = shared if shared is not None else StageVocab(store)
+        self.shared.attach(self.budget)
+        self._attached = True
+        self.acc = None
+        self.acc_cap = 0
+        self.total_added = 0
+        self.joined = False
+        self.seeding = False
+        self.table_mode = False
+        self.degraded = False
+        self.degrade_reason = None
+
+    def _guard(self):
+        return _I64_GUARD if _acc_dtype() == np.int64 else _I32_GUARD
+
+    # -- the stage vocabulary ------------------------------------------------
+    def join(self):
+        """At the job's first dispatch: start in table mode on a seeded
+        vocabulary (another job seeded it), else seed it (``seeding``:
+        the caller ends that with :meth:`end_seed`)."""
+        self.joined = True
+        if self.shared.join(self):
+            self.table_mode = True
+            if self.store is not None:
+                self.store.count_shared_join()
+        else:
+            self.seeding = True
+
+    def end_seed(self):
+        """Release this job's seeding, if it holds it (a no-op else)."""
+        if self.seeding:
+            self.seeding = False
+            self.shared.end_seed(self)
+
+    def _detach(self):
+        if self._attached:
+            self._attached = False
+            self.shared.detach(self.budget)
+
+    def _grow_acc(self, cap):
+        """The accumulator sized for a ``cap``-slot table: counts kept,
+        the sink (row ``cap``) moved to the new end."""
+        import jax.numpy as jnp
+
+        if cap == self.acc_cap:
+            return
+        acc = jnp.zeros(cap + 1, dtype=_acc_dtype())
+        if self.acc is not None:
+            acc = acc.at[:self.acc_cap].set(self.acc[:self.acc_cap])
+        self.acc, self.acc_cap = acc, cap
 
     # -- count flow --------------------------------------------------------
     def scatter_counts(self, slots, counts):
@@ -615,17 +804,16 @@ class HandoffVocab(object):
         if self.total_added + total > self._guard():
             return False
         self.total_added += total
-        self._sync_table()
-        # Pad to a power-of-two count: pad entries add 0 to the sink slot.
-        m = _pow2(len(slots), 256)
-        slots = np.pad(np.asarray(slots, dtype=np.int32),
-                       (0, m - len(slots)), constant_values=self.cap)
-        counts = np.pad(np.asarray(counts).astype(_acc_dtype()),
-                        (0, m - len(counts)))
-        self.acc = _scatter_program()(self.acc, jnp.asarray(slots),
-                                      jnp.asarray(counts))
-        if self.store is not None:
-            self.store.count_h2d(len(slots) * (4 + _acc_dtype().itemsize))
+        self._grow_acc(self.shared.cap)
+        slots = np.asarray(slots, dtype=np.int32)
+        counts = np.asarray(counts).astype(_acc_dtype())
+        for a, b, m in _pieces(len(slots)):
+            # Pad entries add 0 to the sink slot.
+            self.acc = _scatter_program()(
+                self.acc, jnp.asarray(_pad(slots[a:b], m, self.acc_cap)),
+                jnp.asarray(_pad(counts[a:b], m)))
+            if self.store is not None:
+                self.store.count_h2d(m * (4 + _acc_dtype().itemsize))
         return True
 
     def absorb_block(self, blk):
@@ -638,7 +826,7 @@ class HandoffVocab(object):
         raws = [None] * len(keys)
         for i in range(len(keys)):
             raws[i] = keys[i].encode("utf-8")
-        slots = self.lookup_or_insert(raws, keys=keys, h1=h1, h2=h2)
+        slots, _n = self.shared.lookup_or_insert(raws, keys, h1, h2)
         if slots is None:
             return False
         return self.scatter_counts(slots, blk.values)
@@ -653,12 +841,10 @@ class HandoffVocab(object):
         raws = [None] * len(keys)
         for i in range(len(keys)):
             raws[i] = keys[i].encode("utf-8")
-        before = self.nslots
-        slots = self.lookup_or_insert(raws, keys=keys, h1=h1, h2=h2)
+        slots, inserted = self.shared.lookup_or_insert(raws, keys, h1, h2)
         if slots is None:
             return False, 0.0
-        new_frac = ((self.nslots - before) / float(batch_tokens)
-                    if batch_tokens else 0.0)
+        new_frac = inserted / float(batch_tokens) if batch_tokens else 0.0
         return self.scatter_counts(slots, counts), new_frac
 
     # -- the table-mode batch ----------------------------------------------
@@ -681,9 +867,11 @@ class HandoffVocab(object):
         npad, L = mat.shape
         if self.total_added + n > self._guard():
             return None
-        if not self._ensure_capacity(max(self.nslots, 1), self.Lcap):
+        snap = self.shared.snapshot()
+        if snap is None:
             return None
-        self._sync_table()
+        cap, Lcap, tab_index, tab_mat, tab_lens = snap
+        self._grow_acc(cap)
         self.total_added += n
         dedup_k = 0
         if self.dedup and lines_p is not None and n:
@@ -695,15 +883,14 @@ class HandoffVocab(object):
             runs = np.diff(np.concatenate(([0], bound, [n])))
             if int(runs.max()) <= _DEDUP_WINDOW:
                 dedup_k = _DEDUP_WINDOW
-        fn = _table_program(npad, L, self.cap, self.Lcap, self.dedup,
+        fn = _table_program(npad, L, cap, Lcap, self.dedup,
                             _acc_dtype().name, dedup_k)
         nbytes = mat.nbytes + lens_p.nbytes + lines_p.nbytes
         if self.store is not None:
             self.store.count_h2d(nbytes)
         self.acc, miss, n_miss = fn(
             jnp.asarray(mat), jnp.asarray(lens_p), jnp.asarray(lines_p),
-            self.tab_index, self.tab_mat, self.tab_lens, self.acc)
-        self.table_batches += 1
+            tab_index, tab_mat, tab_lens, self.acc)
         return _TableBatch(miss, n_miss, starts, lens, lines, n, npad)
 
     def drain(self, buf, batch):
@@ -712,9 +899,9 @@ class HandoffVocab(object):
         classic program would have fetched.  Returns (ok, miss_frac);
         ``ok=False`` means NO miss count landed (the absorb is
         transactional: slots inserted before the refusal carry zero
-        counts, which the degrade flush drops) — the caller must emit
-        ``batch.miss_idx``'s tokens through the exact host path or they
-        are lost."""
+        counts in this job, and no job emits a zero count) — the caller
+        must emit ``batch.miss_idx``'s tokens through the exact host path
+        or they are lost."""
         import jax
 
         with devtime.phase("scan_wait", "device", "wait", track="device",
@@ -761,41 +948,47 @@ class HandoffVocab(object):
         for i in range(len(uniq)):
             ln = int(uniq[i, 0])
             raws[i] = uniq[i, 1:1 + ln].tobytes()
-        slots = self.lookup_or_insert(raws)
+        slots, _n = self.shared.lookup_or_insert(raws)
         if slots is None:
             return False
         return self.scatter_counts(slots, counts)
 
     # -- endgame -----------------------------------------------------------
-    def flush_block(self):
-        """Degrade: one d2h of the accumulator -> a hash-sorted host
-        block, byte-identical to what the classic combine would have
-        produced; the job continues on the spill path."""
-        if self.nslots == 0:
-            self._reset()
+    def _counted(self):
+        """Fetch this job's counts: ``(slots, counts)`` of the slots it
+        counted (every other slot reads zero here), or None when it
+        holds no accumulator."""
+        if self.acc is None:
             return None
-        counts = np.asarray(self.acc[:self.nslots]).astype(np.int64)
+        acc = np.asarray(self.acc)
         if self.store is not None:
             # Charge what actually crossed the boundary: the
-            # accumulator's own lane width, not the int64-widened copy.
-            self.store.count_d2h(self.nslots * _acc_dtype().itemsize)
+            # accumulator's own lane width.
+            self.store.count_d2h(acc.nbytes)
+        n = min(self.shared.nslots, self.acc_cap)
+        slots = np.flatnonzero(acc[:n] > 0)
+        return slots, acc[slots].astype(np.int64)
+
+    def flush_block(self):
+        """Degrade: one d2h of the accumulator -> a hash-sorted host
+        block of this job's counts, byte-identical to what the classic
+        combine would have produced; the job continues on the spill
+        path.  The shared table stays for the chip's other jobs."""
+        counted = self._counted()
+        self._reset()
+        if counted is None or not len(counted[0]):
+            return None
         from ..blocks import Block
 
-        keys = np.empty(self.nslots, dtype=object)
-        for i, k in enumerate(self.keys):
-            keys[i] = k
-        h1 = np.asarray(self.h1, dtype=np.uint32)
-        h2 = np.asarray(self.h2, dtype=np.uint32)
-        keep = counts > 0
-        blk = Block(keys[keep], counts[keep], h1[keep], h2[keep])
-        self._reset()
-        if not len(blk):
-            return None
-        return blk.sort_by_hash()
+        slots, counts = counted
+        keys, h1, h2 = self.shared.lanes(int(slots[-1]) + 1)
+        return Block(keys[slots], counts, h1[slots],
+                     h2[slots]).sort_by_hash()
 
     def degrade(self, reason):
         self.degraded = True
         self.degrade_reason = reason
+        self.end_seed()
         if self.store is not None:
             self.store.count_handoff_degrade()
         _trace.instant("handoff", "degrade", reason=reason)
@@ -803,77 +996,69 @@ class HandoffVocab(object):
         return self.flush_block()
 
     def _reset(self):
+        """Drop this job's accumulator and leave the shared vocabulary."""
         self.acc = None
-        self.tab_index = self.tab_mat = self.tab_lens = None
-        self.cap = 0
-        self.nslots = 0
-        self.bytes2slot = {}
-        self.keys = []
-        self.slot_bytes = []
-        self.h1 = []
-        self.h2 = []
-        self._pending_rows = []
-        self._index = None
-        self._dirty = set()
+        self.acc_cap = 0
         self.table_mode = False
+        self._detach()
 
     def finalize(self, store, n_partitions):
-        """Job end: the accumulator becomes per-partition HBM-resident
-        refs — hash-sorted within each partition, exactly the layout the
-        classic combine would have registered — registered under the
-        store's budget/attempt discipline.  Returns ``(blocks, {pid:
-        [BlockRef]})``: at most one side is non-empty (``blocks`` is the
-        degrade flush the caller must push through the classic path)."""
+        """Job end: the slots this job counted become per-partition
+        HBM-resident refs — hash-sorted within each partition, exactly
+        the layout the classic combine would have registered —
+        registered under the store's budget/attempt discipline.  Returns
+        ``(blocks, {pid: [BlockRef]})``: at most one side is non-empty
+        (``blocks`` is the degrade flush the caller must push through the
+        classic path)."""
         import jax
         import jax.numpy as jnp
 
         from ..storage import BlockRef
 
-        if self.degraded or self.nslots == 0:
+        self.end_seed()
+        if self.degraded or self.acc is None:
             self._reset()
             return (), {}
-        if self.device_bytes() + self.nslots * 16 > self.budget:
+        with devtime.phase("scan_drain", "handoff", "finalize-fetch",
+                           track="device", records=int(self.acc_cap)):
+            slots, counts = self._counted()
+        if not len(slots):
+            self._reset()
+            return (), {}
+        if _acc_bytes(self.acc_cap) + len(slots) * 16 > self.budget:
             blk = self.degrade("hbm budget exceeded at finalize")
             return ((blk,) if blk is not None else ()), {}
-        store.count_table_unplaced(self.unplaced())
-        h1 = np.asarray(self.h1, dtype=np.uint32)
-        h2 = np.asarray(self.h2, dtype=np.uint32)
+        store.count_table_unplaced(self.shared.unplaced(slots))
+        keys, h1, h2 = self.shared.lanes(int(slots[-1]) + 1)
+        keys, h1, h2 = keys[slots], h1[slots], h2[slots]
+        n = len(slots)
         order = np.lexsort((h2, h1))
         pid = (h1[order] % np.uint32(n_partitions)).astype(np.int32)
         porder = np.argsort(pid, kind="stable")
         perm = order[porder]
         sorted_pid = pid[porder]
-        keys = np.empty(self.nslots, dtype=object)
-        for i, k in enumerate(self.keys):
-            keys[i] = k
         bounds = np.flatnonzero(np.diff(sorted_pid)) + 1
         starts = np.concatenate(([0], bounds))
-        ends = np.concatenate((bounds, [self.nslots]))
+        ends = np.concatenate((bounds, [n]))
+        psum = np.add.reduceat(counts[perm], starts)
+        lane_min = int(counts.min())
         # Every partition's lanes are padded to a power of two (the
         # consumer marks the valid prefix, ``len(ref)``), and all of them
         # gather from the accumulator in ONE program: device shapes stay
         # in a few pow2 buckets instead of one compile per partition size.
-        # Pad rows read the sink slot and belong to no partition.
+        # Pad rows read the sink slot.
         widths = [_pow2(e - s, 8) for s, e in zip(starts, ends)]
         offs = np.concatenate(([0], np.cumsum(widths)))
         total = _pow2(int(offs[-1]), 256)
-        idx = np.full(total, self.cap, dtype=np.int32)
-        seg_of = np.full(total, n_partitions, dtype=np.int32)
+        idx = np.full(total, self.acc_cap, dtype=np.int32)
         for s, e, o in zip(starts, ends, offs):
-            idx[o:o + e - s] = perm[s:e]
-            seg_of[o:o + e - s] = sorted_pid[s]
-        with devtime.phase("scan_drain", "handoff", "finalize-fetch",
-                           track="device", records=int(self.nslots)):
-            vals, psum, vmin = _finalize_program(n_partitions)(
-                self.acc, jnp.asarray(idx), jnp.asarray(seg_of))
-            psum = np.asarray(psum)
-            lane_min = int(vmin)
+            idx[o:o + e - s] = slots[perm[s:e]]
+        vals = _finalize_program()(self.acc, jnp.asarray(idx))
         if self.store is not None:
-            self.store.count_h2d(idx.nbytes + seg_of.nbytes)
-            self.store.count_d2h(psum.nbytes + 4)
+            self.store.count_h2d(idx.nbytes)
         mapping = {}
         total_dev = 0
-        for s, e, o, w in zip(starts, ends, offs, widths):
+        for j, (s, e, o, w) in enumerate(zip(starts, ends, offs, widths)):
             p = int(sorted_pid[s])
             seg = perm[s:e]
             dev_v = _slice(vals, int(o), w)
@@ -887,7 +1072,7 @@ class HandoffVocab(object):
             # sum is exact in the accumulator dtype.
             ref = BlockRef.from_device_lanes(
                 keys.take(seg), h1[seg], h2[seg], dev_v, dev_h1, dev_h2,
-                store=store, value_dtype=np.int64, lane_abs=int(psum[p]),
+                store=store, value_dtype=np.int64, lane_abs=int(psum[j]),
                 lane_min=lane_min,
                 h2d_bytes=h1_seg.nbytes + h2_seg.nbytes)
             store.register_device(ref)

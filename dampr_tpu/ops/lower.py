@@ -238,16 +238,19 @@ class DeviceTokenFoldSink(object):
     host fallbacks keep results byte-identical to the host scanner.
 
     ``handoff=True`` (the plan's ``handoff="device"`` edge,
-    :mod:`.handoff`): emitted partials stay DEVICE-RESIDENT in a per-job
-    vocabulary accumulator instead of draining to host blocks — classic
-    batches bootstrap the vocabulary, later batches run the cheap
+    :mod:`.handoff`): emitted partials stay DEVICE-RESIDENT in the job's
+    accumulator over its chip's stage vocabulary (``vocabs``, shared with
+    the stage's other jobs on that chip; the job's own when None)
+    instead of draining to host blocks — one job's classic batches
+    seed the vocabulary, every batch after them runs the cheap
     table-probe program, and ``finalize_handoff`` registers the
     accumulated counts as HBM-resident BlockRefs the consuming fold
     reads in place.  Any degrade flushes the accumulator into one
     hash-sorted block and reverts to the classic emit path,
     byte-identically."""
 
-    def __init__(self, params, store=None, handoff=False, jobs=1):
+    def __init__(self, params, store=None, handoff=False, jobs=1,
+                 vocabs=None):
         self.mode = params["mode"]
         self.lower = params["lower"]
         self.dedup = params["dedup"]
@@ -264,8 +267,9 @@ class DeviceTokenFoldSink(object):
             # N x budget of device memory between them.
             share = (settings.effective_handoff_budget()
                      // max(1, int(jobs)))
-            self._hv = _handoff.HandoffVocab(store, self.dedup,
-                                             budget=share)
+            self._hv = _handoff.HandoffVocab(
+                store, self.dedup, budget=share,
+                shared=vocabs.current() if vocabs is not None else None)
 
     # -- host fallbacks ----------------------------------------------------
     def _note_fallback(self):
@@ -391,21 +395,15 @@ class DeviceTokenFoldSink(object):
             ph.nbytes = mat.nbytes
         return mat, lens_p, lines_p
 
-    def _dispatch(self, buf, starts, lens, lines):
-        """Pad one batch to its shape bucket and launch the classic
-        program; h2d payload bytes are charged to the store's HBM
-        counters.  The feed's phases (``devtime.phase``) are ``pad``
-        (``build`` under ``settings.profile``) and ``dispatch`` (the
-        program call and its argument feed, ``h2d``); ``wait``
-        (``compute``) and ``fetch`` (``d2h``) follow at drain."""
+    def _dispatch(self, starts, lens, padded):
+        """Launch the classic program over one padded batch; h2d payload
+        bytes are charged to the store's HBM counters.  The feed's
+        phases (``devtime.phase``) are ``pad`` (``build`` under
+        ``settings.profile``) and ``dispatch`` (the program call and its
+        argument feed, ``h2d``); ``wait`` (``compute``) and ``fetch``
+        (``d2h``) follow at drain."""
         n = len(starts)
-        from .. import faults as _faults
-
-        # Fault site: a classified failure here surfaces through the map
-        # job and rides the job retry loop (the whole-chunk fallback
-        # paths keep results byte-identical on re-execution).
-        _faults.check("device_dispatch")
-        mat, lens_p, lines_p = self._pad_batch(buf, starts, lens, lines)
+        mat, lens_p, lines_p = padded
         npad, L = mat.shape
         fn = _token_fold_jit(npad, L, self.dedup,
                              settings.lower_pallas_segfold,
@@ -423,24 +421,31 @@ class DeviceTokenFoldSink(object):
         return _Batch(out, starts, lens, n)
 
     def _next_batch(self, buf, starts, lens, lines, out):
-        """Dispatch one batch through whichever program the vocabulary
-        state calls for (table probe once the vocabulary converged,
-        classic otherwise).  A refused table dispatch (overflow/budget
-        guard) degrades the job and falls back to classic."""
-        if self._handoff_live and self._hv.table_mode:
-            from .. import faults as _faults
+        """Pad one batch and dispatch it through whichever program the
+        vocabulary state calls for (table probe once the vocabulary
+        converged, classic otherwise).  The job's first dispatch joins
+        its chip's stage vocabulary: a seeded one (waited for while
+        another job seeds it) starts the job in table mode, else this
+        job seeds it.  A refused table dispatch (overflow/budget guard)
+        degrades the job and falls back to classic."""
+        from .. import faults as _faults
 
-            _faults.check("device_dispatch")
-            mat, lens_p, lines_p = self._pad_batch(buf, starts, lens,
-                                                   lines)
-            batch = self._hv.dispatch(mat, lens_p, lines_p, starts, lens,
-                                      lines, len(starts))
+        # Fault site: a classified failure here surfaces through the map
+        # job and rides the job retry loop (the whole-chunk fallback
+        # paths keep results byte-identical on re-execution).
+        _faults.check("device_dispatch")
+        padded = self._pad_batch(buf, starts, lens, lines)
+        if self._handoff_live and not self._hv.joined:
+            self._hv.join()
+        if self._handoff_live and self._hv.table_mode:
+            batch = self._hv.dispatch(*padded, starts, lens, lines,
+                                      len(starts))
             if batch is not None:
                 self.batches += 1
                 return batch
             self._degrade_to(out, "count-lane overflow guard or hbm "
                                   "budget exceeded mid-stage")
-        return self._dispatch(buf, starts, lens, lines)
+        return self._dispatch(starts, lens, padded)
 
     def _resolve(self, buf, batch, out):
         """Drain one in-flight dispatch of either shape into ``out`` (or
@@ -471,6 +476,10 @@ class DeviceTokenFoldSink(object):
                 self._hv.table_mode = False
             return
         blk = self._drain(buf, batch, out)
+        if self._hv is not None:
+            # A seeding job's first drain is in the vocabulary (or the
+            # job degraded): the chip's waiting jobs go on.
+            self._hv.end_seed()
         if blk is not None and len(blk):
             out.append(blk)
 
@@ -571,6 +580,15 @@ class DeviceTokenFoldSink(object):
                 - 1).astype(np.int32)
 
     def add(self, win):
+        try:
+            return self._add(win)
+        except BaseException:
+            if self._hv is not None:
+                # A failed seeding must not hold the chip's other jobs.
+                self._hv.end_seed()
+            raise
+
+    def _add(self, win):
         data = bytes(win) if isinstance(win, memoryview) else win
         buf = np.frombuffer(data, dtype=np.uint8)
         if not len(buf):
@@ -588,22 +606,23 @@ class DeviceTokenFoldSink(object):
             except UnicodeDecodeError:
                 self._absorb_or_out(self._host_window(win), out)
                 return out
-        if self._handoff_live and not self._hv.table_mode \
-                and not self._hv.nslots:
-            from .handoff import _host_bootstrap
+        from .handoff import _host_bootstrap
 
-            if _host_bootstrap():
-                # CPU-backend bootstrap: the job's first window seeds the
-                # vocabulary through the NATIVE host codec — its blocks
-                # carry cached hash lanes, so the absorb never re-hashes
-                # or re-sorts, and this window's tokenize/pad/dispatch is
-                # skipped outright (~20x the classic bootstrap program,
-                # which has no accelerator to hide on here).  Counts are
-                # byte-identical: absorb_block keys by canonical utf-8
-                # bytes, the same contract as a classic drain.  Table
-                # mode engages immediately — a vocabulary that fails to
-                # cover the next window's batches reverts through the
-                # standard miss-fraction bar.
+        if self._handoff_live and not self._hv.joined and _host_bootstrap():
+            self._hv.join()
+            if self._hv.seeding:
+                # CPU-backend bootstrap: the seeding job's first window
+                # fills the vocabulary through the NATIVE host codec —
+                # its blocks carry cached hash lanes, so the absorb never
+                # re-hashes or re-sorts, and this window's
+                # tokenize/pad/dispatch is skipped outright (~20x the
+                # classic bootstrap program, which has no accelerator to
+                # hide on here).  Counts are byte-identical: absorb_block
+                # keys by canonical utf-8 bytes, the same contract as a
+                # classic drain.  Table mode engages immediately — a
+                # vocabulary that fails to cover the next window's
+                # batches reverts through the standard miss-fraction
+                # bar.
                 from .. import faults as _faults
                 from .handoff import CLASSIC_DRAIN_BYTES_PER_SLOT
 
@@ -629,7 +648,8 @@ class DeviceTokenFoldSink(object):
                                                      self.pair_values)
                     self._absorb_or_out(
                         (blk,) if blk is not None else (), out)
-                if self._handoff_live and self._hv.nslots:
+                self._hv.end_seed()
+                if self._handoff_live and self._hv.shared.seeded:
                     self._hv.table_mode = True
                     if self.store is not None and blk is not None:
                         # Drain bytes the classic path would have
@@ -683,14 +703,14 @@ class DeviceTokenFoldSink(object):
         # the next one is in flight (jax dispatch is async).
         pending = None
         for a, b in bounds:
-            if (pending is not None and self._handoff_live
-                    and not self._hv.table_mode and not self._hv.nslots):
-                # Bootstrap sync: resolve the job's FIRST classic batch
-                # before the next dispatch — its drain seeds the
-                # vocabulary, so every remaining batch can run the cheap
-                # table program.  One batch of lost overlap buys
-                # table-mode for the rest of the job (jobs are only a
-                # handful of batches long).
+            if pending is not None and self._hv is not None \
+                    and self._hv.seeding:
+                # Bootstrap sync: resolve the seeding job's FIRST classic
+                # batch before the next dispatch — its drain seeds the
+                # chip's vocabulary, so every remaining batch, and every
+                # batch of the chip's other jobs, can run the cheap table
+                # program.  One batch of lost overlap buys table-mode for
+                # the rest of the stage on this chip.
                 self._resolve(buf, pending, out)
                 pending = None
             nxt = self._next_batch(
@@ -718,15 +738,18 @@ class DeviceTokenFoldSink(object):
             return self._hv.finalize(store, n_partitions)
 
 
-def device_window_sink(mapper, store=None, handoff=False, jobs=1):
+def device_window_sink(mapper, store=None, handoff=False, jobs=1,
+                       vocabs=None):
     """The device window sink for a claimed mapper, or None.
     ``handoff=True`` arms the cross-stage device-resident tier (a
     pair-values scanner — an object lane with no device tier — silently
     stays on the classic emit path); ``jobs`` is the stage's concurrent
-    job count, dividing the handoff budget per vocabulary."""
+    job count, dividing the handoff budget per job; ``vocabs`` is the
+    stage's :class:`~.handoff.StageVocabs` (None: a vocabulary of the
+    job's own)."""
     params = claims(mapper)
     if params is None:
         return None
     return DeviceTokenFoldSink(params, store=store, handoff=handoff,
-                               jobs=jobs)
+                               jobs=jobs, vocabs=vocabs)
 

@@ -2032,6 +2032,14 @@ class MTRunner(object):
                 from .analyze import jaxtrace as _jaxtrace
 
                 lane_program = _jaxtrace.stage_program(stage)
+        # One handoff vocabulary per chip for this run of the stage: the
+        # jobs placed on a chip share its slot map and device table, each
+        # with its own accumulator (ops.handoff).  It goes with the stage.
+        vocabs = None
+        if dev_lowered and stage_handoff:
+            from .ops import handoff as ops_handoff
+
+            vocabs = ops_handoff.StageVocabs(self.store)
 
         def window_sink():
             """The stage's window sink honoring its execution target
@@ -2042,7 +2050,8 @@ class MTRunner(object):
                 return ops_lower.device_window_sink(
                     _clone_op(stage.mapper), self.store,
                     handoff=stage_handoff,
-                    jobs=stage.options.get("n_maps", self.n_maps))
+                    jobs=stage.options.get("n_maps", self.n_maps),
+                    vocabs=vocabs)
             return _clone_op(stage.mapper).window_sink()
 
         def job(chunk):
@@ -2098,7 +2107,8 @@ class MTRunner(object):
 
                 dev_sink = ops_lower.device_window_sink(
                     mapper, self.store, handoff=stage_handoff,
-                    jobs=stage.options.get("n_maps", self.n_maps))
+                    jobs=stage.options.get("n_maps", self.n_maps),
+                    vocabs=vocabs)
                 for blk in _overlap_stream(
                         _drive_windows(mapper, chunk, sink=dev_sink),
                         self.store):
@@ -3758,6 +3768,11 @@ class MTRunner(object):
                 "table_probe_tokens": sto.table_probe_tokens,
                 "table_probe_hits": sto.table_probe_hits,
                 "table_index_unplaced": sto.table_index_unplaced,
+                # The stage vocabularies (one per stage and chip): host
+                # slot inserts into them, and map jobs that started on
+                # one another job had seeded.
+                "handoff_slot_inserts": sto.handoff_slot_inserts,
+                "handoff_shared_joins": sto.handoff_shared_joins,
                 # Chips at work (parallel.placement): chips whose lowered
                 # map jobs fed their programs, the lowered feed's h2d
                 # bytes per chip, the mesh size of the collective fold

@@ -578,6 +578,11 @@ class RunStore(object):
         self.table_probe_tokens = 0
         self.table_probe_hits = 0
         self.table_index_unplaced = 0
+        # The handoff stage vocabularies (``ops.handoff.StageVocab``):
+        # slots inserted into them on the host, and map jobs that joined
+        # one another job had already seeded.
+        self.handoff_slot_inserts = 0
+        self.handoff_shared_joins = 0
         # Lowered map batches or windows handed back to the exact host
         # grouping (hash collision, non-UTF-8 window, over-wide line).
         self.lower_fallbacks = 0
@@ -632,9 +637,18 @@ class RunStore(object):
             self.table_probe_hits += hits
 
     def count_table_unplaced(self, n):
-        """A finalized job's vocabulary slots outside its bucket index."""
+        """A finalized job's counted slots outside its chip's bucket
+        index."""
         with self._lock:
             self.table_index_unplaced += n
+
+    def count_slot_inserts(self, n):
+        with self._lock:
+            self.handoff_slot_inserts += n
+
+    def count_shared_join(self):
+        with self._lock:
+            self.handoff_shared_joins += 1
 
     def count_handoff_degrade(self):
         with self._lock:

@@ -377,10 +377,11 @@ class _IndexSpy(object):
         def dispatch(hv, *a):
             batch = orig_dispatch(hv, *a)
             # After the sync: the host index is the one the program reads.
-            placed = {int(s) for s in hv._index[:, 1] if s < hv.cap}
-            spy._unplaced = {hv.slot_bytes[s] for s in range(hv.nslots)
+            sv = hv.shared
+            placed = {int(s) for s in sv._index[:, 1] if s < sv.cap}
+            spy._unplaced = {sv.slot_bytes[s] for s in range(sv.nslots)
                              if s not in placed}
-            spy.caps.append(hv.cap)
+            spy.caps.append(sv.cap)
             return batch
 
         def drain(hv, buf, batch):
@@ -639,7 +640,7 @@ class TestDegradeAndKill:
                 keys, np.array([2, 3, 7], dtype=np.int64), h1, h2, 12)
             assert ok, "long token forced a degrade"
             assert not hv.degraded
-            assert hv.Lcap <= 2 * (_SHORT_TOKEN + 1), hv.Lcap
+            assert hv.shared.Lcap <= 2 * (_SHORT_TOKEN + 1), hv.shared.Lcap
             blk = hv.degrade("test flush")
             got = dict(zip(blk.keys, blk.values))
             assert got == {"a": 2, "b": 3, long_key: 7}
@@ -648,7 +649,8 @@ class TestDegradeAndKill:
 
     def test_flush_block_returns_budget(self):
         """HandoffVocab.degrade flushes every count into one hash-sorted
-        block and resets — no device arrays survive."""
+        block and drops the job's accumulator — no device array of the
+        job survives; the stage vocabulary keeps its slots."""
         store = RunStore("handoff-flush", budget=1 << 24)
         store.handoff_active = True
         hv = handoff_mod.HandoffVocab(store, dedup=False)
@@ -664,9 +666,255 @@ class TestDegradeAndKill:
         blk = hv.degrade("test degrade")
         assert blk is not None and len(blk) == 100
         assert sorted(blk.keys) == sorted(keys)
-        assert hv.acc is None and hv.nslots == 0
+        assert hv.acc is None and hv.shared.nslots == 100
         assert store.handoff_degrades == 1
         store.cleanup()
+
+
+def _phased_corpus(tmp_path, phases=4, n_lines=300, seed=11):
+    """Lines in ``phases`` byte-contiguous parts, each with words of its
+    own plus a common set: a job over one part never counts the other
+    parts' words, which its chip's vocabulary still holds."""
+    rng = np.random.RandomState(seed)
+    common = ["c%d" % i for i in range(60)]
+    lines = []
+    for ph in range(phases):
+        words = common + ["p%d_%d" % (ph, i) for i in range(80)]
+        lines += [" ".join(rng.choice(words, size=rng.randint(1, 10)))
+                  for _ in range(n_lines)]
+    path = str(tmp_path / "phased.txt")
+    with open(path, "wb") as f:
+        f.write(("\n".join(lines) + "\n").encode())
+    return path
+
+
+def _stage_run(corpus, name, dedup, jobs):
+    """``jobs`` map jobs of one lowered stage (DocFreq or TokenCounts
+    into ``fold_values``): the result and the run's stats."""
+    from dampr_tpu.ops.text import TokenCounts
+
+    mapper = (DocFreq if dedup else TokenCounts)(
+        mode="word", lower=True, pair_values=False)
+    em = (Dampr.text(corpus, os.path.getsize(corpus) // jobs + 1)
+          .custom_mapper(mapper).fold_values(operator.add).run(name=name))
+    got = sorted(em.read())
+    stats = em.stats()
+    em.delete()
+    return got, stats
+
+
+def _word_counts(corpus):
+    import re
+
+    rx = re.compile(r"[^\w]+")
+    c = Counter()
+    with open(corpus, encoding="utf-8") as f:
+        for line in f:
+            c.update(t for t in rx.split(line.lower()) if t)
+    return sorted(c.items())
+
+
+_DEDUP = pytest.mark.parametrize("dedup", [False, True],
+                                 ids=["word-count", "doc-freq"])
+
+
+class TestStageVocabulary:
+    """The map jobs of one lowered stage on one chip share its handoff
+    vocabulary (slot map and device table), each with an accumulator of
+    its own: exact results, each word inserted once, one seeding job,
+    and nothing carried into the next ``run()``."""
+
+    JOBS = 4
+
+    @pytest.fixture(autouse=True)
+    def one_chip(self, monkeypatch):
+        """The data mesh cut to one device: every map job on chip 0;
+        the stage vocabularies made recorded in ``self.made``."""
+        from dampr_tpu.parallel import mesh as mesh_mod
+
+        full = mesh_mod.data_mesh
+        monkeypatch.setattr(mesh_mod, "data_mesh",
+                            lambda devices=None, n=None: full(devices, 1))
+        self.made = []
+        init = handoff_mod.StageVocab.__init__
+        made = self.made
+
+        def record(sv, *a, **kw):
+            init(sv, *a, **kw)
+            made.append(sv)
+
+        monkeypatch.setattr(handoff_mod.StageVocab, "__init__", record)
+        settings.handoff = "on"
+
+    def _want(self, corpus, dedup):
+        return _oracle(corpus) if dedup else _word_counts(corpus)
+
+    @_DEDUP
+    def test_jobs_share_one_vocabulary(self, tmp_path, dedup):
+        corpus = _phased_corpus(tmp_path)
+        got, stats = _stage_run(corpus, "stage-vocab-share", dedup,
+                                self.JOBS)
+        want = self._want(corpus, dedup)
+        assert got == want
+        dev = stats["device"]
+        assert dev["handoff_degrades"] == 0 and dev["handoff_bytes"] > 0
+        # One vocabulary for the stage's chip, each word inserted once,
+        # and every job but its seeding one started on it.
+        (sv,) = self.made
+        assert dev["handoff_slot_inserts"] == sv.nslots == len(want)
+        assert sorted(sv.keys) == sorted(k for k, _c in want)
+        assert dev["handoff_shared_joins"] == self.JOBS - 1
+        assert dev["table_probe_tokens"] > 0
+
+    @_DEDUP
+    def test_faulted_job_retries_exactly(self, tmp_path, monkeypatch,
+                                         dedup):
+        """A transient fault at a job's table dispatch fails that
+        attempt after it joined; its retry joins the same vocabulary,
+        and the count stays exact."""
+        monkeypatch.setattr(settings, "job_retries", 2)
+        settings.faults = "device_dispatch:nth=3"
+        corpus = _phased_corpus(tmp_path)
+        got, stats = _stage_run(corpus, "stage-vocab-fault", dedup,
+                                self.JOBS)
+        want = self._want(corpus, dedup)
+        assert got == want
+        dev = stats["device"]
+        assert stats["retries"] >= 1
+        assert dev["handoff_degrades"] == 0
+        (sv,) = self.made
+        assert dev["handoff_slot_inserts"] == sv.nslots == len(want)
+        # The failed attempt joined too.
+        assert dev["handoff_shared_joins"] >= self.JOBS - 1
+
+    @_DEDUP
+    def test_one_job_degrades_while_others_go_on(self, tmp_path,
+                                                 monkeypatch, dedup):
+        """One job's table dispatch is refused: that job flushes its own
+        counts to the spill path, the shared table stays, and the other
+        jobs still register device partials."""
+        import threading
+
+        lock = threading.Lock()
+        refused = []
+        dispatch = handoff_mod.HandoffVocab._dispatch
+
+        def refuse_once(hv, *a):
+            with lock:
+                first = not refused
+                if first:
+                    refused.append(hv)
+            if first:
+                return None
+            return dispatch(hv, *a)
+
+        monkeypatch.setattr(handoff_mod.HandoffVocab, "_dispatch",
+                            refuse_once)
+        corpus = _phased_corpus(tmp_path)
+        got, stats = _stage_run(corpus, "stage-vocab-degrade", dedup,
+                                self.JOBS)
+        assert got == self._want(corpus, dedup)
+        dev = stats["device"]
+        assert dev["handoff_degrades"] == 1
+        assert dev["handoff_bytes"] > 0
+        (sv,) = self.made
+        assert sv.nslots == len(got) and sv.tab_index is not None
+
+    @_DEDUP
+    def test_no_zero_count_key_in_a_partial(self, tmp_path, monkeypatch,
+                                            dedup):
+        """Each job's partials hold only the words its own accumulator
+        counted, every one above zero, though its chip's vocabulary
+        holds the other jobs' words too."""
+        finalize = handoff_mod.HandoffVocab.finalize
+        partials = []
+
+        def spy(hv, store, n_partitions):
+            blocks, mapping = finalize(hv, store, n_partitions)
+            partials.append([(ref.get(), ref) for refs in mapping.values()
+                             for ref in refs])
+            return blocks, mapping
+
+        monkeypatch.setattr(handoff_mod.HandoffVocab, "finalize", spy)
+        corpus = _phased_corpus(tmp_path)
+        got, stats = _stage_run(corpus, "stage-vocab-zero", dedup,
+                                self.JOBS)
+        assert got == self._want(corpus, dedup)
+        (sv,) = self.made
+        assert len(partials) == self.JOBS
+        for job in partials:
+            keys = [k for blk, _r in job for k in blk.keys]
+            vals = [int(v) for blk, _r in job for v in blk.values]
+            assert vals and min(vals) > 0
+            assert len(keys) == len(set(keys))
+            # A phase's own words are in one job's partials only.
+            assert len(keys) < sv.nslots
+
+    def test_concurrent_inserts_and_joins_lose_nothing(self):
+        """More threads than cores insert overlapping words into one
+        stage vocabulary and join it, the interpreter switching threads
+        every microsecond: each word gets one slot that every thread
+        agrees on, the insert count is the distinct words, and exactly
+        one thread seeds while the others wait for it."""
+        import sys
+        import threading
+
+        store = RunStore("stage-vocab-stress", budget=1 << 26)
+        sv = handoff_mod.StageVocab(store)
+        sv.attach(1 << 24)
+        words = [("w%d" % i).encode() for i in range(3000)]
+        n = 2 * (os.cpu_count() or 4)
+        seen, seeded = [None] * n, []
+        start = threading.Barrier(n)
+
+        def job(t):
+            start.wait()
+            seeds = not sv.join(t)
+            if seeds:
+                seeded.append(t)
+                sv.lookup_or_insert(words[:500])
+                sv.end_seed(t)
+            mine = [words[i] for i in
+                    np.random.RandomState(t).permutation(len(words))]
+            seen[t] = {}
+            for a in range(0, len(mine), 7):
+                slots, _n = sv.lookup_or_insert(mine[a:a + 7])
+                seen[t].update(zip(mine[a:a + 7], slots.tolist()))
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=job, args=(t,))
+                       for t in range(n)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=120)
+            assert not any(th.is_alive() for th in threads)
+        finally:
+            sys.setswitchinterval(old)
+            store.cleanup()
+        assert len(seeded) == 1 and sv.seeded
+        assert sv.nslots == len(set(sv.slot_bytes)) == len(words)
+        assert store.handoff_slot_inserts == len(words)
+        assert sorted(sv.bytes2slot.values()) == list(range(len(words)))
+        for got in seen:
+            assert all(sv.bytes2slot[w] == s for w, s in got.items())
+
+    def test_back_to_back_runs_start_fresh(self, tmp_path):
+        """Each ``run()`` builds its stage vocabulary anew: the second
+        inserts every word again and seeds once again."""
+        corpus = _phased_corpus(tmp_path)
+        want = self._want(corpus, False)
+        for i in range(2):
+            got, stats = _stage_run(corpus, "stage-vocab-again", False,
+                                    self.JOBS)
+            assert got == want
+            dev = stats["device"]
+            assert len(self.made) == i + 1
+            assert dev["handoff_slot_inserts"] == len(want)
+            assert dev["handoff_shared_joins"] == self.JOBS - 1
+        assert self.made[0] is not self.made[1]
 
 
 class TestAccounting:

@@ -379,3 +379,124 @@ def test_placement_reaches_a_helper_thread():
     t.join()
     assert seen == {"chip": 2, "dev": {dev}}
     assert placement.current() is None
+
+
+@pytest.fixture
+def vocabs(monkeypatch):
+    """The stage vocabularies a run makes, in the order made."""
+    from dampr_tpu.ops import handoff
+
+    made = []
+    init = handoff.StageVocab.__init__
+
+    def record(sv, *a, **kw):
+        init(sv, *a, **kw)
+        made.append(sv)
+
+    monkeypatch.setattr(handoff.StageVocab, "__init__", record)
+    return made
+
+
+@pytest.mark.parametrize("dedup", [False, True],
+                         ids=["word-count", "doc-freq"])
+def test_each_chip_shares_one_vocabulary(chips, corpus, vocabs, dedup):
+    """The jobs placed on a chip share one vocabulary, kept on that chip:
+    each word its jobs meet is inserted there once, one job a chip seeds
+    it and the others start on it."""
+    got, stats = run(corpus, "mesh-scan-vocab", dedup)
+    want = reference(corpus, dedup)
+    assert dict(got) == want
+    dev = stats["device"]
+    assert len(vocabs) == CHIPS
+    on = [sv.tab_index.sharding.device_set for sv in vocabs]
+    assert all(len(d) == 1 for d in on) and len(set().union(*on)) == CHIPS
+    for sv in vocabs:
+        assert len(set(sv.slot_bytes)) == sv.nslots == len(sv.bytes2slot)
+    assert dev["handoff_slot_inserts"] == sum(sv.nslots for sv in vocabs)
+    assert set().union(*(sv.keys for sv in vocabs)) == set(want)
+    assert dev["handoff_shared_joins"] == JOBS - CHIPS
+    assert dev["handoff_degrades"] == 0
+
+
+@pytest.mark.parametrize("dedup", [False, True],
+                         ids=["word-count", "doc-freq"])
+def test_faulted_job_on_a_chip_retries_exactly(chips, corpus, vocabs,
+                                               monkeypatch, dedup):
+    """A transient fault at a joined job's dispatch fails that attempt;
+    its retry runs on the same chip's vocabulary, and the fold stays
+    exact."""
+    monkeypatch.setattr(settings, "job_retries", 2)
+    monkeypatch.setattr(settings, "faults", "device_dispatch:nth=6")
+    got, stats = run(corpus, "mesh-scan-vocab-fault", dedup)
+    assert dict(got) == reference(corpus, dedup)
+    dev = stats["device"]
+    assert stats["retries"] >= 1 and dev["handoff_degrades"] == 0
+    assert len(vocabs) == CHIPS
+    assert dev["handoff_slot_inserts"] == sum(sv.nslots for sv in vocabs)
+
+
+def test_one_degrading_job_leaves_its_chip_to_the_others(chips, corpus,
+                                                         vocabs,
+                                                         monkeypatch):
+    """One job's accumulator degrades to the spill path: its chip's
+    vocabulary stays, the chip's other job registers device partials,
+    and the count is exact."""
+    import threading
+
+    from dampr_tpu.ops import handoff
+
+    lock = threading.Lock()
+    refused = []
+    dispatch = handoff.HandoffVocab._dispatch
+
+    def refuse_once(hv, *a):
+        with lock:
+            first = not refused
+            refused.append(hv)
+        return None if first else dispatch(hv, *a)
+
+    monkeypatch.setattr(handoff.HandoffVocab, "_dispatch", refuse_once)
+    got, stats = run(corpus, "mesh-scan-vocab-degrade")
+    assert dict(got) == reference(corpus)
+    dev = stats["device"]
+    assert dev["handoff_degrades"] == 1 and dev["handoff_bytes"] > 0
+    assert all(sv.tab_index is not None for sv in vocabs)
+
+
+def test_partials_hold_only_their_jobs_counts(chips, corpus, vocabs,
+                                               monkeypatch):
+    """On every chip each job's partials hold only words its own
+    accumulator counted, every count above zero, and no more of them
+    than its chip's vocabulary holds."""
+    from dampr_tpu.ops import handoff
+
+    finalize = handoff.HandoffVocab.finalize
+    partials = []
+
+    def spy(hv, store, n_partitions):
+        blocks, mapping = finalize(hv, store, n_partitions)
+        vals = [int(v) for refs in mapping.values() for ref in refs
+                for v in ref.get().values]
+        partials.append((hv.shared, vals))
+        return blocks, mapping
+
+    monkeypatch.setattr(handoff.HandoffVocab, "finalize", spy)
+    got, _stats = run(corpus, "mesh-scan-vocab-zero")
+    assert dict(got) == reference(corpus)
+    assert len(partials) == JOBS
+    for sv, vals in partials:
+        assert vals and min(vals) > 0 and len(vals) <= sv.nslots
+
+
+def test_back_to_back_runs_build_fresh_vocabularies(chips, corpus,
+                                                    vocabs):
+    """Each ``run()`` builds its chips' vocabularies anew: the second
+    run inserts as many slots as the first, into new vocabularies."""
+    inserts = []
+    for i in range(2):
+        got, stats = run(corpus, "mesh-scan-vocab-again")
+        assert dict(got) == reference(corpus)
+        assert len(vocabs) == CHIPS * (i + 1)
+        inserts.append(stats["device"]["handoff_slot_inserts"])
+    assert inserts[0] == inserts[1] > 0
+    assert not set(map(id, vocabs[:CHIPS])) & set(map(id, vocabs[CHIPS:]))

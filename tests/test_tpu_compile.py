@@ -96,17 +96,17 @@ def test_handoff_scatter_stage_and_finalize_programs(spec):
     from dampr_tpu.ops import handoff
 
     cap = 4096
-    _compile(handoff._scatter_program.__wrapped__(),
-             spec((cap + 1,), np.int32), spec((1024,), np.int32),
-             spec((1024,), np.int32))
-    _compile(handoff._set_rows_program.__wrapped__(),
-             spec((cap, 16), np.uint8), spec((cap,), np.int32),
-             spec((2 * cap, 2), np.uint32), spec((256,), np.int32),
-             spec((256, 16), np.uint8), spec((256,), np.int32),
-             spec((256,), np.int32), spec((256, 2), np.uint32))
-    _compile(handoff._finalize_program.__wrapped__(64),
-             spec((cap + 1,), np.int32), spec((8192,), np.int32),
-             spec((8192,), np.int32))
+    for m in handoff._UPDATE_ROWS:
+        _compile(handoff._scatter_program.__wrapped__(),
+                 spec((cap + 1,), np.int32), spec((m,), np.int32),
+                 spec((m,), np.int32))
+        _compile(handoff._set_rows_program.__wrapped__(),
+                 spec((cap, 16), np.uint8), spec((cap,), np.int32),
+                 spec((2 * cap, 2), np.uint32), spec((m,), np.int32),
+                 spec((m, 16), np.uint8), spec((m,), np.int32),
+                 spec((m,), np.int32), spec((m, 2), np.uint32))
+    _compile(handoff._finalize_program.__wrapped__(),
+             spec((cap + 1,), np.int32), spec((8192,), np.int32))
 
 
 def test_segment_fold_and_lexsort(spec):
