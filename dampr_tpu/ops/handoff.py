@@ -47,12 +47,10 @@ two-lane ``(slot, line)`` sort + scatter-add — roughly a third of the
 classic program's cost, because the five-lane ``lax.sort`` over the full
 token stream disappears.  One job per (stage, chip) bootstraps the
 vocabulary through the classic hash->sort->segment program
-(:mod:`.lower`), whose drained survivors seed the table; the chip's other
-jobs wait for that seed at their first dispatch and start in table mode.
-On the CPU backend the bootstrapping job's first whole window seeds it
-through the native host codec instead (:func:`_host_bootstrap` — cached
-hash lanes, no re-hash, the window's tokenize/pad/dispatch skipped
-outright).  A job whose table batches miss past the revert bar
+(:mod:`.lower`), whose drained survivors seed the table; the seed ends
+at that job's first drain, and the chip's other jobs wait for it at their
+first dispatch and start in table mode.  This is the one bootstrap, on
+every backend.  A job whose table batches miss past the revert bar
 (vocabulary shift) goes back to classic batches on its own.
 
 At job end the job's accumulator becomes per-partition HBM-resident
@@ -163,19 +161,6 @@ def _acc_dtype():
     import jax
 
     return np.dtype(np.int64 if jax.config.jax_enable_x64 else np.int32)
-
-
-@functools.lru_cache(maxsize=None)
-def _host_bootstrap():
-    """On the CPU backend the classic bootstrap program is pure
-    overhead: its five-lane ``lax.sort`` runs on the very cores the
-    native host codec would use at ~20x the throughput — so the job
-    seeding a chip's vocabulary does so from its FIRST WHOLE WINDOW
-    through that codec (whose blocks carry cached hash lanes: no
-    re-hash, no row sort), skipping the window's tokenize/pad/dispatch
-    entirely.  A real accelerator keeps the classic bootstrap: the
-    program runs on device while the host tokenizes the next window."""
-    return settings.backend() == "cpu"
 
 
 #: Windowed-dedup span (tokens): batches whose longest line fits run

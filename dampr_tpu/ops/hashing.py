@@ -162,11 +162,7 @@ def _use_device(n):
 
 
 def _fnv(mat, lens):
-    # Measured on a real v5e (benchmarks/pallas_bench.py, round 3): the
-    # Pallas VMEM-resident kernel (ops/pallas_fnv.py) runs at 0.58x the
-    # portable _fnv_jit path (43.5 vs 74.7 Mtok/s at 128k x 16B tokens), so
-    # there is no production dispatch to it — the kernel remains only as a
-    # benchmarked negative result.
+    # _fnv_jit is the one device FNV: a hand-written kernel ran slower.
     n = mat.shape[0]
     if not _use_device(n):
         return _fnv_numpy(mat, lens)

@@ -16,9 +16,8 @@ Division of labor per line-aligned scan window:
 - **device (one program)**: dual-lane FNV hash of the matrix (byte-exact
   with :mod:`.hashing`), stable sorts ordering rows by ``(h1, h2)``,
   per-line first-occurrence dedup (DocFreq), segment counts via an
-  in-program prefix scan (or the Pallas fused segfold kernel when
-  ``settings.lower_pallas_segfold`` opts in), segment-representative
-  indices, and a collision check;
+  in-program prefix scan, segment-representative indices, and a
+  collision check;
 - **host (drain)**: compact the vocabulary-sized survivors, decode their
   representative strings from the original buffer, and build the Block
   the normal fold/spill machinery consumes.
@@ -88,7 +87,7 @@ def _len_bucket(max_len):
 
 
 @functools.lru_cache(maxsize=None)
-def _token_fold_jit(n, L, dedup, pallas, interpret):
+def _token_fold_jit(n, L, dedup):
     """One compiled program: hash -> sort -> dedup -> segment count ->
     collision check over a padded [n, L] token byte matrix.  Cached per
     shape bucket so recompilations stay bounded."""
@@ -149,25 +148,14 @@ def _token_fold_jit(n, L, dedup, pallas, interpret):
         pos = jnp.arange(n, dtype=jnp.int32)
         start_pos = lax.cummax(jnp.where(starts, pos, -1), axis=0)
 
-        use_pallas = pallas and n >= 8192 and n % 8192 == 0
-        if use_pallas:
-            from . import pallas_segfold as SF
-
-            tot, live = SF._segfold_call(n // SF._tile_elems(), interpret)(
-                sh1.astype(jnp.int32).reshape(-1, 128),
-                sh2.astype(jnp.int32).reshape(-1, 128),
-                v.reshape(-1, 128), sinv.reshape(-1, 128))
-            tot = tot.reshape(n)
-            live = live.reshape(n).astype(bool)
-        else:
-            csum = jnp.cumsum(v, dtype=jnp.int32)
-            ex = csum - v
-            # exclusive prefix at the segment start: ex is nondecreasing,
-            # so a running max over start-marked values carries it
-            start_ex = lax.cummax(jnp.where(starts, ex, -1), axis=0)
-            ends = jnp.ones((n,), dtype=bool).at[:-1].set(starts[1:])
-            tot = jnp.where(ends, csum - start_ex, 0)
-            live = ends & (sinv == 0)
+        csum = jnp.cumsum(v, dtype=jnp.int32)
+        ex = csum - v
+        # exclusive prefix at the segment start: ex is nondecreasing, so
+        # a running max over start-marked values carries it
+        start_ex = lax.cummax(jnp.where(starts, ex, -1), axis=0)
+        ends = jnp.ones((n,), dtype=bool).at[:-1].set(starts[1:])
+        tot = jnp.where(ends, csum - start_ex, 0)
+        live = ends & (sinv == 0)
 
         # -- collision check: every token's bytes == its segment rep's --
         smat = jnp.take(mat, perm, axis=0)
@@ -181,12 +169,6 @@ def _token_fold_jit(n, L, dedup, pallas, interpret):
         return sh1, sh2, tot, live, rep_orig, collisions
 
     return jax.jit(token_fold)
-
-
-def _lower_interpret():
-    """Pallas runs interpreted off-TPU only: on a TPU the kernel always
-    compiles through Mosaic."""
-    return settings.backend() != "tpu"
 
 
 def _handoff_enter_frac():
@@ -380,8 +362,7 @@ class DeviceTokenFoldSink(object):
         with devtime.phase("scan_feed", "scan", "pad", track="codec",
                            prof="build") as ph:
             L = _len_bucket(lens.max())
-            npad = max(_pow2(n),
-                       8192 if settings.lower_pallas_segfold else 8)
+            npad = _pow2(n)
             idx = starts[:, None] + np.arange(L, dtype=np.int64)[None, :]
             np.clip(idx, 0, len(buf) - 1, out=idx)
             mat = np.zeros((npad, L), dtype=np.uint8)
@@ -405,9 +386,7 @@ class DeviceTokenFoldSink(object):
         n = len(starts)
         mat, lens_p, lines_p = padded
         npad, L = mat.shape
-        fn = _token_fold_jit(npad, L, self.dedup,
-                             settings.lower_pallas_segfold,
-                             _lower_interpret())
+        fn = _token_fold_jit(npad, L, self.dedup)
         nbytes = mat.nbytes + lens_p.nbytes + lines_p.nbytes
         if self.store is not None:
             self.store.count_h2d(nbytes)
@@ -605,59 +584,6 @@ class DeviceTokenFoldSink(object):
                 data.decode("utf-8")
             except UnicodeDecodeError:
                 self._absorb_or_out(self._host_window(win), out)
-                return out
-        from .handoff import _host_bootstrap
-
-        if self._handoff_live and not self._hv.joined and _host_bootstrap():
-            self._hv.join()
-            if self._hv.seeding:
-                # CPU-backend bootstrap: the seeding job's first window
-                # fills the vocabulary through the NATIVE host codec —
-                # its blocks carry cached hash lanes, so the absorb never
-                # re-hashes or re-sorts, and this window's
-                # tokenize/pad/dispatch is skipped outright (~20x the
-                # classic bootstrap program, which has no accelerator to
-                # hide on here).  Counts are byte-identical: absorb_block
-                # keys by canonical utf-8 bytes, the same contract as a
-                # classic drain.  Table mode engages immediately — a
-                # vocabulary that fails to cover the next window's
-                # batches reverts through the standard miss-fraction
-                # bar.
-                from .. import faults as _faults
-                from .handoff import CLASSIC_DRAIN_BYTES_PER_SLOT
-
-                # The bootstrap replaces this window's program dispatches
-                # — it keeps their fault site, so chaos schedules aimed
-                # at the lowered map fire on every backend.
-                _faults.check("device_dispatch")
-                with _trace.span("handoff", "bootstrap-host",
-                                 bytes=len(data)):
-                    # The native grouping is codec work — bucketed and
-                    # traced as such, so codec_fraction/critpath keep
-                    # attributing the scan's host compute when the
-                    # handoff swallows every emitted block.
-                    with devtime.track("codec"), _trace.span(
-                            "codec", "codec-window", bytes=len(data)):
-                        if self.dedup:
-                            blk = chunk_doc_freq(data, self.mode,
-                                                 self.lower,
-                                                 self.pair_values)
-                        else:
-                            blk = chunk_token_counts(data, self.mode,
-                                                     self.lower,
-                                                     self.pair_values)
-                    self._absorb_or_out(
-                        (blk,) if blk is not None else (), out)
-                self._hv.end_seed()
-                if self._handoff_live and self._hv.shared.seeded:
-                    self._hv.table_mode = True
-                    if self.store is not None and blk is not None:
-                        # Drain bytes the classic path would have
-                        # fetched for this window, one-batch lower
-                        # bound (its real fetch scales with padded
-                        # TOKENS, not distinct keys).
-                        self.store.count_d2h_avoided(
-                            CLASSIC_DRAIN_BYTES_PER_SLOT * len(blk))
                 return out
         with devtime.phase("scan_feed", "scan", "bounds", track="codec",
                            bytes=len(buf)):

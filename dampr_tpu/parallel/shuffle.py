@@ -107,9 +107,7 @@ def _local_fold(inv, h1, h2, v, kind, nonneg_sum=False):
 def _scan_fold_sorted(inv, h1, h2, v, starts=None):
     """The post-sort scan chain of the nonneg-sum lowering (see
     _local_fold): segment totals land at segment-end positions via cumsum +
-    a cummax-carried start offset, no scatters.  Exposed separately so
-    benchmarks/pallas_bench.py can compare it against the fused Pallas
-    kernel on identical pre-sorted inputs."""
+    a cummax-carried start offset, no scatters."""
     import jax.numpy as jnp
     from jax import lax
 

@@ -573,14 +573,6 @@ def effective_handoff_budget():
     return 0
 
 
-#: Route the lowered program's segment-count step through the Pallas
-#: fused segfold kernel (ops/pallas_segfold.py) instead of the XLA scan
-#: lowering.  Off by default until benchmarks/pallas_bench.py measures a
-#: win on real hardware (the FNV Pallas kernel measured 0.58x and is NOT
-#: dispatched; same discipline here).
-lower_pallas_segfold = os.environ.get(
-    "DAMPR_TPU_LOWER_PALLAS", "0").lower() not in ("0", "false", "no", "off")
-
 #: Spill compression policy: "auto" (default) compresses object-lane
 #: blocks and writes fully-numeric blocks raw (high-entropy lanes don't
 #: compress and the codec pass is core-bound both ways); "always"/"never"

@@ -329,6 +329,24 @@ def _run(name):
     return s
 
 
+def _unpicked_partitions(name):
+    """A partition count the next run of ``name`` picks only from a
+    tuned.json: neither its static count (which follows the host's core
+    count) nor the cost model's argmin over measured timings.  A probe
+    run shows both; its history record is then taken back, so the next
+    run plans from the same history and picks the same."""
+    corpus = history.corpus_path(name)
+    with open(corpus, "rb") as f:
+        before = f.read()
+    probe = _run(name)
+    with open(corpus, "wb") as f:
+        f.write(before)
+    taken = {probe["n_partitions"]}
+    taken.update(ch["static"] for ch in probe["plan"]["cost"]["choices"]
+                 if ch["knob"] == "n_partitions")
+    return next(p for p in (8, 16, 32, 12) if p not in taken)
+
+
 class TestKillSwitchAndDegradation:
     def test_kill_switch_reproduces_median_path(self, isolated):
         """DAMPR_TPU_COST_MODEL=0: the adaptive decisions must be
@@ -461,10 +479,8 @@ class TestAutotuneSession:
         applied by the next run's cost layer with the autotune
         provenance in the decision trace."""
         for i in range(4):
-            prev = _run("loop")
-        # A winner the history alone would not pick (the adaptive sizing
-        # follows the host's core count).
-        want = 8 if prev["n_partitions"] != 8 else 16
+            _run("loop")
+        want = _unpicked_partitions("loop")
         os.makedirs(os.path.join(settings.scratch_root, "loop"),
                     exist_ok=True)
         with open(os.path.join(settings.scratch_root, "loop",
@@ -487,8 +503,8 @@ class TestAutotuneSession:
         the same run name is ignored (recorded as tuned_stale), never
         force-applied."""
         for i in range(4):
-            prev = _run("stale")
-        want = 8 if prev["n_partitions"] != 8 else 16
+            _run("stale")
+        want = _unpicked_partitions("stale")
         os.makedirs(os.path.join(settings.scratch_root, "stale"),
                     exist_ok=True)
         with open(os.path.join(settings.scratch_root, "stale",

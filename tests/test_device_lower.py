@@ -35,12 +35,10 @@ from dampr_tpu.plan import lower as plan_lower
 def lowering_on():
     """Force the lowering pass on (explicit, so no backend probe) and
     restore every knob after."""
-    old = (settings.lower, settings.lower_batch,
-           settings.lower_pallas_segfold, settings.optimize)
+    old = (settings.lower, settings.lower_batch, settings.optimize)
     settings.lower = "1"
     yield
-    (settings.lower, settings.lower_batch,
-     settings.lower_pallas_segfold, settings.optimize) = old
+    (settings.lower, settings.lower_batch, settings.optimize) = old
 
 
 def _dict_of(blocks, pair_values):
@@ -155,8 +153,8 @@ class TestProgramParity:
         exact token bytes — results cannot change."""
         real = ops_lower._token_fold_jit
 
-        def lying(n, L, dedup, pallas, interpret):
-            fn = real(n, L, dedup, pallas, interpret)
+        def lying(n, L, dedup):
+            fn = real(n, L, dedup)
 
             def wrapped(mat, lens, lines):
                 out = list(fn(mat, lens, lines))
@@ -173,16 +171,6 @@ class TestProgramParity:
             dev, sink = _device_dict(mapper, data)
             assert sink.fallbacks >= 1
             assert dev == _host_dict(mapper, data)
-
-    def test_pallas_segfold_path_matches(self):
-        settings.lower_pallas_segfold = True
-        try:
-            data = _corpus(13)
-            mapper = TokenCounts(mode="word", lower=True, pair_values=False)
-            dev, _sink = _device_dict(mapper, data)
-            assert dev == _host_dict(mapper, data)
-        finally:
-            settings.lower_pallas_segfold = False
 
     def test_claims_rejects_subclasses_and_unknown(self):
         class Odd(TokenCounts):

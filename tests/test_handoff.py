@@ -767,6 +767,62 @@ class TestStageVocabulary:
         assert dev["table_probe_tokens"] > 0
 
     @_DEDUP
+    def test_seed_drains_before_the_others_join(self, tmp_path,
+                                                monkeypatch, dedup):
+        """The chip's bootstrap, on every backend: the seeding job runs a
+        classic token-fold batch, its first drain ends the seed, and only
+        then do the chip's other jobs join, in table mode."""
+        from dampr_tpu.ops import lower as ops_lower
+
+        events = []
+        sink_cls = ops_lower.DeviceTokenFoldSink
+        hv_cls = handoff_mod.HandoffVocab
+
+        def spy(cls, name, note):
+            """Record ``name``'s calls with ``note`` before and after;
+            a seed's end is recorded before it wakes the waiting jobs."""
+            real = getattr(cls, name)
+
+            def wrapped(obj, *a, **kw):
+                before = note(obj)
+                if name == "end_seed":
+                    events.append((name, before, None))
+                    return real(obj, *a, **kw)
+                out = real(obj, *a, **kw)
+                events.append((name, before, note(obj)))
+                return out
+
+            monkeypatch.setattr(cls, name, wrapped)
+
+        def seeding(sink):
+            return sink._hv is not None and sink._hv.seeding
+
+        spy(sink_cls, "_dispatch", seeding)
+        spy(sink_cls, "_drain", seeding)
+        spy(hv_cls, "end_seed", lambda hv: hv.seeding)
+        spy(hv_cls, "join", lambda hv: (hv.seeding, hv.table_mode))
+
+        corpus = _phased_corpus(tmp_path)
+        got, stats = _stage_run(corpus, "stage-vocab-seed", dedup,
+                                self.JOBS)
+        assert got == self._want(corpus, dedup)
+        dev = stats["device"]
+
+        def at(name, before=None, after=None):
+            return [i for i, (n, b, a) in enumerate(events)
+                    if n == name and before in (None, b)
+                    and after in (None, a)]
+
+        (seed,) = at("join", after=(True, False))
+        (ended,) = at("end_seed", before=True)
+        assert [i for i in at("_dispatch", before=True) if seed < i < ended]
+        assert [i for i in at("_drain", before=True) if seed < i < ended]
+        joins = at("join", after=(False, True))
+        assert len(joins) == dev["handoff_shared_joins"] >= 1
+        assert min(joins) > ended
+        assert dev["table_probe_tokens"] > 0
+
+    @_DEDUP
     def test_faulted_job_retries_exactly(self, tmp_path, monkeypatch,
                                          dedup):
         """A transient fault at a job's table dispatch fails that
@@ -792,24 +848,49 @@ class TestStageVocabulary:
                                                  monkeypatch, dedup):
         """One job's table dispatch is refused: that job flushes its own
         counts to the spill path, the shared table stays, and the other
-        jobs still register device partials."""
+        jobs still register device partials.  The refused job is the
+        first that joined the seeded vocabulary, at its first dispatch:
+        it inserted nothing, so the table holds exactly the words of the
+        other jobs' input."""
+        import re
         import threading
 
-        lock = threading.Lock()
-        refused = []
-        dispatch = handoff_mod.HandoffVocab._dispatch
+        from dampr_tpu.ops import lower as ops_lower
 
-        def refuse_once(hv, *a):
+        lock = threading.Lock()
+        joiners, dispatched, refused = set(), set(), []
+        windows = {}
+        join = handoff_mod.HandoffVocab.join
+        dispatch = handoff_mod.HandoffVocab._dispatch
+        add = ops_lower.DeviceTokenFoldSink.add
+
+        def record_join(hv):
+            join(hv)
+            if hv.table_mode:
+                with lock:
+                    joiners.add(hv)
+
+        def refuse_first_joiner(hv, *a):
             with lock:
-                first = not refused
-                if first:
+                refuse = hv in joiners and hv not in dispatched \
+                    and not refused
+                dispatched.add(hv)
+                if refuse:
                     refused.append(hv)
-            if first:
+            if refuse:
                 return None
             return dispatch(hv, *a)
 
+        def record_window(sink, win):
+            with lock:
+                windows.setdefault(sink._hv, []).append(bytes(win))
+            return add(sink, win)
+
+        monkeypatch.setattr(handoff_mod.HandoffVocab, "join", record_join)
         monkeypatch.setattr(handoff_mod.HandoffVocab, "_dispatch",
-                            refuse_once)
+                            refuse_first_joiner)
+        monkeypatch.setattr(ops_lower.DeviceTokenFoldSink, "add",
+                            record_window)
         corpus = _phased_corpus(tmp_path)
         got, stats = _stage_run(corpus, "stage-vocab-degrade", dedup,
                                 self.JOBS)
@@ -817,8 +898,22 @@ class TestStageVocabulary:
         dev = stats["device"]
         assert dev["handoff_degrades"] == 1
         assert dev["handoff_bytes"] > 0
+        (victim,) = refused
+        assert len(windows) == self.JOBS
+        rx = re.compile(r"[^\w]+")
+        others = set()
+        for hv, wins in windows.items():
+            if hv is not victim:
+                for w in wins:
+                    others.update(t for t in rx.split(w.decode().lower())
+                                  if t)
+        # The degrade did not reset the shared table, and none of the
+        # refused job's words entered it after.
         (sv,) = self.made
-        assert sv.nslots == len(got) and sv.tab_index is not None
+        assert sv.tab_index is not None
+        assert len(set(sv.slot_bytes)) == sv.nslots == len(others)
+        assert sorted(sv.keys) == sorted(others)
+        assert dev["handoff_slot_inserts"] == sv.nslots
 
     @_DEDUP
     def test_no_zero_count_key_in_a_partial(self, tmp_path, monkeypatch,

@@ -6,7 +6,8 @@
 - object-lane hashing is deterministic across processes (no PYTHONHASHSEED
   dependence) — required for spill-reload and multi-host partition routing;
 - device segment folds respect collision-repaired group bounds;
-- bool value columns round-trip exactly.
+- bool value columns round-trip exactly;
+- the device FNV program hashes exactly as the numpy definition.
 """
 
 import subprocess
@@ -17,6 +18,8 @@ import pytest
 
 from dampr_tpu.blocks import Block
 from dampr_tpu.ops import hashing, segment
+
+from conftest import reference_text
 
 
 def _h(keys):
@@ -168,3 +171,26 @@ class TestNativeBatchHash:
         import numpy as np
         np.testing.assert_array_equal(a[0], b[0])
         np.testing.assert_array_equal(a[1], b[1])
+
+
+def _fnv_cases():
+    return {
+        "reference-words": (reference_text() * 3).split(),
+        "empty-and-high-bytes": ["", "é" * 20, "\xff\x80 mixed", "plain"],
+        # row counts on both sides of a power of two
+        "row-counts": [["k%d" % i for i in range(n)]
+                       for n in (1, 511, 512, 513)],
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_fnv_cases()))
+def test_device_fnv_matches_numpy(case):
+    """``_fnv_jit`` (the device FNV the lowered programs inline) against
+    the numpy definition, lane for lane."""
+    keys = _fnv_cases()[case]
+    for ks in (keys if case == "row-counts" else [keys]):
+        mat, lens = hashing.encode_str_keys(ks)
+        w1, w2 = hashing._fnv_numpy(mat, lens)
+        d1, d2 = hashing._fnv_jit()(mat, lens)
+        np.testing.assert_array_equal(w1, np.asarray(d1))
+        np.testing.assert_array_equal(w2, np.asarray(d2))

@@ -132,3 +132,107 @@ class TestGlobalSum:
     def test_float(self, mesh8):
         vals = np.ones(1000, dtype=np.float32) * 0.5
         assert abs(mesh_global_sum(mesh8, vals) - 500.0) < 1e-3
+
+
+def _sorted_case(rng, n_keys, n, max_v=9, n_invalid=0):
+    """Random (inv, h1, h2)-sorted lanes, as the fold's sort leaves them:
+    ``n - n_invalid`` valid records over ``n_keys`` keys, then the
+    invalid tail."""
+    kh1 = rng.randint(0, 1 << 32, size=n_keys, dtype=np.uint64).astype(
+        np.uint32)
+    kh2 = rng.randint(0, 1 << 32, size=n_keys, dtype=np.uint64).astype(
+        np.uint32)
+    ids = rng.randint(0, n_keys, size=n - n_invalid)
+    h1 = np.concatenate([kh1[ids], np.zeros(n_invalid, np.uint32)])
+    h2 = np.concatenate([kh2[ids], np.zeros(n_invalid, np.uint32)])
+    inv = np.zeros(n, dtype=np.uint32)
+    inv[n - n_invalid:] = 1
+    v = rng.randint(0, max_v + 1, size=n).astype(np.int32)
+    order = np.lexsort((h2, h1, inv))
+    return h1[order], h2[order], v[order], inv[order]
+
+
+def _segment_oracle(h1, h2, v, inv):
+    """Exact per-segment totals, on the host: each run of equal
+    (inv, h1, h2) totals at its last position, live iff valid."""
+    n = len(h1)
+    starts = np.ones(n, dtype=bool)
+    starts[1:] = ((h1[1:] != h1[:-1]) | (h2[1:] != h2[:-1])
+                  | (inv[1:] != inv[:-1]))
+    ends = np.ones(n, dtype=bool)
+    ends[:-1] = starts[1:]
+    tot = np.zeros(n, dtype=np.int64)
+    tot[ends] = np.add.reduceat(v.astype(np.int64), np.flatnonzero(starts))
+    return tot, ends & (inv == 0)
+
+
+def _one_segment():
+    n = 2048
+    z = np.zeros(n, dtype=np.uint32)
+    return z, z.copy(), np.ones(n, dtype=np.int32), z.copy()
+
+
+def _all_distinct():
+    n = 1024
+    a = np.arange(n, dtype=np.uint32)
+    return a, a.copy(), np.full(n, 3, dtype=np.int32), np.zeros(n, np.uint32)
+
+
+_SCAN_CASES = {
+    "few-keys": lambda: _sorted_case(np.random.RandomState(0), 50, 1024),
+    "many-keys": lambda: _sorted_case(np.random.RandomState(1), 300, 3072),
+    "one-long-segment": _one_segment,
+    "invalid-tail": lambda: _sorted_case(np.random.RandomState(2), 40, 1024,
+                                         n_invalid=500),
+    "all-distinct": _all_distinct,
+}
+
+
+class TestScanFoldSorted:
+    """The post-sort scan chain of the nonneg-sum fold (cumsum and a
+    cummax-carried segment start, no scatters) against the exact host
+    oracle: the chain the mesh fold runs on the chip."""
+
+    @pytest.mark.parametrize("case", sorted(_SCAN_CASES))
+    def test_matches_host_oracle(self, case):
+        import jax.numpy as jnp
+
+        from dampr_tpu.parallel.shuffle import _scan_fold_sorted
+
+        h1, h2, v, inv = _SCAN_CASES[case]()
+        oinv, oh1, oh2, tot = _scan_fold_sorted(
+            jnp.asarray(inv), jnp.asarray(h1), jnp.asarray(h2),
+            jnp.asarray(v))
+        want_tot, want_live = _segment_oracle(h1, h2, v, inv)
+        live = np.asarray(oinv) == 0
+        np.testing.assert_array_equal(live, want_live)
+        np.testing.assert_array_equal(
+            np.where(live, np.asarray(tot).astype(np.int64), 0),
+            np.where(want_live, want_tot, 0))
+        np.testing.assert_array_equal(np.asarray(oh1), h1)
+        np.testing.assert_array_equal(np.asarray(oh2), h2)
+
+    def test_local_fold_scan_lowering_matches_oracle(self):
+        """``_local_fold(..., nonneg_sum=True)`` sorts unsorted lanes and
+        folds them through the same chain: its live rows are the oracle's
+        per-key totals."""
+        import jax.numpy as jnp
+
+        from dampr_tpu.parallel.shuffle import _local_fold
+
+        rng = np.random.RandomState(3)
+        h1, h2, v, inv = _sorted_case(rng, 100, 1024, n_invalid=24)
+        shuffled = rng.permutation(len(h1))
+        oinv, oh1, oh2, ov = _local_fold(
+            *(jnp.asarray(a[shuffled]) for a in (inv, h1, h2, v)),
+            "sum", nonneg_sum=True)
+        want_tot, want_live = _segment_oracle(h1, h2, v, inv)
+        want = {(int(a), int(b)): int(t) for a, b, t in
+                zip(h1[want_live], h2[want_live], want_tot[want_live])}
+        m = np.asarray(oinv) == 0
+        got = {}
+        for a, b, t in zip(np.asarray(oh1)[m], np.asarray(oh2)[m],
+                           np.asarray(ov)[m]):
+            assert (int(a), int(b)) not in got
+            got[(int(a), int(b))] = int(t)
+        assert got == want

@@ -3,9 +3,10 @@
 Every device program the chip smoke (``chip_smoke.py``) reaches is
 compiled here by the TPU compiler for a ``v5e:2x2`` topology: the lowered
 token fold, the handoff table, scatter, staging and finalize programs,
-the segment fold and lexsort, the Pallas segmented fold, and the mesh
-keyed fold over four described chips.  Nothing runs: a pass means the chip's compiler accepts
-the program at these shapes, not that it is right or fast.
+the segment fold and lexsort, and the mesh keyed fold, with its scan
+chain, over four described chips.  Nothing runs: a pass means the chip's
+compiler accepts the program at these shapes, not that it is right or
+fast.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every test
@@ -66,7 +67,7 @@ def test_token_fold_program(spec, dedup):
     from dampr_tpu.ops import lower
 
     n, L = 4096, 16
-    fn = lower._token_fold_jit.__wrapped__(n, L, dedup, False, False)
+    fn = lower._token_fold_jit.__wrapped__(n, L, dedup)
     _compile(fn, spec((n, L), np.uint8), spec((n,), np.int32),
              spec((n,), np.int32))
 
@@ -117,17 +118,6 @@ def test_segment_fold_and_lexsort(spec):
              spec((n,), np.int32), spec((n,), np.int32))
     _compile(segment._lexsort_jit.__wrapped__(), spec((n,), np.uint8),
              spec((n,), np.uint32), spec((n,), np.uint32))
-
-
-def test_pallas_segfold_is_a_tpu_kernel(spec):
-    """16 tiles of the fused segmented fold compile through Mosaic."""
-    from dampr_tpu.ops import pallas_segfold as SF
-
-    rows = 16 * SF._ROWS
-    fn = SF._segfold_call.__wrapped__(16, False)
-    text = _compile(fn, *(spec((rows, SF._LANES), np.int32)
-                          for _ in range(4)))
-    assert "tpu_custom_call" in text
 
 
 def test_mesh_keyed_fold_on_four_chips(topo):
