@@ -76,13 +76,6 @@ def get_lib():
             if not os.path.exists(so):
                 _build(so)
             lib = ctypes.CDLL(so)
-            fn = lib.dampr_tokenize_hash
-            fn.restype = ctypes.c_long
-            fn.argtypes = [
-                ctypes.c_void_p, ctypes.c_long, ctypes.c_int, ctypes.c_int,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_void_p,
-            ]
             fc = lib.dampr_token_counts
             fc.restype = ctypes.c_long
             fc.argtypes = [
@@ -102,6 +95,19 @@ def get_lib():
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_long,
                 ctypes.c_void_p, ctypes.c_void_p,
             ]
+            ft = lib.dampr_token_bounds
+            ft.restype = ctypes.c_long
+            ft.argtypes = [
+                ctypes.c_void_p, ctypes.c_long, ctypes.c_int,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ]
+            fr = lib.dampr_pad_rows
+            fr.restype = None
+            fr.argtypes = [
+                ctypes.c_void_p, ctypes.c_long, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_long, ctypes.c_long,
+                ctypes.c_void_p,
+            ]
             _lib = lib
         except Exception as exc:  # noqa: BLE001 - any failure -> numpy path
             log.warning("native tokenizer unavailable (%s); using numpy", exc)
@@ -109,30 +115,58 @@ def get_lib():
     return _lib
 
 
-@_codec_timed
-def tokenize_hash(buf, mode, lower, want_line_ids=False):
-    """One native pass: (starts, lens, h1, h2[, line_ids]) for a uint8 buffer.
-    Returns None when the native library is unavailable."""
+def _u8(buf):
+    buf = np.ascontiguousarray(buf)
+    if buf.dtype != np.uint8 or buf.ndim != 1:
+        raise ValueError("expected a 1-D uint8 buffer, got {} {}".format(
+            buf.dtype, buf.shape))
+    return buf
+
+
+def token_bounds(buf, mode, want_line_ids=False):
+    """One native pass: (starts int64, lens int32[, line_ids int32]) of
+    the token runs of a contiguous uint8 buffer, equal to
+    ``ops.text._token_bounds`` (and the line ids to a newline search).
+    Returns None when the native library is unavailable.  Untimed: the
+    lowered scan charges it to its own feed phase."""
     lib = get_lib()
     if lib is None:
         return None
+    buf = _u8(buf)
     n = len(buf)
     cap = n // 2 + 1
     starts = np.empty(cap, dtype=np.int64)
     lens = np.empty(cap, dtype=np.int32)
-    h1 = np.empty(cap, dtype=np.uint32)
-    h2 = np.empty(cap, dtype=np.uint32)
-    line_ids = np.empty(cap, dtype=np.int64) if want_line_ids else None
-    buf = np.ascontiguousarray(buf)
-    count = lib.dampr_tokenize_hash(
-        buf.ctypes.data, n, int(mode), int(lower),
-        starts.ctypes.data, lens.ctypes.data,
-        h1.ctypes.data, h2.ctypes.data,
+    line_ids = np.empty(cap, dtype=np.int32) if want_line_ids else None
+    count = lib.dampr_token_bounds(
+        buf.ctypes.data, n, int(mode), starts.ctypes.data, lens.ctypes.data,
         line_ids.ctypes.data if want_line_ids else None)
-    out = (starts[:count], lens[:count], h1[:count], h2[:count])
     if want_line_ids:
-        out = out + (line_ids[:count],)
-    return out
+        return starts[:count], lens[:count], line_ids[:count]
+    return starts[:count], lens[:count]
+
+
+def pad_rows(buf, starts, lens, mat):
+    """Fill rows ``[0, len(starts))`` of the zeroed C-contiguous uint8
+    matrix ``mat`` with each token's bytes from ``buf``, zero past its
+    length, in one native pass.  Returns False when the native library
+    is unavailable (``mat`` untouched).  Untimed, like
+    :func:`token_bounds`."""
+    lib = get_lib()
+    if lib is None:
+        return False
+    buf = _u8(buf)
+    starts = np.ascontiguousarray(starts, dtype=np.int64)
+    lens = np.ascontiguousarray(lens, dtype=np.int32)
+    if (mat.dtype != np.uint8 or mat.ndim != 2 or not mat.flags.c_contiguous
+            or mat.shape[0] < len(starts) or len(lens) != len(starts)):
+        raise ValueError("pad_rows: {} tokens, {} lengths, matrix {} {}"
+                         .format(len(starts), len(lens), mat.dtype,
+                                 mat.shape))
+    lib.dampr_pad_rows(buf.ctypes.data, len(buf), starts.ctypes.data,
+                       lens.ctypes.data, len(starts), mat.shape[1],
+                       mat.ctypes.data)
+    return True
 
 
 @_codec_timed

@@ -48,45 +48,58 @@ struct ClassTables {
 };
 static const ClassTables kTables;
 
-// Single pass: tokenize + hash + (optional) lowercase folding into the hash.
-// Returns the number of tokens found.  Output arrays must hold at least
-// n/2 + 1 entries (the worst case: alternating token/separator bytes).
-// line_ids receives the 0-based line index of each token (newlines counted
-// in the raw buffer) — pass nullptr to skip.
-long dampr_tokenize_hash(const uint8_t* buf, long n, int mode, int lower,
-                         int64_t* starts, int32_t* lens,
-                         uint32_t* h1_out, uint32_t* h2_out,
-                         int64_t* line_ids) {
-    const uint32_t OFF1 = 2166136261u, OFF2 = 0x9747B28Cu;
-    const uint32_t P1 = 16777619u, P2 = 0x85EBCA6Bu;
-
-    const uint8_t* fold = kTables.fold[lower ? 1 : 0];
+// The token scan of the lowered device scan's feed: maximal runs of token
+// bytes (kTables.tok[mode]), each emitted as (start, len); the device
+// program hashes the padded rows itself.  line_ids receives the 0-based
+// line index of each token (newlines counted in the buffer) — pass nullptr
+// to skip.  Returns the number of tokens found.  Output arrays must hold at
+// least n/2 + 1 entries (the worst case: alternating token/separator bytes).
+long dampr_token_bounds(const uint8_t* buf, long n, int mode,
+                        int64_t* starts, int32_t* lens, int32_t* line_ids) {
     const bool* tokt = kTables.tok[mode ? 1 : 0];
     long count = 0;
     long i = 0;
-    int64_t line = 0;
+    int32_t line = 0;
     while (i < n) {
         uint8_t b = buf[i];
         if (b == '\n') { ++line; ++i; continue; }
         if (!tokt[b]) { ++i; continue; }
-        // token run
         long s = i;
-        uint32_t h1 = OFF1, h2 = OFF2;
-        int64_t tok_line = line;
-        do {
-            uint8_t c = fold[buf[i]];
-            h1 = (h1 ^ c) * P1;
-            h2 = (h2 ^ c) * P2;
-            ++i;
-        } while (i < n && tokt[buf[i]]);
+        do { ++i; } while (i < n && tokt[buf[i]]);
         starts[count] = s;
         lens[count] = (int32_t)(i - s);
-        h1_out[count] = h1;
-        h2_out[count] = h2;
-        if (line_ids) line_ids[count] = tok_line;
+        if (line_ids) line_ids[count] = line;
         ++count;
     }
     return count;
+}
+
+// Rows [0, n) of a zeroed row-major uint8 [*, L] matrix: row i holds the
+// first min(lens[i], L) bytes of buf at starts[i], zero past them (the
+// lowered scan's padded batch).  Rows whose L bytes lie inside the buffer
+// copy in masked 8-byte words; the rest bytewise.  A token reaching past
+// the buffer is cut at its end (none does: the bounds come from the scan).
+void dampr_pad_rows(const uint8_t* buf, long nbuf, const int64_t* starts,
+                    const int32_t* lens, long n, long L, uint8_t* mat) {
+    const bool words = (L % 8) == 0;
+    for (long i = 0; i < n; ++i) {
+        const int64_t s = starts[i];
+        if (s < 0 || s >= nbuf) continue;
+        long len = lens[i] < L ? (long)lens[i] : L;
+        if (len > nbuf - s) len = nbuf - s;
+        uint8_t* row = mat + i * L;
+        if (words && s + L <= nbuf) {
+            for (long j = 0; j < len; j += 8) {
+                uint64_t w;
+                memcpy(&w, buf + s + j, 8);
+                const long rem = len - j;
+                if (rem < 8) w &= (1ULL << (rem * 8)) - 1;  // little-endian
+                memcpy(row + j, &w, 8);
+            }
+        } else if (len > 0) {
+            memcpy(row, buf + s, (size_t)len);
+        }
+    }
 }
 
 // Fused tokenize + hash + count: one pass over the buffer feeding an

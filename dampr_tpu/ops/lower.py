@@ -9,10 +9,12 @@ primitive is *lowered through JAX*, not interpreted per record.
 
 Division of labor per line-aligned scan window:
 
-- **host (feed)**: byte classification + token bounds (the vectorized
-  table lookups from :mod:`.text`), case fold, per-line ids, and the
-  padded token byte matrix — the h2d payload, built for the NEXT batch
-  while the previous batch's program runs (double buffering);
+- **host (feed)**: case fold, then token bounds with per-line ids in
+  one native pass over the window and the padded token byte matrix in
+  one native pass per batch (:mod:`..native`; the numpy forms from
+  :mod:`.text` only where that library is unavailable) — the h2d
+  payload, built for the NEXT batch while the previous batch's program
+  runs (double buffering);
 - **device (one program)**: dual-lane FNV hash of the matrix (byte-exact
   with :mod:`.hashing`), stable sorts ordering rows by ``(h1, h2)``,
   per-line first-occurrence dedup (DocFreq), segment counts via an
@@ -40,7 +42,7 @@ import functools
 
 import numpy as np
 
-from .. import settings
+from .. import native, settings
 from ..obs import trace as _trace
 from . import devtime
 from .segment import sort_perm
@@ -363,17 +365,21 @@ class DeviceTokenFoldSink(object):
                            prof="build") as ph:
             L = _len_bucket(lens.max())
             npad = _pow2(n)
-            idx = starts[:, None] + np.arange(L, dtype=np.int64)[None, :]
-            np.clip(idx, 0, len(buf) - 1, out=idx)
             mat = np.zeros((npad, L), dtype=np.uint8)
-            mat[:n] = np.where(np.arange(L, dtype=np.int32)[None, :]
-                               < lens[:, None], buf[idx], 0)
+            built = native.pad_rows(buf, starts, lens, mat)
+            if not built:
+                idx = starts[:, None] + np.arange(L, dtype=np.int64)[None, :]
+                np.clip(idx, 0, len(buf) - 1, out=idx)
+                mat[:n] = np.where(np.arange(L, dtype=np.int32)[None, :]
+                                   < lens[:, None], buf[idx], 0)
             lens_p = np.zeros(npad, dtype=np.int32)
             lens_p[:n] = lens
             lines_p = np.zeros(npad, dtype=np.int32)
             if lines is not None:
                 lines_p[:n] = lines
             ph.nbytes = mat.nbytes
+        if self.store is not None:
+            self.store.count_pad_batch(built)
         return mat, lens_p, lines_p
 
     def _dispatch(self, starts, lens, padded):
@@ -589,12 +595,15 @@ class DeviceTokenFoldSink(object):
                            bytes=len(buf)):
             if self.lower:
                 buf = _LOWER[buf]
-            starts, lens = _token_bounds(buf, self.mode)
+            tokens = (native.token_bounds(
+                buf, 1 if self.mode == "word" else 0, self.dedup)
+                or _token_bounds(buf, self.mode))
+        starts, lens = tokens[:2]
         n = len(starts)
         if n == 0:
             return ()
-        line_id = None
-        if self.dedup:
+        line_id = tokens[2] if len(tokens) > 2 else None
+        if self.dedup and line_id is None:
             with devtime.phase("scan_feed", "scan", "line-ids"):
                 line_id = self._line_ids(buf, starts)
 

@@ -3773,6 +3773,10 @@ class MTRunner(object):
                 # one another job had seeded.
                 "handoff_slot_inserts": sto.handoff_slot_inserts,
                 "handoff_shared_joins": sto.handoff_shared_joins,
+                # The lowered scan's padded batches, and those the
+                # native feed built rather than the numpy fallback.
+                "scan_pad_batches": sto.scan_pad_batches,
+                "scan_native_batches": sto.scan_native_batches,
                 # Chips at work (parallel.placement): chips whose lowered
                 # map jobs fed their programs, the lowered feed's h2d
                 # bytes per chip, the mesh size of the collective fold

@@ -583,6 +583,10 @@ class RunStore(object):
         # one another job had already seeded.
         self.handoff_slot_inserts = 0
         self.handoff_shared_joins = 0
+        # The lowered scan's padded batches, and those of them the
+        # native feed built (the rest: the numpy fallback).
+        self.scan_pad_batches = 0
+        self.scan_native_batches = 0
         # Lowered map batches or windows handed back to the exact host
         # grouping (hash collision, non-UTF-8 window, over-wide line).
         self.lower_fallbacks = 0
@@ -645,6 +649,11 @@ class RunStore(object):
     def count_slot_inserts(self, n):
         with self._lock:
             self.handoff_slot_inserts += n
+
+    def count_pad_batch(self, native):
+        with self._lock:
+            self.scan_pad_batches += 1
+            self.scan_native_batches += bool(native)
 
     def count_shared_join(self):
         with self._lock:
