@@ -1,58 +1,33 @@
-"""The seeded corpus and the plain references' process pool.
+"""The seeded input and the plain references' process pool.
 
-Copied from ``dampr_tpu/bench_tfidf.py:corpus_text`` (the Shakespeare
-shape) so that no program PR can move the yardstick; parameterised by the
-configuration file.  Nothing here imports JAX or ``dampr_tpu``: the pool
-is spawned before the parent touches JAX, and its children never do.
+A configuration names its data generator, ``perfbench/data/<kind>.py``,
+by its ``"data"`` key (``zipf_text`` where it has none) and gives it its
+``corpus`` block as parameters.  Each generator has one entry,
+``shard(seed, idx, offset, nbytes, params) -> bytes``: shard ``idx`` of
+the input, at most ``nbytes`` long, whose first byte lies at ``offset`` of
+the whole input when every shard before it is whole.  The same
+``(seed, idx)`` gives the same bytes.  Nothing here imports JAX or
+``dampr_tpu``: the pool is spawned before the parent touches JAX, and its
+children never do.
 """
 
 import importlib.util
 import multiprocessing
 import os
 
-MASK32 = 0xFFFFFFFF
-
-
-def shard_text(seed, idx, nbytes, corpus):
-    """Line-aligned Zipf text of at most ``nbytes`` (shorter only by the
-    cut partial line), drawn from ``(seed, idx)``.  ``corpus`` holds
-    ``vocabulary``, ``zipf_exponent``, ``tokens_per_line`` ([min, max])."""
-    import numpy as np
-
-    # RandomState takes 32-bit words; the driver's seeds pass 2**31.
-    rng = np.random.RandomState([seed & MASK32, (seed >> 32) & MASK32, idx])
-    vocab_n = corpus["vocabulary"]
-    lo, hi = corpus["tokens_per_line"]
-    vocab = np.array(["w%04x" % i if i > 200 else "t%d" % i
-                      for i in range(vocab_n)], dtype=object)
-    probs = 1.0 / np.arange(1, vocab_n + 1) ** corpus["zipf_exponent"]
-    probs /= probs.sum()
-    parts = []
-    written = 0
-    while written < nbytes:
-        ids = rng.choice(vocab_n, size=(20000,), p=probs)
-        lens = rng.randint(lo, hi + 1, size=2000)
-        pos = 0
-        out = []
-        for n in lens:
-            out.append(" ".join(vocab[ids[pos:pos + n]]))
-            pos += n
-            if pos + hi + 1 > len(ids):
-                break
-        chunk = "\n".join(out) + "\n"
-        parts.append(chunk)
-        written += len(chunk)
-    text = "".join(parts)
-    return text[:text.rindex("\n", 0, nbytes) + 1].encode("ascii")
+#: The generator of a configuration that names none.
+DEFAULT_DATA = "zipf_text"
 
 
 def _make_shard(task):
-    return shard_text(*task)
+    kind, seed, idx, offset, nbytes, params = task
+    return load("data", kind).shard(seed, idx, offset, nbytes, params)
 
 
 def load(kind, name):
-    """``perfbench/<kind>/<name>.py`` as a module: cells, pipelines and
-    metrics are found by the names in ``BENCHMARK.json``."""
+    """``perfbench/<kind>/<name>.py`` as a module: data generators,
+    pipelines and metrics are found by the names in ``BENCHMARK.json`` and
+    the files it points to."""
     path = os.path.join(bench_dir(), kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
         "perfbench_{}_{}".format(kind, name.replace("-", "_").replace(
@@ -81,10 +56,13 @@ class Pool(object):
         self.path = None
         self.bounds = []
 
-    def start_corpus(self, seed, corpus, total_bytes, shard_bytes, path):
+    def start_corpus(self, seed, kind, params, total_bytes, shard_bytes,
+                     path):
+        """Start writing ``seed``'s input from generator ``kind``."""
         n = -(-total_bytes // shard_bytes)
-        tasks = [(seed, i, min(shard_bytes, total_bytes - i * shard_bytes),
-                  corpus) for i in range(n)]
+        tasks = [(kind, seed, i, i * shard_bytes,
+                  min(shard_bytes, total_bytes - i * shard_bytes), params)
+                 for i in range(n)]
         self.path = path
         self._pending = self._pool.map_async(_make_shard, tasks)
 

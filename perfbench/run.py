@@ -15,8 +15,10 @@ pool computes the plain reference and every job's whole result is
 compared with it.  The last line of standard output is the result.
 
 Everything about a cell is found by name: its configuration, traffic and
-cell files under ``configs/``, ``traffic/`` and ``workloads/``, its
-pipeline under ``pipelines/`` and each metric's reader under ``metrics/``.
+cell files under ``configs/``, ``traffic/`` and ``workloads/``, its data
+generator under ``data/`` (the configuration's ``"data"``, ``zipf_text``
+where it names none), its pipeline under ``pipelines/`` and each metric's
+reader under ``metrics/``.
 
 ``--rehearse-cpu BYTES`` runs on whatever JAX finds, with the lowering
 forced on and the 1x tier cut to BYTES: for rehearsals on a machine
@@ -36,7 +38,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 
-from corpus import Pool, bench_dir, load  # noqa: E402
+from corpus import DEFAULT_DATA, Pool, bench_dir, load  # noqa: E402
 
 BENCH = bench_dir()
 ROOT = os.path.dirname(BENCH)
@@ -95,7 +97,8 @@ class Cell(object):
         total = self.traffic["tier"] * tier_bytes
         shard = min(self.traffic["shard_bytes"], tier_bytes)
         pool = Pool(max(1, min(16, os.cpu_count() or 1, -(-total // shard))))
-        pool.start_corpus(seed, self.config["corpus"], total, shard,
+        pool.start_corpus(seed, self.config.get("data", DEFAULT_DATA),
+                          self.config["corpus"], total, shard,
                           os.path.join(work, "corpus.txt"))
         return pool
 
@@ -298,7 +301,10 @@ def measure(args, cell, pool, work):
     if args.trace:
         from trace_reduce import reduce_trace
 
+        t0 = time.perf_counter()
         run.trace = reduce_trace(os.path.join(work, "trace"))
+        log("trace of {} jobs reduced in {:.3f} s".format(
+            TRACE_JOBS, time.perf_counter() - t0))
         breakdown = run.trace["breakdown"]
         traced, rest = run.jobs[:TRACE_JOBS], run.jobs[TRACE_JOBS:]
         if rest:

@@ -65,6 +65,82 @@ def test_synthetic_trace():
     assert ops_["jit_kernel/reduce"] == pytest.approx(50e-9)
 
 
+def test_engine_span_inside_a_job_names_its_gap():
+    """A ``dampr.`` span inside ``job.run``, on another host thread,
+    names the part of the gap it covers; the innermost span wins where
+    two engine spans nest; the device side is as before."""
+    host = Plane("/host:CPU", [
+        Line("python", [Ev("perfbench.window", 0, 1000),
+                        Ev("job.run", 0, 1000)]),
+        Line("worker", [Ev("dampr.map.scan", 200, 700),
+                        Ev("dampr.scan.pad", 300, 400),
+                        Ev("PjitFunction(x)", 250, 260)])])
+    dev = Plane("/device:TPU:0", [
+        Line("XLA Ops", [Ev("%fusion.1 = s32[8] fusion(%x)", 0, 100),
+                         Ev("%fusion.1 = s32[8] fusion(%x)", 900, 1000)]),
+        Line("XLA Modules", [Ev("jit_program(1)", 0, 1000)])])
+    got = reduce_planes([host, dev])
+    assert got["busy_s"] == pytest.approx(200e-9)
+    assert got["window_s"] == pytest.approx(1000e-9)
+    assert got["breakdown"]["device_ops"] == [
+        ["jit_program/fusion.1", pytest.approx(200e-9)]]
+    assert got["breakdown"]["idle_gaps"] == [
+        ["dampr.map.scan", pytest.approx(300e-9)],  # 400..700
+        ["job.run", pytest.approx(200e-9)],         # 700..900
+        ["job.run", pytest.approx(100e-9)],         # 100..200
+        ["dampr.map.scan", pytest.approx(100e-9)],  # 200..300
+        ["dampr.scan.pad", pytest.approx(100e-9)]]  # 300..400
+
+
+def test_many_spans_name_every_piece_as_a_rescan_would():
+    """The sweep over span edges against the plain rule: each piece of a
+    gap named by the shortest span covering its midpoint, the first
+    listed among equals."""
+    import random
+
+    rng = random.Random(7)
+    spans = [Ev("perfbench.window", 0, 100000)]
+    for i in range(400):
+        a = rng.randrange(0, 100000)
+        spans.append(Ev("dampr.s{}".format(i % 37), a,
+                        a + rng.choice([0, 5, 50, 500, 5000])))
+    ops = []
+    t = 0
+    while t < 100000:
+        t += rng.randrange(1, 3000)
+        ops.append(Ev("%op = f32[] add(%a)", t, t + rng.randrange(1, 900)))
+    host = Plane("/host:CPU", [Line("t", spans)])
+    dev = Plane("/device:TPU:0", [Line("XLA Ops", ops),
+                                  Line("XLA Modules", [])])
+    got = reduce_planes([host, dev], top=10 ** 6)["breakdown"]["idle_gaps"]
+    busy = union((a, b) for a, b in ((max(e.start_ns, 0), min(e.end_ns, 100000))
+                                     for e in ops) if b > a)
+    gaps, prev = [], 0
+    for a, b in busy + [[100000, 100000]]:
+        if a > prev:
+            gaps.append((prev, a))
+        prev = max(prev, b)
+    inner = [(e.start_ns, e.end_ns, e.name) for e in spans[1:]]
+    edges = sorted({t for s in inner for t in s[:2]})
+    want = []
+    for a, b in gaps:
+        cuts = [a] + [t for t in edges if a < t < b] + [b]
+        for x, y in zip(cuts, cuts[1:]):
+            mid = (x + y) / 2.0
+            cover = [s for s in inner if s[0] <= mid <= s[1]]
+            name = (min(cover, key=lambda s: s[1] - s[0])[2] if cover
+                    else "perfbench.between")
+            if want and want[-1][2] == x and want[-1][0] == name:
+                want[-1][1] += (y - x) / 1e9
+                want[-1][2] = y
+            else:
+                want.append([name, (y - x) / 1e9, y])
+    want = sorted(([n, s] for n, s, _y in want), key=lambda g: -g[1])
+    assert len(got) == len(want) > 100
+    assert [g[0] for g in got] == [g[0] for g in want]
+    assert [g[1] for g in got] == pytest.approx([g[1] for g in want])
+
+
 def test_trace_without_a_tpu_plane_is_refused():
     host = Plane("/host:CPU", [Line("python", [
         Ev("perfbench.window", 0, 10)])])
@@ -88,4 +164,18 @@ def test_recorded_v5e_trace():
     assert all(g[1] >= 0.015 for g in gaps[:3])
     idle = got["window_s"] - got["busy_s"]
     assert sum(g[1] for g in gaps) <= idle + 1e-9
-    assert got["breakdown"]["device_ops"]
+    # As the reduction read it before it took the engine's spans: the
+    # trace holds none, so nothing moves.
+    assert got["busy_s"] == 0.002243162
+    assert got["window_s"] == 0.067945407
+    assert got["breakdown"]["device_ops"] == [
+        ["jit__lambda/sort.6", 0.002225185],
+        ["jit__lambda/multiply_add_fusion", 1.2988e-05],
+        ["jit__lambda/reduce_sum.7", 3.618e-06],
+        ["jit__lambda/iota", 1.371e-06]]
+    assert gaps == [
+        ["job.release", 0.020983539], ["job.release", 0.019365565],
+        ["job.release", 0.018531147], ["job.run", 0.00203727],
+        ["job.run", 0.00195848], ["job.run", 0.00188554],
+        ["job.release", 0.000474774], ["job.release", 0.000442813],
+        ["perfbench.between", 4.18e-06], ["perfbench.between", 3.609e-06]]

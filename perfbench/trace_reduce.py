@@ -5,8 +5,9 @@ is the benchmark's own ``perfbench.window`` annotation on the host plane.
 Device busy time is the union of the intervals of the operations on each
 TPU plane's ``XLA Ops`` line, clipped to the window and averaged over the
 TPU planes.  Idle gaps are the holes in that union on the first TPU
-plane, cut where a benchmark annotation (``job.run``, ``job.read``...)
-starts or ends, each piece named by the innermost annotation over it.
+plane, cut where a host annotation (``job.run``, ``job.read``, the
+engine's ``dampr.*``...) starts or ends, each piece named by the innermost
+annotation over it.
 
 The device's timestamps lead the host's by about 1.7 ms in a v5e trace
 (the recorded trace in ``tests/data``: each program starts that long
@@ -16,11 +17,13 @@ noise, and the reduction does not correct it.
 
 import bisect
 import glob
+import heapq
 import os
 
 WINDOW = "perfbench.window"
-#: The benchmark's own annotations, which name what the host was doing.
-HOST_PREFIXES = ("job.", "perfbench.")
+#: The annotations that name what the host was doing: the benchmark's own
+#: and the engine's (``dampr.<cat>.<name>``, one at each ``obs.trace.span``).
+HOST_PREFIXES = ("job.", "perfbench.", "dampr.")
 
 
 def find_xplane(trace_dir):
@@ -70,6 +73,30 @@ def _op_names(plane):
         yield ev, "{}/{}".format(module, op)
 
 
+def _pieces(gaps, host):
+    """``(start, end, name)`` for each piece of the sorted, disjoint
+    ``gaps``, cut where a ``host`` span starts or ends, and named by the
+    innermost span that covers it: the shortest, the first listed among
+    equals.  One sweep over the spans' edges: a span enters a heap at its
+    start, and leaves once a piece ends past its end."""
+    edges = sorted({t for s in host for t in s[:2]})
+    by_start = sorted(range(len(host)), key=lambda i: host[i][0])
+    active = []
+    k = 0
+    for a, b in gaps:
+        cuts = ([a] + edges[bisect.bisect_right(edges, a):
+                            bisect.bisect_left(edges, b)] + [b])
+        for x, y in zip(cuts, cuts[1:]):
+            while k < len(by_start) and host[by_start[k]][0] <= x:
+                i = by_start[k]
+                heapq.heappush(active, (host[i][1] - host[i][0], i))
+                k += 1
+            while active and host[active[0][1]][1] < y:
+                heapq.heappop(active)
+            yield x, y, (host[active[0][1]][2] if active
+                         else "perfbench.between")
+
+
 def reduce_planes(planes, top=10):
     """The reduction over ``ProfileData.planes``; see the module doc."""
     planes = list(planes)
@@ -111,23 +138,13 @@ def reduce_planes(planes, top=10):
         if a > prev:
             gaps.append((prev, a))
         prev = max(prev, b)
-    host = [s for s in spans if s[2] != WINDOW]
-    edges = sorted({t for s in host for t in s[:2]})
     named = []
-    for a, b in gaps:
-        # Cut the gap where a host span starts or ends; name each piece by
-        # the innermost span that covers it.
-        cuts = [a] + [t for t in edges if a < t < b] + [b]
-        for x, y in zip(cuts, cuts[1:]):
-            mid = (x + y) / 2.0
-            inner = [s for s in host if s[0] <= mid <= s[1]]
-            name = (min(inner, key=lambda s: s[1] - s[0])[2] if inner
-                    else "perfbench.between")
-            if named and named[-1][2] == x and named[-1][0] == name:
-                named[-1][1] += (y - x) / 1e9
-                named[-1][2] = y
-            else:
-                named.append([name, (y - x) / 1e9, y])
+    for x, y, name in _pieces(gaps, [s for s in spans if s[2] != WINDOW]):
+        if named and named[-1][2] == x and named[-1][0] == name:
+            named[-1][1] += (y - x) / 1e9
+            named[-1][2] = y
+        else:
+            named.append([name, (y - x) / 1e9, y])
     named = sorted(([n, secs] for n, secs, _end in named),
                    key=lambda g: -g[1])
     ops = sorted(([k, v / 1e9] for k, v in by_op.items()),
